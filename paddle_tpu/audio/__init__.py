@@ -139,7 +139,7 @@ class features:
 # datasets (reference: python/paddle/audio/datasets — dataset.py base,
 # esc50.py, tess.py). Zero-egress: with `files`/`labels` the datasets read
 # real audio-feature arrays from disk (np.load-able); without, deterministic
-# synthetic waveforms with the real label taxonomy + feature pipeline.
+# synthetic waveforms with the real label vocabulary + feature pipeline.
 
 from paddle_tpu.io import Dataset as _IODataset  # noqa: E402
 

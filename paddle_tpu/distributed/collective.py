@@ -9,7 +9,7 @@ are COMPILED INTO sharded programs as XLA collectives (`lax.psum`,
 `all_gather`, `psum_scatter`, `ppermute`, `all_to_all`) over named mesh axes —
 the ProcessGroupXLA seam. Two contexts:
 
-1. Inside a shard_map'd/jitted region (`in_collective_context()` true): ops
+1. Inside a shard_map'd region (the group's mesh axes are bound): ops
    lower to lax collectives over the group's mesh axes. This is the hot path —
    XLA schedules them on ICI with compute overlap (the analog of NCCL comm
    streams + the reference's CommContext).
@@ -173,33 +173,17 @@ def _axis_names(group: Group | None):
     return g.axes if g.axes else None
 
 
-def in_collective_context() -> bool:
-    """True when called under a jax trace that binds mesh axis names (shard_map)."""
+def _axis_bound(axis) -> bool:
     try:
-        return bool(jax.core.get_axis_env() and jax.core.get_axis_env().axis_sizes)
-    except Exception:
-        # jax>=0.5 moved axis env; probe by attempting a cheap lookup
-        try:
-            jax.lax.axis_index("_probe_nonexistent_axis")
-        except NameError:
-            return False
-        except Exception as e:
-            return "unbound axis name" not in str(e)
+        jax.lax.axis_size(axis)
+    except NameError:  # "unbound axis name": not inside a shard_map over it
         return False
+    return True
 
 
 def _bound_axes(axes):
     """Subset of `axes` that are bound in the current trace (inside shard_map)."""
-    if not axes:
-        return ()
-    bound = []
-    for a in axes:
-        try:
-            jax.lax.axis_index(a)  # raises NameError if not bound
-            bound.append(a)
-        except Exception:
-            pass
-    return tuple(bound)
+    return tuple(a for a in axes or () if _axis_bound(a))
 
 
 _REDUCERS = {

@@ -8,6 +8,8 @@ per-chip processes). `python -m paddle_tpu.distributed.launch --nnodes N
 train.py` execs the script once per host with rank env set; a watcher restarts
 or tears down the group on child failure (the launch/controllers/watcher.py
 analog). Multi-host rendezvous metadata comes from --master host:port or env.
+With --nproc_per_node > 1 on a TPU host each child is given its own chip
+(launch/chips.py); a split with no verified recipe is refused up front.
 """
 from __future__ import annotations
 
@@ -94,9 +96,20 @@ def launch(argv=None):
             args.master = f"127.0.0.1:{_free_port()}"
         if not coordinator:
             coordinator = f"{args.master.rsplit(':', 1)[0]}:{_free_port()}"
+    from paddle_tpu.distributed.launch.chips import child_chip_env
+
+    # one process per chip: each child of a multi-process TPU host gets its
+    # own chip in the environment (or the split is refused) — children that
+    # inherit the parent's environment would all ask for every chip
+    chip_ports = [_free_port() for _ in range(nproc)] if nproc > 1 else []
     for local in range(nproc):
         rank = base_rank + local
         env = dict(os.environ)
+        try:
+            env.update(child_chip_env(local, nproc, env, chip_ports))
+        except RuntimeError as e:  # raised for local 0: nothing started yet
+            print(f"--nproc_per_node {nproc}: {e}", file=sys.stderr)
+            return 2
         env.update({
             "PADDLE_TRAINER_ID": str(rank),
             "PADDLE_LOCAL_RANK": str(local),

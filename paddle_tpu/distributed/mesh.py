@@ -21,19 +21,12 @@ __all__ = ["build_mesh", "get_mesh", "set_mesh", "mesh_axis_size", "PartitionSpe
 
 
 def shard_map_compat(body, mesh, in_specs, out_specs):
-    """shard_map across jax API generations (new jax.shard_map/check_vma vs
-    jax.experimental.shard_map/check_rep), with replication checking off —
-    our bodies use rank-dependent values (axis_index) by design."""
-    try:
-        from jax import shard_map
-
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
+    """`jax.shard_map` with replication checking off — the repo-wide
+    convention: our bodies use rank-dependent values (axis_index) by
+    design."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-    except (ImportError, TypeError):  # older jax API
-        from jax.experimental.shard_map import shard_map
 
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
 
 _GLOBAL_MESH: Mesh | None = None
 

@@ -54,26 +54,30 @@ def synth_host_inputs(in_shapes):
             for shape, dtype in in_shapes]
 
 
-_ARTIFACT_MOD = None
+_BY_PATH: dict = {}
 
 
-def _artifact_mod():
-    """Load the sibling artifact module BY FILE PATH: standalone serving
-    runs with an import hook that forbids every `paddle_tpu.*` import (the
-    frontend-free guarantee), and artifact.py itself needs only
-    json/zipfile/numpy."""
-    global _ARTIFACT_MOD
-    if _ARTIFACT_MOD is None:
+def _load_by_path(relpath: str):
+    """Load a module of this package BY FILE PATH: standalone serving runs
+    with an import hook that forbids every `paddle_tpu.*` import (the
+    frontend-free guarantee), and the modules loaded this way need only
+    the stdlib, numpy and jax."""
+    if relpath not in _BY_PATH:
         import importlib.util
         import os
 
-        p = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                         "artifact.py")
-        spec = importlib.util.spec_from_file_location("_serve_artifact", p)
+        p = os.path.normpath(os.path.join(
+            os.path.dirname(os.path.abspath(__file__)), relpath))
+        name = "_serve_" + os.path.splitext(os.path.basename(p))[0]
+        spec = importlib.util.spec_from_file_location(name, p)
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
-        _ARTIFACT_MOD = mod
-    return _ARTIFACT_MOD
+        _BY_PATH[relpath] = mod
+    return _BY_PATH[relpath]
+
+
+def _artifact_mod():
+    return _load_by_path("artifact.py")
 
 
 def _np_dtype(s: str):
@@ -385,6 +389,8 @@ def main(argv=None):
     ap.add_argument("--timeout-s", type=float, default=DEFAULT_TIMEOUT_S)
     ap.add_argument("--max-body-mb", type=int, default=DEFAULT_MAX_BODY_MB)
     args = ap.parse_args(argv)
+    # before the first compile: JAX's persistent cache, placed from outside
+    _load_by_path("../core/compile_cache.py").enable_compile_cache()
     art = Artifact(args.artifact, warmup=args.warmup)
     if args.bench:
         print(json.dumps(art.bench(args.bench)), flush=True)

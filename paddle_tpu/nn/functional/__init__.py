@@ -1295,8 +1295,10 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
     """reference: nn/functional/flash_attention.py:722 scaled_dot_product_attention.
 
     Layout: [batch, seq, heads, head_dim] (paddle flash-attention convention).
-    Uses the Pallas flash-attention kernel on TPU when enabled+applicable,
-    else an XLA fallback (fused by the compiler; memory O(S^2) only at trace).
+    Uses the Pallas flash-attention kernel on a TPU backend (or under
+    force_interpret()) when enabled and applicable — a kernel failure there
+    raises; on a CPU backend, with dropout or with an explicit mask it is
+    the XLA path (fused by the compiler; memory O(S^2) only at trace).
 
     segment_ids ([batch, seq] int32, sequence packing): attention becomes
     block-diagonal per packed document — position i attends to j only when
@@ -1311,16 +1313,11 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
     through unchanged, so no combination overflows to -inf/NaN.
     """
     if flag("use_pallas_attention") and dropout_p == 0.0 and attn_mask is None:
-        try:
-            # guarded: a jax install without a working pallas import must
-            # degrade to the XLA path, not break every attention call
-            from paddle_tpu.ops.pallas.flash_attention import (
-                _on_tpu, flash_attention_bshd, interpret_forced,
-                pallas_blocks_ok)
-            pallas_route = _on_tpu() or interpret_forced()
-        except Exception:
-            pallas_route = False
-        if pallas_route:
+        from paddle_tpu.ops.pallas._compat import on_tpu
+        from paddle_tpu.ops.pallas.flash_attention import (
+            flash_attention_bshd, interpret_forced, pallas_blocks_ok)
+
+        if on_tpu() or interpret_forced():
             ok, reason = pallas_blocks_ok(int(_t(query).shape[1]))
             if not ok:
                 # a bad FLAGS_flash_block_q/k override must not fail inside
@@ -1329,26 +1326,20 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
                 _warn_pallas_blocks_once(
                     reason, shape_sig=tuple(_t(query).shape))
             else:
-                try:
-                    q, k, v = _t(query), _t(key), _t(value)
-                    args = [q, k, v]
-                    if segment_ids is not None:
-                        args.append(_t(segment_ids))
+                # no catch around the kernel: on a TPU backend a flash
+                # kernel that fails to trace, lower or compile must raise,
+                # not quietly become the O(S^2) XLA attention below
+                q, k, v = _t(query), _t(key), _t(value)
+                args = [q, k, v]
+                if segment_ids is not None:
+                    args.append(_t(segment_ids))
 
-                    def fa(a, b, c, *s):
-                        return flash_attention_bshd(
-                            a, b, c, causal=is_causal,
-                            segment_ids=s[0] if s else None)
+                def fa(a, b, c, *s):
+                    return flash_attention_bshd(
+                        a, b, c, causal=is_causal,
+                        segment_ids=s[0] if s else None)
 
-                    return apply_op(fa, *args, name="flash_attention")
-                except Exception:
-                    if interpret_forced():
-                        # the tests' force_interpret() route exists to
-                        # exercise the kernel: swallowing a kernel failure
-                        # here would silently downgrade the parity tests
-                        # to XLA-vs-XLA
-                        raise
-                    pass  # fall back to XLA path below
+                return apply_op(fa, *args, name="flash_attention")
 
     def f(q, k, v, *extra):
         # [B,S,H,D] -> [B,H,S,D]; GQA (fewer kv heads) via grouped einsum —

@@ -1,19 +1,31 @@
-"""Shared Pallas-kernel compatibility helpers."""
+"""Shared Pallas-kernel helpers: the one platform probe, the x64 trace
+override, and the shard_map wrap Mosaic kernels need under a GSPMD mesh."""
 from __future__ import annotations
 
 import contextlib
 
-__all__ = ["x64_off", "kernel_trace_ctx"]
+import jax
+
+__all__ = ["on_tpu", "x64_off", "kernel_trace_ctx", "DATA_AXES",
+           "mesh_axes_dividing", "gspmd_mesh"]
+
+# the mesh axes a batch dim is sharded over (io.device_feed.default_batch_spec)
+DATA_AXES = ("dp", "sharding")
+
+
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU — the ONE probe every kernel
+    module routes on (Mosaic on TPU, interpret mode elsewhere). A backend
+    that fails to start raises here: turning that into "not a TPU" would
+    silently select interpret mode on a machine that has a chip."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def x64_off():
     """x64 mode (paddle int64 parity, enabled at package import) makes Pallas
-    index maps emit i64 constants Mosaic can't legalize. `jax.enable_x64` was
-    removed upstream; `jax.experimental.disable_x64` is the surviving
-    spelling of the same trace-local override."""
-    from jax.experimental import disable_x64
-
-    return disable_x64()
+    index maps emit i64 constants Mosaic can't legalize; trace the
+    pallas_call with it off."""
+    return jax.enable_x64(False)
 
 
 def kernel_trace_ctx(interpret: bool):
@@ -28,3 +40,39 @@ def kernel_trace_ctx(interpret: bool):
     context (and needs x64 off for its index types), so the TPU path keeps
     the override."""
     return contextlib.nullcontext() if interpret else x64_off()
+
+
+def mesh_axes_dividing(mesh, names, *sizes):
+    """The subset of mesh axes `names` (present, size > 1) whose combined
+    size divides every one of `sizes`, as a PartitionSpec entry (None when
+    nothing applies — that dim stays replicated)."""
+    axes, div = [], 1
+    for a in names:
+        n = int(mesh.shape.get(a, 1))
+        if n > 1 and all(s % (div * n) == 0 for s in sizes):
+            axes.append(a)
+            div *= n
+    return tuple(axes) if axes else None
+
+
+def gspmd_mesh(*args):
+    """The global mesh when `args` are being traced into a multi-device
+    GSPMD program, else None.
+
+    Mosaic kernels cannot be auto-partitioned: a `pallas_call` lowered inside
+    a `jit` that spans more than one device raises NotImplementedError
+    ("wrap the call in a shard_map"). The mesh-compiled train step is such a
+    jit, so a kernel entry that gets a mesh back here runs its pallas_call
+    under `shard_map_compat` with the layout GSPMD already gives the
+    operands (batch over DATA_AXES, heads / vocab over "mp"). Inside an
+    enclosing shard_map (bound axes) or outside any trace the kernel is
+    called as is."""
+    from paddle_tpu.distributed.collective import _bound_axes
+    from paddle_tpu.distributed.mesh import get_mesh
+
+    mesh = get_mesh()
+    if (mesh is None or mesh.size == 1
+            or not any(isinstance(a, jax.core.Tracer) for a in args)
+            or _bound_axes(tuple(mesh.axis_names))):
+        return None
+    return mesh

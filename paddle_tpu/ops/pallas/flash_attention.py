@@ -32,9 +32,12 @@ Peak memory is O(block * D) per grid step — no [S, S] materialization in
 either direction. GQA is handled by BlockSpec index maps (q-head -> kv-head
 = h // group), never by materializing repeated K/V.
 
-Falls back to interpreter mode off-TPU so the same code path is unit-tested
-on CPU (the fake-device pattern, SURVEY §4.4); `force_interpret()` pins that
-mode explicitly (the conftest fixture the tier-1 segment tests use).
+Runs through Mosaic on a TPU backend and in interpreter mode on a CPU
+backend, so the same code path is unit-tested on CPU (the fake-device
+pattern, SURVEY §4.4); `force_interpret()` pins interpret mode explicitly
+(the conftest fixture the tier-1 segment tests use). On a TPU backend a
+kernel that fails to trace, lower or compile raises — nothing here or in
+the callers retries in interpret mode or on an XLA path.
 """
 from __future__ import annotations
 
@@ -47,28 +50,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddle_tpu.ops.pallas import _compat
 from paddle_tpu.ops.pallas._compat import kernel_trace_ctx as _kernel_trace_ctx
-
-try:  # pallas TPU backend may be absent on pure-CPU installs
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
 
 __all__ = ["flash_attention_bshd", "flash_attention_bhsd",
            "segment_block_visit_counts", "pallas_blocks_ok",
            "force_interpret"]
 
 _NEG_INF = -1e30
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 class _InterpretTLS(threading.local):
@@ -93,7 +82,7 @@ def force_interpret():
 
 
 def _interpret_mode() -> bool:
-    return _interp_tls.force or not _on_tpu()
+    return _interp_tls.force or not _compat.on_tpu()
 
 
 def interpret_forced() -> bool:
@@ -140,6 +129,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int, causal: bool,
     else:
         last = num_kb
 
+    def k_seg_block(kb):                            # [1, BK] int32
+        # the ids sit along LANES, where Mosaic only takes a dynamic start
+        # it can prove a multiple of 128. Sequences under 128 (the serving
+        # engine's 64-row pack frame) are one whole K block: load it static
+        if num_kb == 1:
+            return kseg_ref[...]
+        return kseg_ref[:, pl.ds(pl.multiple_of(kb * block_k, block_k),
+                                 block_k)]
+
     def compute(kb, carry):
         m, l, acc = carry
         k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
@@ -151,8 +149,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int, causal: bool,
             k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
         if segmented:
-            k_seg_blk = kseg_ref[:, pl.ds(kb * block_k, block_k)]  # [1, BK]
-            s = jnp.where(q_seg_col == k_seg_blk, s, _NEG_INF)
+            s = jnp.where(q_seg_col == k_seg_block(kb), s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -163,7 +160,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int, causal: bool,
 
     if segmented and block_skip:
         def body(kb, carry):
-            k_seg_blk = kseg_ref[:, pl.ds(kb * block_k, block_k)]
+            k_seg_blk = k_seg_block(kb)
             needed = _seg_blocks_can_touch(q_min, q_max,
                                            jnp.min(k_seg_blk),
                                            jnp.max(k_seg_blk))
@@ -624,20 +621,43 @@ def flash_attention_bhsd(q, k, v, causal: bool = False,
         scale = 1.0 / math.sqrt(d)
     if interpret is None:
         interpret = _interpret_mode()
-    q3 = q.reshape(b * hq, s, d)
-    k3 = k.reshape(b * hkv, s, d)
-    v3 = v.reshape(b * hkv, s, d)
-    if segment_ids is None:
-        out = _flash3(q3, k3, v3, causal, scale, group, interpret)
-    else:
+    seg = None
+    if segment_ids is not None:
         seg = jnp.asarray(segment_ids, jnp.int32)
         if seg.shape != (b, s):
             raise ValueError(
                 f"segment_ids must be [batch, seq]=({b}, {s}), "
                 f"got {seg.shape}")
-        out = _flash3_seg(q3, k3, v3, seg, causal, scale, group, hq,
-                          interpret)
-    return out.reshape(b, hq, s, d)
+
+    def kernels(q, k, v, *seg):
+        b, hq, hkv = q.shape[0], q.shape[1], k.shape[1]   # this shard's
+        q3 = q.reshape(b * hq, s, d)
+        k3 = k.reshape(b * hkv, s, d)
+        v3 = v.reshape(b * hkv, s, d)
+        if seg:
+            out = _flash3_seg(q3, k3, v3, seg[0], causal, scale, group, hq,
+                              interpret)
+        else:
+            out = _flash3(q3, k3, v3, causal, scale, group, interpret)
+        return out.reshape(b, hq, s, d)
+
+    args = (q, k, v) + (() if seg is None else (seg,))
+    mesh = _compat.gspmd_mesh(q, k, v)
+    if mesh is None:
+        return kernels(*args)
+    # Mosaic under a GSPMD mesh: per shard, in the layout GSPMD gives
+    # attention operands — batch over the data axes, heads over "mp"
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.distributed.fleet.layers.mpu.mp_ops import MP_AXIS
+    from paddle_tpu.distributed.mesh import shard_map_compat
+
+    bax = _compat.mesh_axes_dividing(mesh, _compat.DATA_AXES, b)
+    hax = _compat.mesh_axes_dividing(mesh, (MP_AXIS,), hq, hkv)
+    qkv = P(bax, hax, None, None)
+    return shard_map_compat(
+        kernels, mesh, (qkv,) * 3 + (P(bax, None),) * (seg is not None),
+        qkv)(*args)
 
 
 def flash_attention_bshd(q, k, v, causal: bool = False,

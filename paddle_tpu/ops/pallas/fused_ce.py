@@ -21,9 +21,9 @@ forward OR backward:
   token count is small but the vocabulary is huge.
 * **pallas** (`variant="pallas"`): a Pallas kernel grids over
   (token-block, vocab-block) and keeps the running max/sum-exp/target/sum
-  accumulators resident in VMEM, one MXU matmul per tile; it falls back to
-  interpreter mode off-TPU (fake-device pattern, SURVEY §4.4) so tier-1 CPU
-  tests exercise the identical kernel body. Backward reuses the chunked
+  accumulators resident in VMEM, one MXU matmul per tile; on a CPU backend
+  it runs in interpreter mode (fake-device pattern, SURVEY §4.4) so tier-1
+  CPU tests exercise the identical kernel body. Backward reuses the chunked
   scan (already logits-free).
 
 * **mp-parallel softmax**: when the "mp" mesh axis is bound (shard_map — the
@@ -54,19 +54,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddle_tpu.ops.pallas import _compat
 from paddle_tpu.ops.pallas._compat import x64_off
 
 __all__ = ["fused_linear_cross_entropy_loss", "softmax_cross_entropy_loss",
            "resolve_chunks", "x64_off"]
 
 _NEG_INF = float(np.finfo(np.float32).min)
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 def _mp_info(mp_axis):
@@ -279,12 +273,16 @@ def _ce_stats_kernel(x_ref, w_ref, lab_ref, m_ref, s_ref, t_ref, sl_ref,
 def _stats_pallas(cfg: _CECfg, x, w, labels_loc, interpret=None):
     n, h = x.shape
     vloc = w.shape[1]
-    br = min(cfg.chunk_tokens, 256, n)
-    bv = min(cfg.chunk_vocab, 512, vloc)
+    # Mosaic tiles blocks in (16, 128) bf16 / (8, 128) fp32 units: the chunk
+    # heuristic bounds the scan variants' logits tile and is free to be odd
+    # (131 tokens at vocab 32000), so the kernel's own tile rounds down to
+    # the hardware unit (the padding below absorbs a short tail)
+    br = max(16, min(cfg.chunk_tokens, 256, n) // 16 * 16)
+    bv = max(128, min(cfg.chunk_vocab, 512, vloc) // 128 * 128)
     xp, lp, ni = _pad_tokens(x, labels_loc, br)
     wp, _, nj = _pad_vocab(w, None, vloc, bv)
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not _compat.on_tpu()
     kern = functools.partial(_ce_stats_kernel, bv=bv, vloc=vloc)
     stat = jax.ShapeDtypeStruct((ni * br, 128), jnp.float32)
     # labels lane-replicated to a (rows, 128) int32 tile (min int tiling)
@@ -568,7 +566,7 @@ def _resolve_cfg(n, vloc, ignore_index, label_smoothing, z_loss, chunk_tokens,
     if variant in (None, "", "auto"):
         variant = flag("fused_ce_variant")
     if variant in (None, "", "auto"):
-        variant = ("pallas" if (has_w and not has_bias and _on_tpu()
+        variant = ("pallas" if (has_w and not has_bias and _compat.on_tpu()
                                 and not fp8)
                    else "tokens")
     if fp8 and variant == "pallas":
@@ -595,10 +593,32 @@ def fused_linear_cross_entropy_loss(x, w, labels, bias=None, *,
                        True, bias is not None)
     if cfg.variant == "pallas" and bias is not None:
         cfg = cfg._replace(variant="tokens")
-    fn = _build_linear_ce(cfg)
     if bias is not None:
-        return fn(x, w, bias, labels)
-    return fn(x, w, labels)
+        return _build_linear_ce(cfg)(x, w, bias, labels)
+    mesh = _compat.gspmd_mesh(x, w) if cfg.variant == "pallas" else None
+    if mesh is None:
+        return _build_linear_ce(cfg)(x, w, labels)
+    # Mosaic under a GSPMD mesh: per shard, in the layout GSPMD gives the
+    # head — tokens over the data axes, the vocab dim of w over "mp", which
+    # is then BOUND inside the shard and selects the Megatron parallel
+    # softmax above (an undivisible vocab stays whole: no mp reduction)
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.distributed.fleet.layers.mpu.mp_ops import MP_AXIS
+    from paddle_tpu.distributed.mesh import shard_map_compat
+
+    tok = _compat.mesh_axes_dividing(mesh, _compat.DATA_AXES, x.shape[0])
+    voc = _compat.mesh_axes_dividing(mesh, (MP_AXIS,), w.shape[1])
+
+    def per_shard(xs, ws, ls):
+        c = _resolve_cfg(xs.shape[0], ws.shape[1], ignore_index,
+                         label_smoothing, z_loss, chunk_tokens, chunk_vocab,
+                         "pallas", MP_AXIS if voc else None, True, False)
+        return _build_linear_ce(c)(xs, ws, ls)
+
+    return shard_map_compat(per_shard, mesh,
+                            (P(tok, None), P(None, voc), P(tok)),
+                            P(tok))(x, w, labels)
 
 
 def softmax_cross_entropy_loss(logits, labels, *, ignore_index=-100,

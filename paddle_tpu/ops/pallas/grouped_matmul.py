@@ -48,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from paddle_tpu.ops.pallas import _compat
 from paddle_tpu.ops.pallas._compat import x64_off as _x64_off
 from paddle_tpu.ops.pallas.flash_attention import (
     _interpret_mode, _seg_blocks_can_touch, interpret_forced,
@@ -84,8 +85,7 @@ def _resolve_backend(backend: str | None) -> str:
     if backend == "auto":
         if interpret_forced():
             return "pallas"
-        on_tpu = jax.default_backend() == "tpu"
-        return "pallas" if on_tpu else "xla"
+        return "pallas" if _compat.on_tpu() else "xla"
     if backend not in ("pallas", "xla"):
         raise ValueError(f"moe_gmm_backend={backend!r}: auto|pallas|xla")
     return backend

@@ -30,12 +30,12 @@ flash kernels share — so decode compute is O(sum_b ceil(len_b / ps))
 pages, not O(batch * pages_per_seq). `page_visit_counts` runs that same
 predicate as a standalone kernel = the bench utilization counter.
 
-Off-TPU the public entry point routes to a jnp gather reference
+On a CPU backend the public entry point routes to a jnp gather reference
 (`paged_attention_reference`, identical math) the way
-F.scaled_dot_product_attention falls back to XLA; `force_interpret()`
-pins the exact Pallas kernel in interpret mode instead (the conftest
+F.scaled_dot_product_attention uses XLA there; `force_interpret()` pins
+the exact Pallas kernel in interpret mode instead (the conftest
 `paged_interpret` fixture), so tier-1 CPU runs the same kernel code the
-TPU compiles through Mosaic.
+TPU compiles through Mosaic. On a TPU backend it is always the kernel.
 """
 from __future__ import annotations
 
@@ -47,18 +47,11 @@ from contextlib import contextmanager
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from paddle_tpu.ops.pallas import _compat
 from paddle_tpu.ops.pallas._compat import x64_off as _x64_off
-from paddle_tpu.ops.pallas.flash_attention import (_on_tpu,
-                                                   _seg_blocks_can_touch)
-
-try:  # pallas TPU backend may be absent on pure-CPU installs
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from paddle_tpu.ops.pallas.flash_attention import _seg_blocks_can_touch
 
 __all__ = ["paged_attention", "paged_decode_attention",
            "paged_attention_reference", "page_visit_counts",
@@ -93,7 +86,7 @@ def interpret_forced() -> bool:
 
 
 def _interpret_mode() -> bool:
-    return _interp_tls.force or not _on_tpu()
+    return _interp_tls.force or not _compat.on_tpu()
 
 
 # ---------------------------------------------------------------------------
@@ -235,9 +228,6 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, context_lens,
         scale = 1.0 / math.sqrt(d)
     if interpret is None:
         interpret = _interpret_mode()
-    if not interpret and not _HAS_PLTPU:  # pragma: no cover
-        raise RuntimeError("pallas TPU backend unavailable; use "
-                           "paged_attention_reference or force_interpret()")
     # [B, T, Hkv, G, D] -> [B, Hkv, T*G, D]: the kernel's q block carries
     # the whole verify window, frame index recovered as row // group
     qg = (q.reshape(b, t, hkv, group, d).transpose(0, 2, 1, 3, 4)
@@ -352,11 +342,12 @@ def paged_attention(q, k_pages, v_pages, page_table, context_lens,
                     scale: float | None = None,
                     k_scales=None, v_scales=None):
     """Dispatching entry point (what the model's decode path calls): the
-    Pallas kernel on TPU or under force_interpret(); the XLA reference
-    elsewhere — the same routing contract as
-    F.scaled_dot_product_attention. ``k_scales``/``v_scales`` flow to
-    whichever path runs (in-kernel dequant of quantized pools)."""
-    if _HAS_PLTPU and (_on_tpu() or interpret_forced()):
+    Pallas kernel on a TPU backend (always — a kernel failure there raises)
+    or under force_interpret(); the XLA reference on a CPU backend — the
+    same routing contract as F.scaled_dot_product_attention.
+    ``k_scales``/``v_scales`` flow to whichever path runs (in-kernel
+    dequant of quantized pools)."""
+    if _compat.on_tpu() or interpret_forced():
         return paged_decode_attention(q, k_pages, v_pages, page_table,
                                       context_lens, scale=scale,
                                       k_scales=k_scales, v_scales=v_scales)

@@ -22,7 +22,7 @@ match the composite formula:
     dx = rstd * (dy*w - x * rstd^2/d * sum(dy*w*x, axis=-1))
     dw = sum over rows of dy * x * rstd
 
-Falls back to interpreter mode off-TPU (fake-device pattern, SURVEY §4.4).
+Runs in interpreter mode on a CPU backend (fake-device pattern, SURVEY §4.4).
 """
 from __future__ import annotations
 
@@ -32,16 +32,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddle_tpu.ops.pallas import _compat
 from paddle_tpu.ops.pallas._compat import x64_off as _x64_off
 
 __all__ = ["rmsnorm"]
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 def _rmsnorm_fwd_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -98,7 +92,7 @@ def rmsnorm(x, w, eps: float = 1e-6, block_rows: int = 0):
 def _fwd(x, w, eps, block_rows):
     rows, d = x.shape
     br = _pick_rows(rows, d, block_rows)
-    interpret = not _on_tpu()
+    interpret = not _compat.on_tpu()
     # x64 mode (paddle int64 parity, enabled at package import) makes index
     # maps emit i64 constants Mosaic can't legalize — same guard as flash
     with _x64_off():
@@ -119,7 +113,7 @@ def _bwd(eps, block_rows, res, dy):
     rows, d = x.shape
     br = _pick_rows(rows, d, block_rows)
     n_blocks = pl.cdiv(rows, br)
-    interpret = not _on_tpu()
+    interpret = not _compat.on_tpu()
     with _x64_off():
         dx, dw_acc = pl.pallas_call(
             functools.partial(_rmsnorm_bwd_kernel, eps=eps),

@@ -83,24 +83,10 @@ def ring_attention(q, k, v, axis_name: str = SEP_AXIS, causal: bool = True,
     Must be called inside shard_map with `axis_name` bound."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    # static ring size: jax.lax.axis_size does not exist on this jax; the
-    # python-int size feeds the static perm list, so read the bound axis env
-    # (or fall back to the global mesh shape, which binds the shard_map
-    # axes). An unresolvable axis must raise — silently defaulting to a
-    # 1-rank ring would skip every neighbor exchange and corrupt attention.
-    try:
-        from jax._src.core import get_axis_env
-
-        n = int(get_axis_env().axis_sizes[axis_name])
-    except Exception:
-        from paddle_tpu.distributed.mesh import get_mesh
-
-        mesh = get_mesh()
-        if mesh is None or axis_name not in mesh.shape:
-            raise ValueError(
-                f"ring_attention: axis {axis_name!r} is not bound (call "
-                f"inside shard_map over a mesh carrying it)")
-        n = int(mesh.shape[axis_name])
+    # static ring size (a python int: it feeds the static perm list). An
+    # unbound axis raises NameError here — silently defaulting to a 1-rank
+    # ring would skip every neighbor exchange and corrupt attention.
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     s_local = q.shape[1]
     qpos = idx * s_local + jnp.arange(s_local)

@@ -66,10 +66,13 @@ def _nan_poison(vals):
 
 def _abstractify(x):
     """ShapeDtypeStruct mirror of one step argument leaf (sharding kept
-    when present) — concrete arrays are donated per step, so the abstract
-    mirror is what `CompiledTrainStep.cost_analysis()` lowers against."""
+    when the array is COMMITTED to it) — concrete arrays are donated per
+    step, so the abstract mirror is what `CompiledTrainStep.cost_analysis()`
+    lowers against. An uncommitted leaf (the PRNG key, lr and step scalars)
+    goes wherever the program runs: pinning it to its current device would
+    clash with mesh-sharded parameters at lowering."""
     if hasattr(x, "shape") and hasattr(x, "dtype"):
-        sh = getattr(x, "sharding", None)
+        sh = x.sharding if getattr(x, "committed", False) else None
         try:
             return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh)
         except TypeError:
@@ -663,10 +666,22 @@ class CompiledTrainStep:
         # per-layer split of stacked group columns is DEFERRED to explicit
         # sync_params_to_model() calls — slicing here would keep a second
         # full copy of every layer's weights resident for the whole run
+        # Without a mesh the arrays are COMMITTED where they already live
+        # (host values: to the current place): the step's outputs are
+        # committed, and a first call on uncommitted inputs would give step 2
+        # another signature — one more trace and compile of the whole program.
+        def commit(v):
+            from paddle_tpu.core.device import current_jax_device
+
+            return jax.device_put(
+                v, getattr(v, "sharding", None) or current_jax_device())
+
         self._param_vals = []
         for v, spec in zip(packed_vals, self._param_specs):
             if self.mesh is not None:
                 v = jax.device_put(v, NamedSharding(self.mesh, spec))
+            else:
+                v = commit(v)
             self._param_vals.append(v)
         for p, v in zip(self._outer_params,
                         self._param_vals[:len(self._outer_params)]):
@@ -692,6 +707,8 @@ class CompiledTrainStep:
                         else:
                             sh = NamedSharding(self.mesh, sp)
                         v = jax.device_put(v, sh)
+                    else:
+                        v = commit(v)
                     st[k] = v
                     st_sh[k] = sh
                 self._opt_states.append(st)
