@@ -95,47 +95,17 @@ def log(msg: str):
     print(msg, flush=True)
 
 
-class CompileMeter:
-    """JAX's own account of compiling, via jax.monitoring: how many programs
-    were traced, the seconds spent tracing + lowering + backend-compiling,
-    and persistent-cache hits and misses. A cache hit is charged its
-    retrieval time."""
+def run_phase(summary, name, fn, *args):
+    """Run one phase and record its facts with wall and compile seconds
+    (JAX's own account of compiling: the program's compile log). No catch: a
+    phase that raises ends the run."""
+    from paddle_tpu.core.compile_cache import compile_totals
 
-    _TRACE = "/jax/core/compile/jaxpr_trace_duration"
-    _DURATIONS = (_TRACE,
-                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
-                  "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        import jax.monitoring
-
-        self.secs = 0.0
-        self.traces = 0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **_):
-        if event in self._DURATIONS:
-            self.secs += secs
-            self.traces += event == self._TRACE
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-
-def run_phase(summary, meter, name, fn, *args):
-    """Run one phase and record its facts with wall and compile seconds.
-    No catch: a phase that raises ends the run."""
     log(f"== {name}")
-    t0, c0 = time.perf_counter(), meter.secs
+    t0, c0 = time.perf_counter(), compile_totals()["secs"]
     facts = fn(*args)
     facts["wall_s"] = round(time.perf_counter() - t0, 1)
-    facts["compile_s"] = round(meter.secs - c0, 1)
+    facts["compile_s"] = round(compile_totals()["secs"] - c0, 1)
     summary["phases"][name] = facts
     log(f"   {name}: {json.dumps(facts)}")
     return facts
@@ -191,6 +161,15 @@ def step_program_text(step) -> str:
     return step._jitted.lower(*step._abstract_args).as_text()
 
 
+def has_kernel(text: str, name: str) -> bool:
+    """Whether a lowered program holds the Pallas kernel the program names
+    `name` (`pallas_call(name=...)`, ops/pallas/_compat.kernel_name)."""
+    return f'kernel_name = "{name}"' in text
+
+
+TRAIN_KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "ce_stats")
+
+
 def has_full_logits(text: str, sz: Sizes, rows: int) -> bool:
     """A [rows, seq, vocab] tensor in any training dtype — what the unfused
     head materialises. (The flat [rows*seq, vocab] form says nothing here:
@@ -204,16 +183,18 @@ def has_full_logits(text: str, sz: Sizes, rows: int) -> bool:
 # ---------------------------------------------------------------------------
 
 
-def phase_train(sz: Sizes, on_tpu: bool, state: dict, meter) -> dict:
+def phase_train(sz: Sizes, on_tpu: bool, state: dict) -> dict:
     import jax
+
+    from paddle_tpu.core.compile_cache import compile_totals
 
     dev = jax.devices()[0]
     model, opt, step = build_train_step(sz)
     ids, labels = train_batch(sz, 1)
     losses = [float(step(ids, labels, labels))]
-    traced = meter.traces
+    traced = compile_totals()["traces"]
     losses += [float(step(ids, labels, labels)) for _ in range(3)]
-    retraces = meter.traces - traced
+    retraces = compile_totals()["traces"] - traced
     log(f"   losses {losses}")
     check(all(np.isfinite(losses)), f"non-finite loss: {losses}")
     check(losses[-1] < losses[0] and all(
@@ -223,9 +204,8 @@ def phase_train(sz: Sizes, on_tpu: bool, state: dict, meter) -> dict:
     text = step_program_text(step)
     if on_tpu:
         check("tpu_custom_call" in text, "no tpu_custom_call in train step")
-        for kernel in ("_fwd_kernel", "_dq_kernel", "_dkv_kernel",
-                       "_ce_stats_kernel"):
-            check(kernel in text,
+        for kernel in TRAIN_KERNELS:
+            check(has_kernel(text, kernel),
                   f"{kernel} is not in the train step program: that Pallas "
                   f"kernel was routed around")
     check(not has_full_logits(text, sz, 1),
@@ -303,7 +283,7 @@ def phase_serve(sz: Sizes, on_tpu: bool, state: dict) -> dict:
         None, None, None).as_text()
     if on_tpu:
         check("tpu_custom_call" in decode_text
-              and "_decode_kernel" in decode_text,
+              and has_kernel(decode_text, "paged_decode"),
               "the decode program does not hold the paged Pallas kernel")
     engine.mark_warmup()
 
@@ -432,8 +412,7 @@ def phase_parity(sz: Sizes, on_tpu: bool, rehearsal: bool) -> dict:
     with kernel_mode(flash_attention):
         text, got = attn_run(True)
     if on_tpu:
-        check(all(n in text for n in ("_fwd_kernel", "_dq_kernel",
-                                      "_dkv_kernel")),
+        check(all(has_kernel(text, n) for n in TRAIN_KERNELS[:3]),
               "flash parity did not run the Pallas kernels")
     text, ref = attn_run(False)
     check("tpu_custom_call" not in text, "the XLA reference ran a kernel")
@@ -472,8 +451,8 @@ def phase_parity(sz: Sizes, on_tpu: bool, rehearsal: bool) -> dict:
     with kernel_mode(paged):
         fn = jax.jit(paged.paged_attention)
         if on_tpu:
-            check("_decode_kernel" in fn.lower(
-                qd, kp, vp, table, lens).as_text(),
+            check(has_kernel(fn.lower(qd, kp, vp, table, lens).as_text(),
+                             "paged_decode"),
                 "paged parity did not run the Pallas kernel")
         got = fn(qd, kp, vp, table, lens)
     ref = jax.jit(paged.paged_attention_reference)(qd, kp, vp, table, lens)
@@ -510,9 +489,9 @@ def phase_mesh(sz: Sizes, on_tpu: bool, state: dict) -> dict:
         lowered = step._jitted.lower(*step._abstract_args)
         text = lowered.as_text()
         if on_tpu:
-            for kernel in ("_fwd_kernel", "_dq_kernel", "_dkv_kernel",
-                           "_ce_stats_kernel"):
-                check(kernel in text, f"{kernel} not in the mesh program")
+            for kernel in TRAIN_KERNELS:
+                check(has_kernel(text, kernel),
+                      f"{kernel} not in the mesh program")
         hlo = lowered.compile().as_text()
         collectives = {op: hlo.count(f" {op}(") + hlo.count(f" {op}-start(")
                        for op in ("all-reduce", "all-gather",
@@ -558,7 +537,9 @@ def main(argv=None) -> int:
 
     import paddle_tpu as paddle
     from paddle_tpu.core import native
-    from paddle_tpu.core.compile_cache import enable_compile_cache
+    from paddle_tpu.core.compile_cache import (compile_totals,
+                                               enable_compile_cache,
+                                               start_compile_log)
 
     # -- device check first: nothing below runs on a CPU by accident ----------
     if args.rehearsal:
@@ -572,7 +553,7 @@ def main(argv=None) -> int:
                   file=sys.stderr)
             return 2
         cache_dir = enable_compile_cache()
-    meter = CompileMeter()
+    start_compile_log()   # a rehearsal keeps no cache and still counts
     dev = jax.devices()[0]
     on_tpu = dev.platform == "tpu"
     device = {"platform": dev.platform, "kind": dev.device_kind,
@@ -589,14 +570,15 @@ def main(argv=None) -> int:
 
     state: dict = {}
     t0 = time.perf_counter()
-    run_phase(summary, meter, "train", phase_train, sz, on_tpu, state, meter)
-    run_phase(summary, meter, "serve", phase_serve, sz, on_tpu, state)
-    run_phase(summary, meter, "parity", phase_parity, sz, on_tpu,
-              args.rehearsal)
-    run_phase(summary, meter, "mesh", phase_mesh, sz, on_tpu, state)
+    run_phase(summary, "train", phase_train, sz, on_tpu, state)
+    run_phase(summary, "serve", phase_serve, sz, on_tpu, state)
+    run_phase(summary, "parity", phase_parity, sz, on_tpu, args.rehearsal)
+    run_phase(summary, "mesh", phase_mesh, sz, on_tpu, state)
     summary["wall_s"] = round(time.perf_counter() - t0, 1)
-    summary["compile_s"] = round(meter.secs, 1)
-    summary["persistent_cache"] = {"hits": meter.hits, "misses": meter.misses}
+    totals = compile_totals()
+    summary["compile_s"] = round(totals["secs"], 1)
+    summary["persistent_cache"] = {"hits": totals["hits"],
+                                   "misses": totals["misses"]}
     summary["claim"] = None
     print(json.dumps(summary), flush=True)
     print(json.dumps({"ok": True, "device": device} | (

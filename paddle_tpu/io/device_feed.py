@@ -38,7 +38,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.distributed.resilience import faults
-from paddle_tpu.profiler import RecordEvent
+from paddle_tpu.observability import tracing as obs_tracing
 
 __all__ = ["DeviceFeeder", "FeederWorkerError", "prefetch_to_device",
            "BatchSpecCache", "LossFuture", "DispatchWindow",
@@ -326,14 +326,14 @@ class DeviceFeeder:
         try:
             while not self._stop.is_set():
                 phase = "collate"
-                with RecordEvent("DeviceFeeder::fetch"):
+                with obs_tracing.span("train.feed.fetch"):
                     try:
                         faults.point("feeder.collate")
                         batch = next(self._it)
                     except StopIteration:
                         break
                 phase = "device_put"
-                with RecordEvent("DeviceFeeder::place"):
+                with obs_tracing.span("train.feed.place"):
                     faults.point("feeder.device_put")
                     placed = self._place_batch(batch)
                 if not self._put(placed):

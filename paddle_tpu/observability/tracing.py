@@ -1,48 +1,57 @@
-"""Cross-component span tracing with trace-id propagation.
+"""The program's one host-span primitive, with trace-id propagation.
 
-Extends `paddle_tpu.profiler.RecordEvent` host spans into SPANS that carry
-a **trace id** across component boundaries: the router mints one per
-request, it rides the payload / the Request object (like sampling knobs)
-through replica -> engine -> scheduler -> decode step, and training steps
-emit named phase spans — so ONE exported Chrome/Perfetto file shows a
-request's (or step's) full path across threads and components.
+A span is recorded two ways at once, and costs next to nothing when neither
+is on:
+
+  * into the in-memory list of a **collection window**
+    (`start_tracing()` / `stop_tracing()`), as a Chrome `X` (complete) event
+    with `args = {trace_id, component, parent, **attrs}` — what
+    `export_chrome` writes and the benchmark's serving kind reads;
+  * onto the **profiler's clock**: every span enters a
+    `jax.profiler.TraceAnnotation(name)` while a `jax.profiler` session runs,
+    so it is an event of `/host:CPU` in the same xplane as the device's
+    `XLA Ops` and an idle gap of the device can be named by what the host was
+    doing. No call of this module is needed for that: a session is enough.
+    (`jax` is looked up only if something else already imported it — the
+    module itself stays dependency-free host code, importable from the
+    scheduler/router hot paths.)
 
 Contract:
 
-  * `start_tracing()` / `stop_tracing()` bound a collection window (the
-    module-level `_ACTIVE` flag keeps the off-path to one attribute read —
-    the <2% overhead gate in bench.py's observability arm measures with it
-    ON);
-  * `span(name, component=..., trace_id=..., **attrs)` context manager
-    records a Chrome `X` (complete) event with `args = {trace_id,
-    component, **attrs}`; `trace_id=None` inherits the thread's current
-    trace context;
-  * `trace_context(trace_id)` sets that thread-local context — a worker
+  * `span(name, component=..., trace_id=..., **attrs)` is the context
+    manager; `trace_id=None` inherits the thread's current trace context.
+    A recorded span names the span that encloses it on its thread
+    (`args.parent`), so a layer's self time is its span less its children;
+  * `record_span(name, begin_ns, dur_ns, args)` records a span measured by
+    the caller (a queue wait known only at its end). Under a profiler
+    session it leaves a zero-length annotation carrying `dur_us`, since a
+    past interval cannot be entered;
+  * `tracing_active()` is true inside a collection window OR a profiler
+    session: call sites guard attribute lists (`trace_ids=[...]`) with it;
+  * `trace_context(trace_id)` sets the thread-local context — a worker
     picking up request R wraps its work in `trace_context(R.trace_id)` and
-    every span (including plain profiler `RecordEvent`s, which mirror in
-    here when tracing is active) lands correlated;
-  * `export_chrome(path, device_trace_dir=...)` writes one
-    ``{"traceEvents": [...]}`` JSON, merging any Chrome-format device
-    traces `jax.profiler` produced under `device_trace_dir`
-    (``**/*.trace.json[.gz]`` — TensorBoard's plugins/profile layout), so
-    host spans and XLA device activity share one timeline.
-
-Everything here is dependency-free host code — importable from the
-scheduler/router hot paths without pulling jax.
+    every span inside lands correlated. The router mints one id per request;
+    it rides the payload / the Request object through replica -> engine ->
+    scheduler -> decode step;
+  * `profiler.RecordEvent` and `profiler.Profiler` are thin wrappers over
+    this module: there is ONE store of host spans;
+  * `export_chrome(path)` writes one ``{"traceEvents": [...]}`` JSON of the
+    collected spans (host clock). Host spans and device activity on one
+    timeline come from the profiler's own xplane, where the spans already
+    are.
 """
 from __future__ import annotations
 
 import contextlib
-import glob
-import gzip
 import json
 import os
+import sys
 import threading
 import time
 import uuid
 
-__all__ = ["start_tracing", "stop_tracing", "tracing_active", "span",
-           "trace_context", "current_trace_id", "new_trace_id",
+__all__ = ["start_tracing", "stop_tracing", "tracing_active", "collecting",
+           "span", "trace_context", "current_trace_id", "new_trace_id",
            "record_span", "export_chrome", "events_snapshot"]
 
 _ACTIVE = False
@@ -54,6 +63,21 @@ _tls = threading.local()
 # sandboxes) — cache it; a fork gets a fresh module state anyway under
 # the spawn start-method every paddle_tpu multiproc path uses
 _PID = os.getpid()
+_annotation = None   # jax.profiler.TraceAnnotation, once jax is imported
+_SCALARS = (str, int, float)
+
+
+def _session():
+    """`jax.profiler.TraceAnnotation` while a profiler session is running,
+    else None. Never imports jax: without it there is no session."""
+    global _annotation
+    ann = _annotation
+    if ann is None:
+        mod = sys.modules.get("jax.profiler")
+        ann = _annotation = getattr(mod, "TraceAnnotation", None)
+        if ann is None:
+            return None
+    return ann if ann.is_enabled() else None
 
 
 def new_trace_id() -> str:
@@ -61,7 +85,9 @@ def new_trace_id() -> str:
 
 
 def tracing_active() -> bool:
-    return _ACTIVE
+    """True when a span would be recorded anywhere: in a collection window
+    or under a profiler session."""
+    return _ACTIVE or _session() is not None
 
 
 def start_tracing():
@@ -80,9 +106,14 @@ def stop_tracing() -> list:
         return list(_events)
 
 
-def events_snapshot() -> list:
+def collecting() -> bool:
+    """True inside a collection window (the in-memory list is filling)."""
+    return _ACTIVE
+
+
+def events_snapshot(since: int = 0) -> list:
     with _lock:
-        return list(_events)
+        return _events[since:]
 
 
 def reset():
@@ -100,8 +131,8 @@ def current_trace_id() -> str | None:
 
 @contextlib.contextmanager
 def trace_context(trace_id: str | None):
-    """Bind `trace_id` as this thread's current trace — spans (and
-    mirrored RecordEvents) inside inherit it. None is a no-op bind."""
+    """Bind `trace_id` as this thread's current trace — spans inside
+    inherit it. None is a no-op bind."""
     prev = getattr(_tls, "trace_id", None)
     _tls.trace_id = trace_id if trace_id is not None else prev
     try:
@@ -110,31 +141,48 @@ def trace_context(trace_id: str | None):
         _tls.trace_id = prev
 
 
-def record_span(name: str, begin_ns: int, dur_ns: int,
-                args: dict | None = None):
-    """Low-level sink (profiler.RecordEvent mirrors through this): one
-    Chrome complete event; the thread's current trace id is attached when
-    the caller didn't set one."""
-    if not _ACTIVE:
-        return
-    a = dict(args) if args else {}
-    if "trace_id" not in a:
+def _store(name: str, begin_ns: int, dur_ns: int, args: dict):
+    """One Chrome complete event into the window's list; the thread's
+    current trace id is attached when the caller set none."""
+    if "trace_id" not in args:
         tid = getattr(_tls, "trace_id", None)
         if tid is not None:
-            a["trace_id"] = tid
+            args["trace_id"] = tid
     ev = {"name": name, "ph": "X", "ts": begin_ns / 1e3,
           "dur": dur_ns / 1e3, "pid": _PID,
-          "tid": threading.get_ident(), "args": a}
+          "tid": threading.get_ident(), "args": args}
     with _lock:
         if len(_events) < _MAX_EVENTS:
             _events.append(ev)
 
 
+def _scalars(args: dict) -> dict:
+    """What a TraceAnnotation can carry as stats: lists (`trace_ids`) stay
+    in the window's list only."""
+    return {k: v for k, v in args.items() if isinstance(v, _SCALARS)}
+
+
+def record_span(name: str, begin_ns: int, dur_ns: int,
+                args: dict | None = None):
+    """A span the caller measured itself (`time.perf_counter_ns()` at its
+    begin, its length), recorded at its END."""
+    ann = _session()
+    if ann is not None:
+        with ann(name, dur_us=dur_ns // 1000, **_scalars(args or {})):
+            pass
+    if _ACTIVE:
+        args = dict(args) if args else {}
+        parent = getattr(_tls, "span", None)
+        if parent is not None:
+            args.setdefault("parent", parent)
+        _store(name, begin_ns, dur_ns, args)
+
+
 class span:
-    """Context manager recording one span when tracing is active. With
-    `bind=True` (default) the span also binds its trace id as the thread
-    context for its duration, so nested spans (and plain RecordEvents)
-    correlate. Pass `bind=False` when the span wraps a GENERATOR's
+    """Context manager recording one span (module docstring). With
+    `bind=True` (default) the span also binds its trace id, and its own name
+    as the enclosing span, as the thread context for its duration, so nested
+    spans correlate. Pass `bind=False` when the span wraps a GENERATOR's
     lifetime (e.g. the router's per-request stream): a suspended
     generator's `with` stays entered across unrelated work on the
     consumer thread, and interleaved generators would restore the
@@ -142,7 +190,7 @@ class span:
     not own the thread context."""
 
     __slots__ = ("name", "component", "trace_id", "attrs", "bind",
-                 "_begin", "_prev")
+                 "_begin", "_prev", "_parent", "_ann")
 
     def __init__(self, name: str, component: str = "",
                  trace_id: str | None = None, bind: bool = True, **attrs):
@@ -153,76 +201,61 @@ class span:
         self.attrs = attrs
         self._begin = None
         self._prev = None
+        self._parent = None
+        self._ann = None
+
+    def _args(self) -> dict:
+        args = dict(self.attrs)
+        if self.component:
+            args["component"] = self.component
+        if self.trace_id is not None:
+            args["trace_id"] = self.trace_id
+        return args
 
     def __enter__(self):
+        ann = _session()
+        if ann is not None:
+            self._ann = ann(self.name, **_scalars(self._args()))
+            self._ann.__enter__()
         if _ACTIVE:
             self._begin = time.perf_counter_ns()
-            if self.trace_id is not None and self.bind:
-                self._prev = getattr(_tls, "trace_id", None)
-                _tls.trace_id = self.trace_id
+            self._parent = getattr(_tls, "span", None)
+            if self.bind:
+                _tls.span = self.name
+                if self.trace_id is not None:
+                    self._prev = getattr(_tls, "trace_id", None)
+                    _tls.trace_id = self.trace_id
         return self
 
     def __exit__(self, *a):
+        if self._ann is not None:
+            self._ann.__exit__(*a)
+            self._ann = None
         if self._begin is not None:
-            args = dict(self.attrs)
-            if self.component:
-                args["component"] = self.component
-            if self.trace_id is not None:
-                args["trace_id"] = self.trace_id
-                if self.bind:
+            dur = time.perf_counter_ns() - self._begin
+            args = self._args()
+            if self.bind:
+                _tls.span = self._parent
+                if self.trace_id is not None:
                     _tls.trace_id = self._prev
-            record_span(self.name, self._begin,
-                        time.perf_counter_ns() - self._begin, args)
+            if self._parent is not None:
+                args["parent"] = self._parent
+            if _ACTIVE:
+                _store(self.name, self._begin, dur, args)
             self._begin = None
         return False
 
 
-def _device_trace_events(device_trace_dir: str) -> list:
-    """Chrome events from a jax.profiler trace directory, when the backend
-    exported Chrome-format traces (TensorBoard layout:
-    ``<dir>/plugins/profile/<run>/*.trace.json[.gz]``). xplane-only dumps
-    merge nothing — the host timeline still stands alone."""
-    out = []
-    for pat in ("**/*.trace.json", "**/*.trace.json.gz"):
-        for p in glob.glob(os.path.join(device_trace_dir, pat),
-                           recursive=True):
-            try:
-                if p.endswith(".gz"):
-                    with gzip.open(p, "rt") as f:
-                        data = json.load(f)
-                else:
-                    with open(p) as f:
-                        data = json.load(f)
-            except (OSError, ValueError) as e:
-                out.append({"name": f"device-trace-unreadable: {p}: {e}",
-                            "ph": "i", "ts": 0, "pid": 0, "tid": 0,
-                            "s": "g"})
-                continue
-            evs = (data.get("traceEvents", data)
-                   if isinstance(data, dict) else data)
-            if isinstance(evs, list):
-                out.extend(e for e in evs if isinstance(e, dict))
-    return out
-
-
-def export_chrome(path: str, device_trace_dir: str | None = None,
-                  extra_events: list | None = None) -> dict:
-    """Write the collected spans (plus optional merged device trace and
-    caller-supplied events) as ONE Chrome trace file. Returns summary
-    counts {host_events, device_events, path}."""
-    with _lock:
-        events = list(_events)
+def export_chrome(path: str, extra_events: list | None = None) -> dict:
+    """Write the collected spans (plus caller-supplied events) as ONE Chrome
+    trace file on the host's clock. Returns {host_events, path}."""
+    events = events_snapshot()
     n_host = len(events)
     if extra_events:
         events.extend(extra_events)
-    n_dev = 0
-    if device_trace_dir is not None and os.path.isdir(device_trace_dir):
-        dev = _device_trace_events(device_trace_dir)
-        n_dev = len(dev)
-        events.extend(dev)
     d = os.path.dirname(os.path.abspath(path))
     os.makedirs(d, exist_ok=True)
     with open(path, "w") as f:
         json.dump({"traceEvents": events,
                    "displayTimeUnit": "ms"}, f)
-    return {"host_events": n_host, "device_events": n_dev, "path": path}
+    return {"host_events": n_host, "path": path}

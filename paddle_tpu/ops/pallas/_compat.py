@@ -6,8 +6,8 @@ import contextlib
 
 import jax
 
-__all__ = ["on_tpu", "x64_off", "kernel_trace_ctx", "DATA_AXES",
-           "mesh_axes_dividing", "gspmd_mesh"]
+__all__ = ["on_tpu", "x64_off", "kernel_trace_ctx", "kernel_name",
+           "DATA_AXES", "mesh_axes_dividing", "gspmd_mesh"]
 
 # the mesh axes a batch dim is sharded over (io.device_feed.default_batch_spec)
 DATA_AXES = ("dp", "sharding")
@@ -40,6 +40,14 @@ def kernel_trace_ctx(interpret: bool):
     context (and needs x64 off for its index types), so the TPU path keeps
     the override."""
     return contextlib.nullcontext() if interpret else x64_off()
+
+
+def kernel_name(name: str) -> dict:
+    """The `pallas_call` keywords that give a kernel its stable name: Mosaic
+    writes them into the custom call as `kernel_name` and `kernel_metadata`,
+    which is how a device trace tells one kernel from another (PERF.md
+    section 3 says where each arrives on a v5e)."""
+    return {"name": name, "metadata": {"kernel": name}}
 
 
 def mesh_axes_dividing(mesh, names, *sizes):

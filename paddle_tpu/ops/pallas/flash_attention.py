@@ -295,6 +295,7 @@ def _flash_fwd(q, k, v, seg, causal: bool, scale: float, group: int,
                 jax.ShapeDtypeStruct((bh, s, 1), jnp.float32),
             ],
             interpret=interpret,
+            **_compat.kernel_name("flash_fwd"),
         )(*args)
     return out, lse[..., 0]
 
@@ -452,6 +453,7 @@ def _flash_bwd(q, k, v, seg, out, lse, do, causal: bool, scale: float,
             out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             out_shape=jax.ShapeDtypeStruct((bhq, s, d), jnp.float32),
             interpret=interpret,
+            **_compat.kernel_name("flash_dq"),
         )(*dq_args)
 
         # trailing grid dim walks (group, q_blocks) group-major so each kv head
@@ -492,6 +494,7 @@ def _flash_bwd(q, k, v, seg, out, lse, do, causal: bool, scale: float,
                 jax.ShapeDtypeStruct((bhkv, s, d), jnp.float32),
             ],
             interpret=interpret,
+            **_compat.kernel_name("flash_dkv"),
         )(*dkv_args)
 
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
@@ -551,6 +554,7 @@ def segment_block_visit_counts(segment_ids, block_q: int | None = None,
             out_specs=pl.BlockSpec((1, 1, 1), lambda r, i: (r, i, 0)),
             out_shape=jax.ShapeDtypeStruct((b, s // block_q, 1), jnp.float32),
             interpret=interpret,
+            **_compat.kernel_name("flash_block_count"),
         )(seg)
     return cnt[..., 0].astype(jnp.int32)
 

@@ -297,6 +297,7 @@ def _stats_pallas(cfg: _CECfg, x, w, labels_loc, interpret=None):
             out_specs=[pl.BlockSpec((br, 128), lambda i, j: (i, 0))] * 4,
             out_shape=[stat] * 4,
             interpret=interpret,
+            **_compat.kernel_name("ce_stats"),
         )(xp, wp, lab)
     return tuple(a[:n, 0] for a in (m, s, t, sl))
 

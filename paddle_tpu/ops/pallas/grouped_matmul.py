@@ -150,6 +150,7 @@ def _gmm_fwd_pallas(x, w, gids, block_rows, interpret):
             out_specs=pl.BlockSpec((block_rows, h), lambda i, g: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((m, h), jnp.float32),
             interpret=interpret,
+            **_compat.kernel_name("grouped_matmul"),
         )(gid2, x, w)
 
 
@@ -169,6 +170,7 @@ def _gmm_dw_pallas(x, dy, gids, num_groups, block_rows, interpret):
             out_specs=pl.BlockSpec((1, d, h), lambda g, i: (g, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((num_groups, d, h), jnp.float32),
             interpret=interpret,
+            **_compat.kernel_name("grouped_matmul_dw"),
         )(gid2, x, dy)
 
 
@@ -318,6 +320,7 @@ def grouped_matmul_visit_counts(gids, num_groups: int, block_rows: int,
             out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((m // block_rows, 1), jnp.float32),
             interpret=interpret,
+            **_compat.kernel_name("grouped_matmul_block_count"),
         )(gids.reshape(1, m))
     return cnt[:, 0].astype(jnp.int32)
 

@@ -274,6 +274,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, context_lens,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, hkv, tg, d), q.dtype),
             interpret=interpret,
+            **_compat.kernel_name("paged_decode"),
         )(jnp.asarray(context_lens, jnp.int32),
           jnp.asarray(page_table, jnp.int32), *operands)
     out = (out.reshape(b, hkv, t, group, d).transpose(0, 2, 1, 3, 4)
@@ -395,5 +396,6 @@ def page_visit_counts(context_lens, page_size: int, pages_per_seq: int,
             out_specs=pl.BlockSpec((1, 1), lambda r: (r, 0)),
             out_shape=jax.ShapeDtypeStruct((b, 1), jnp.float32),
             interpret=interpret,
+            **_compat.kernel_name("paged_block_count"),
         )(lens)
     return cnt[:, 0].astype(jnp.int32)

@@ -104,6 +104,7 @@ def _fwd(x, w, eps, block_rows):
             out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
             interpret=interpret,
+            **_compat.kernel_name("rmsnorm_fwd"),
         )(x, w.reshape(1, d))
     return out, (x, w)
 
@@ -126,6 +127,7 @@ def _bwd(eps, block_rows, res, dy):
             out_shape=[jax.ShapeDtypeStruct((rows, d), x.dtype),
                        jax.ShapeDtypeStruct((8, d), jnp.float32)],
             interpret=interpret,
+            **_compat.kernel_name("rmsnorm_bwd"),
         )(x, w.reshape(1, d), dy)
     return dx, dw_acc[0].astype(w.dtype)
 

@@ -17,6 +17,7 @@ training is stochastically correct (the RNGStatesTracker analog under jit).
 """
 from __future__ import annotations
 
+import time
 from functools import partial
 from typing import Callable, Sequence
 
@@ -31,6 +32,7 @@ from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.distributed.fleet import rng as fleet_rng
 from paddle_tpu.distributed.mesh import get_mesh
 from paddle_tpu.distributed.resilience import faults
+from paddle_tpu.observability import tracing as obs_tracing
 
 __all__ = ["CompiledTrainStep", "functional_call", "init_opt_states",
            "apply_optimizer_update"]
@@ -198,31 +200,40 @@ def apply_optimizer_update(optimizer, params, grads, states, lr, step_i):
              for p, g in zip(params, grads)]
     clip = getattr(optimizer, "_grad_clip", None)
     if clip is not None:
-        from paddle_tpu.nn.clip import (ClipGradByGlobalNorm, ClipGradByNorm,
-                                        ClipGradByValue)
-
-        if isinstance(clip, ClipGradByGlobalNorm):
-            gn = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                              for g in grads))
-            f = jnp.where(gn > clip.clip_norm,
-                          clip.clip_norm / jnp.maximum(gn, 1e-12), 1.0)
-            grads = [g * f.astype(g.dtype) for g in grads]
-        elif isinstance(clip, ClipGradByNorm):
-            out = []
-            for g in grads:
-                n = jnp.sqrt(jnp.sum(jnp.square(g.astype(jnp.float32))))
-                f = jnp.where(n > clip.clip_norm,
-                              clip.clip_norm / jnp.maximum(n, 1e-12), 1.0)
-                out.append(g * f.astype(g.dtype))
-            grads = out
-        elif isinstance(clip, ClipGradByValue):
-            grads = [jnp.clip(g, clip.min, clip.max) for g in grads]
+        with jax.named_scope("clip"):
+            grads = _clip_grads(clip, grads)
     new_p, new_s = [], []
-    for pv, gv, st in zip(params, grads, states):
-        np_, ns_ = optimizer._update(pv, gv, st, lr, step_i)
-        new_p.append(np_)
-        new_s.append(ns_)
+    with jax.named_scope("optimizer"):
+        for pv, gv, st in zip(params, grads, states):
+            np_, ns_ = optimizer._update(pv, gv, st, lr, step_i)
+            new_p.append(np_)
+            new_s.append(ns_)
     return new_p, new_s
+
+
+def _clip_grads(clip, grads):
+    """nn.clip semantics (global norm / per-tensor norm / value) on raw
+    arrays."""
+    from paddle_tpu.nn.clip import (ClipGradByGlobalNorm, ClipGradByNorm,
+                                    ClipGradByValue)
+
+    if isinstance(clip, ClipGradByGlobalNorm):
+        gn = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                          for g in grads))
+        f = jnp.where(gn > clip.clip_norm,
+                      clip.clip_norm / jnp.maximum(gn, 1e-12), 1.0)
+        return [g * f.astype(g.dtype) for g in grads]
+    if isinstance(clip, ClipGradByNorm):
+        out = []
+        for g in grads:
+            n = jnp.sqrt(jnp.sum(jnp.square(g.astype(jnp.float32))))
+            f = jnp.where(n > clip.clip_norm,
+                          clip.clip_norm / jnp.maximum(n, 1e-12), 1.0)
+            out.append(g * f.astype(g.dtype))
+        return out
+    if isinstance(clip, ClipGradByValue):
+        return [jnp.clip(g, clip.min, clip.max) for g in grads]
+    return grads
 
 
 def _param_pspec(p: Tensor, mesh: Mesh | None) -> PartitionSpec:
@@ -578,6 +589,11 @@ class CompiledTrainStep:
                                  if metrics_every is None else metrics_every)
         self._async_count = 0
         self._window = DispatchWindow(dispatch_window)
+        # host seconds of __call__ by part, cumulative (host_counters())
+        self._host_s = {"place": 0.0, "build": 0.0, "dispatch": 0.0,
+                        "run_ahead_wait": 0.0}
+        self._builds = 0
+        self._calls = 0
 
         # packed layout: [outer params..., one stacked array per group column]
         packed_vals = [p._value for p in self._outer_params]
@@ -849,7 +865,10 @@ class CompiledTrainStep:
             full = list(param_vals)
             for i, v in zip(trainable_idx, train_vals):
                 full[i] = v
-            loss = run_loss(full, fp8_s)
+            # scopes name HLO metadata only (docs/observability.md): the
+            # backward is `transpose(jvp(loss))` by JAX's own naming
+            with jax.named_scope("loss"):
+                loss = run_loss(full, fp8_s)
             moe_vec = moe_stats() if self._moe_layers else None
             # float16 loss scaling happens INSIDE the differentiated fn so
             # the whole backward benefits; the aux output reports the
@@ -948,7 +967,8 @@ class CompiledTrainStep:
 
             for j, i in enumerate(trainable_idx):
                 st = streamed_state(i)
-                np_, ns_ = one_update(j, i, st)
+                with jax.named_scope("optimizer"):
+                    np_, ns_ = one_update(j, i, st)
                 if found_inf is not None:
                     # inf/nan grads (or an unhealthy anomaly-detected step)
                     # skip the WHOLE update: params and moments keep their
@@ -1045,14 +1065,22 @@ class CompiledTrainStep:
         callers control how often dispatch is broken (`metrics_every`).
         Pre-placed inputs (a DeviceFeeder batch) whose sharding already
         matches skip the device_put entirely."""
-        from paddle_tpu.profiler import RecordEvent
-
         named = len(batch) == 1 and isinstance(batch[0], dict)
         if named and "labels" not in batch[0]:
             raise ValueError(
                 "a dict batch must carry a 'labels' entry (it feeds both "
                 f"the model and loss_fn); got keys {sorted(batch[0])}")
-        with RecordEvent("CompiledTrainStep::place"):
+        # xprof's step view; the spans inside are the host's share of a step
+        # (docs/observability.md), each summed into host_counters()
+        with jax.profiler.StepTraceAnnotation("train.call",
+                                              step_num=self._step_i + 1):
+            return self._call(batch, named)
+
+    def _call(self, batch, named):
+        clock = time.perf_counter
+        host = self._host_s
+        t0 = clock()
+        with obs_tracing.span("train.place"):
             if named:
                 keys = sorted(batch[0])
                 flat, moved = self._spec_cache.place(
@@ -1061,10 +1089,35 @@ class CompiledTrainStep:
             else:
                 vals, moved = self._spec_cache.place(batch)
             self.h2d_transfers += moved
-        if self._jitted is None:
-            if self.fp8_policy != "none" and self._fp8_states is None:
-                self._discover_fp8(vals)
-            self._build()
+        t1 = clock()
+        host["place"] += t1 - t0
+        building = self._jitted is None
+        if building:
+            with obs_tracing.span("train.build", part="program"):
+                if self.fp8_policy != "none" and self._fp8_states is None:
+                    self._discover_fp8(vals)
+                self._build()
+            self._builds += 1
+        with obs_tracing.span("train.dispatch", step=self._step_i + 1):
+            loss = self._dispatch_step(vals, building)
+        t0, t1 = t1, clock()
+        # the first call is the program's construction, trace and compile
+        host["build" if building else "dispatch"] += t1 - t0
+        # bounded run-ahead: block on the loss of step N-window before
+        # returning, so at most `window` compiled steps are queued on-device
+        with obs_tracing.span("train.run_ahead_wait"):
+            self._window.admit(loss)
+        host["run_ahead_wait"] += clock() - t1
+        self._calls += 1
+        if self.optimizer is not None:
+            _innermost_opt(self.optimizer)._step_count = self._step_i
+        return Tensor(loss)
+
+    def _dispatch_step(self, vals, building: bool):
+        """Everything between the placed batch and the enqueued step: the
+        step's key and learning rate, the call of the compiled program (on
+        the first call its trace and compile: `train.build`), and taking
+        over its outputs."""
         self._step_i += 1
         self._key, sub = jax.random.split(self._key)
         lr = jnp.asarray(
@@ -1078,67 +1131,60 @@ class CompiledTrainStep:
                 lr = jnp.asarray(float("nan"), jnp.float32)
         extended = (self.fp8_policy != "none" or self._scaler is not None
                     or self._anomaly)
-        with RecordEvent("CompiledTrainStep::dispatch",
-                         attrs={"step": self._step_i}):
-            if extended:
-                scale_arr = jnp.asarray(
-                    self._scaler._scale if self._scaler is not None else 1.0,
-                    jnp.float32)
-                args = (self._param_vals, self._opt_states, vals, sub, lr,
-                        jnp.asarray(self._step_i, jnp.int32),
-                        self._fp8_states if self._fp8_states is not None
-                        else [],
-                        scale_arr)
-            else:
-                args = (self._param_vals, self._opt_states, vals, sub, lr,
-                        jnp.asarray(self._step_i, jnp.int32))
-            if self._abstract_args is None:
-                # abstract (shape, dtype, sharding) mirror of the step's
-                # arguments — what cost_analysis() lowers against later
-                # (the concrete arrays are about to be donated)
-                self._abstract_args = jax.tree_util.tree_map(
-                    _abstractify, args)
+        if extended:
+            scale_arr = jnp.asarray(
+                self._scaler._scale if self._scaler is not None else 1.0,
+                jnp.float32)
+            args = (self._param_vals, self._opt_states, vals, sub, lr,
+                    jnp.asarray(self._step_i, jnp.int32),
+                    self._fp8_states if self._fp8_states is not None
+                    else [],
+                    scale_arr)
+        else:
+            args = (self._param_vals, self._opt_states, vals, sub, lr,
+                    jnp.asarray(self._step_i, jnp.int32))
+        if self._abstract_args is None:
+            # abstract (shape, dtype, sharding) mirror of the step's
+            # arguments — what cost_analysis() lowers against later
+            # (the concrete arrays are about to be donated)
+            self._abstract_args = jax.tree_util.tree_map(
+                _abstractify, args)
+        if building:
+            with obs_tracing.span("train.build", part="compile"):
+                outs = self._dispatch(*args)
+        else:
             outs = self._dispatch(*args)
-            step_metrics = None
-            if self._telemetry:
-                step_metrics = outs[-1]
-                outs = outs[:-1]
-            if extended:
-                (loss, self._param_vals, self._opt_states, new_fp8,
-                 found) = outs
-                if self.fp8_policy != "none":
-                    self._fp8_states = new_fp8
-                if self._scaler is not None:
-                    # settle the scaler state machine lazily: flags are read
-                    # only once their device value is ready, so async
-                    # dispatch never blocks here (drain() settles the rest)
-                    self._pending_inf.append(found)
-                    self._settle_scaler(block=False)
-                if self._anomaly:
-                    # same lazy contract for the health scalar: the detector
-                    # only sees READY values, so step_async run-ahead is
-                    # never broken by detection
-                    self._pending_health.append((self._step_i, loss, found))
-                    self.settle_anomalies(block=False)
-            else:
-                loss, self._param_vals, self._opt_states = outs
-            if step_metrics is not None:
-                # same lazy contract as health/found_inf: the dict's device
-                # scalars settle once ready (drain() settles all); the wall
-                # time stamps host-side dispatch pacing
-                import time as _time
-
-                self._pending_metrics.append(
-                    (self._step_i, step_metrics, _time.perf_counter()))
-                self.settle_metrics(block=False)
-        # bounded run-ahead: block on the loss of step N-window before
-        # returning, so at most `window` compiled steps are queued on-device
-        self._window.admit(loss)
-        if self.optimizer is not None:
-            _innermost_opt(self.optimizer)._step_count = self._step_i
-            if hasattr(self.optimizer._lr, "step") and not isinstance(self.optimizer._lr, float):
-                pass  # schedulers stepped by caller, matching eager semantics
-        return Tensor(loss)
+        step_metrics = None
+        if self._telemetry:
+            step_metrics = outs[-1]
+            outs = outs[:-1]
+        if extended:
+            (loss, self._param_vals, self._opt_states, new_fp8,
+             found) = outs
+            if self.fp8_policy != "none":
+                self._fp8_states = new_fp8
+            if self._scaler is not None:
+                # settle the scaler state machine lazily: flags are read
+                # only once their device value is ready, so async
+                # dispatch never blocks here (drain() settles the rest)
+                self._pending_inf.append(found)
+                self._settle_scaler(block=False)
+            if self._anomaly:
+                # same lazy contract for the health scalar: the detector
+                # only sees READY values, so step_async run-ahead is
+                # never broken by detection
+                self._pending_health.append((self._step_i, loss, found))
+                self.settle_anomalies(block=False)
+        else:
+            loss, self._param_vals, self._opt_states = outs
+        if step_metrics is not None:
+            # same lazy contract as health/found_inf: the dict's device
+            # scalars settle once ready (drain() settles all); the wall
+            # time stamps host-side dispatch pacing
+            self._pending_metrics.append(
+                (self._step_i, step_metrics, time.perf_counter()))
+            self.settle_metrics(block=False)
+        return loss
 
     def step_async(self, *batch):
         """Dispatch one step and return a LossFuture — the deferred-read
@@ -1203,6 +1249,21 @@ class CompiledTrainStep:
     @property
     def collects_metrics(self) -> bool:
         return self._telemetry
+
+    def host_counters(self) -> dict:
+        """Cumulative host-side account of `__call__`, telemetry on or off:
+        seconds spent placing batches (`train.place`), dispatching
+        (`train.dispatch`: key, learning rate, the call, taking over the
+        outputs), waiting on the run-ahead window (`train.run_ahead_wait`)
+        and building (`train.build`: the first call's program construction,
+        trace and compile), with the number of calls (`steps`) and builds,
+        and what JAX's compile log (`core.compile_cache`) holds for this
+        class's program, process-wide."""
+        from paddle_tpu.core.compile_cache import compile_totals
+
+        return {"steps": self._calls, "builds": self._builds,
+                **{f"{k}_s": v for k, v in self._host_s.items()},
+                "compile": compile_totals("jit(_step_fn)")}
 
     def cost_analysis(self) -> dict:
         """XLA's own cost model for ONE compiled step (flops, bytes

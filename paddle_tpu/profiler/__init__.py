@@ -1,29 +1,27 @@
 """Profiler (reference: python/paddle/profiler/profiler.py:346 + C++ profiler
 paddle/fluid/platform/profiler/profiler.h:47).
 
-TPU-native: host-side RecordEvent spans (the HostTracer analog) + optional
-jax.profiler device traces (XLA/xplane, viewable in TensorBoard/xprof — the
-CudaTracer/CUPTI analog). Chrome-trace export for the host timeline.
+TPU-native: the paddle-shaped face of `paddle_tpu.observability.tracing`,
+the program's ONE store of host spans (the HostTracer analog), plus
+`device_trace`, a `jax.profiler` session (XLA/xplane, viewable in
+TensorBoard/xprof — the CudaTracer/CUPTI analog) in which every span is a
+`TraceAnnotation` on the device trace's own clock.
 """
 from __future__ import annotations
 
 import contextlib
 import json
 import os
-import threading
 import time
 from enum import Enum
 from typing import Callable
+
+from paddle_tpu.observability import tracing as _tracing
 
 __all__ = [
     "Profiler", "ProfilerTarget", "RecordEvent", "make_scheduler",
     "export_chrome_tracing", "SummaryView",
 ]
-
-try:  # the tracing mirror (dependency-free host code; see RecordEvent)
-    from paddle_tpu.observability import tracing as _tracing
-except ImportError:  # pragma: no cover - partial installs
-    _tracing = None
 
 
 class ProfilerTarget(Enum):
@@ -43,60 +41,23 @@ class SummaryView(Enum):
     MemoryView = 6
 
 
-class _Collector:
-    """Process-wide event sink (NOT thread-local): background workers —
-    DeviceFeeder placement, DataLoader prefetchers — must land in the same
-    trace as the main loop; events carry tid, so the chrome timeline still
-    separates threads."""
-
-    def __init__(self):
-        self.events = []
-        self.active = False
-        self.lock = threading.Lock()
-
-
-_collector = _Collector()
-_PID = os.getpid()
-
-
 class RecordEvent:
-    """Host event annotation (reference: platform/profiler/event_tracing.h).
-
-    Doubles as the span primitive of the unified observability plane:
-    when `paddle_tpu.observability.tracing` has an active collection
-    window, every RecordEvent mirrors in there too — carrying the
-    thread's current trace id (`tracing.trace_context`), so existing
-    annotations (CompiledTrainStep::place/dispatch, DeviceFeeder spans)
-    correlate with router/engine request spans in ONE exported file
-    without any call-site change."""
+    """Host event annotation (reference: platform/profiler/event_tracing.h):
+    `tracing.span` under paddle's name, with explicit `begin()` / `end()`.
+    It lands in the active collection window carrying the thread's trace id
+    and, under a profiler session, in the xplane."""
 
     def __init__(self, name: str, event_type=None, attrs: dict | None = None):
         self.name = name
-        self.attrs = attrs
-        self._begin = None
+        self._span = _tracing.span(name)
+        if attrs:
+            self._span.attrs = dict(attrs)
 
     def begin(self):
-        self._begin = time.perf_counter_ns()
+        self._span.__enter__()
 
     def end(self):
-        if self._begin is None:
-            return
-        now = time.perf_counter_ns()
-        if _collector.active:
-            # os.getpid() is a syscall per call (tens of µs in sandboxed
-            # kernels) — the cached module value is identical
-            ev = {"name": self.name, "ts": self._begin / 1000.0,
-                  "dur": (now - self._begin) / 1000.0,
-                  "ph": "X", "pid": _PID,
-                  "tid": threading.get_ident()}
-            if self.attrs:
-                ev["args"] = dict(self.attrs)
-            with _collector.lock:
-                _collector.events.append(ev)
-        if _tracing is not None and _tracing.tracing_active():
-            _tracing.record_span(self.name, self._begin, now - self._begin,
-                                 self.attrs)
-        self._begin = None
+        self._span.__exit__(None, None, None)
 
     def __enter__(self):
         self.begin()
@@ -160,14 +121,17 @@ class Profiler:
         self._jax_trace_dir = None
 
     def start(self):
-        with _collector.lock:
-            _collector.events = []
-        _collector.active = True
+        # a window of the one store: opened here unless one is open already
+        # (then this profiler reads its slice of it and leaves it open)
+        self._opened = not _tracing.collecting()
+        if self._opened:
+            _tracing.start_tracing()
+        self._mark = 0 if self._opened else len(_tracing.events_snapshot())
 
     def stop(self):
-        _collector.active = False
-        with _collector.lock:
-            self._events = list(_collector.events)
+        self._events = _tracing.events_snapshot(self._mark)
+        if self._opened:
+            _tracing.stop_tracing()
         if self.on_trace_ready:
             self.on_trace_ready(self)
 

@@ -76,6 +76,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddle_tpu.core.compile_cache import compile_totals
 from paddle_tpu.distributed.resilience import faults
 from paddle_tpu.lora.store import AdapterLoadError  # registers swap_fail chaos
 from paddle_tpu.observability import events as obs_events
@@ -234,6 +235,14 @@ def _register_engine_metrics(engine: "ServingEngine"):
                        tenant=tenant)._set_total(float(n))
 
     obs_metrics.registry().add_collector(collect, owner=engine)
+
+
+def _named(fn, name: str):
+    """`fn` under a name of its own: JAX names a program after its function
+    (`jit(engine_decode)`), which is how the compile log and a device
+    trace's `XLA Modules` tell the engine's programs apart."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 def _buckets(lo: int, hi: int) -> list[int]:
@@ -469,6 +478,9 @@ class ServingEngine:
         # compiled step REASSIGNS (and on TPU donates) the functional
         # cache handle, so concurrent dispatch would fork or kill it
         self._step_lock = threading.RLock()
+        # seconds submit() waited for the step lock (stats())
+        self._submit_wait_s = 0.0
+        self._submit_wait_max_s = 0.0
         self._http_stop = False
         self._http_error: str | None = None
         # observability: register a SCRAPE-TIME collector mapping stats()
@@ -567,7 +579,8 @@ class ServingEngine:
                 return tokens, new_keys, cache
 
             self._decode_fn = self._maybe_aot(jax.jit(
-                fn, donate_argnums=(1,) if self._donate else ()), "decode")
+                _named(fn, "engine_decode"),
+                donate_argnums=(1,) if self._donate else ()), "decode")
         return self._decode_fn
 
     def _prefill(self, chunk_pad: int, ctx_pad: int):
@@ -597,7 +610,8 @@ class ServingEngine:
                 return cache
 
             self._prefill_fns[key] = self._maybe_aot(
-                jax.jit(fn, donate_argnums=(1,) if self._donate else ()),
+                jax.jit(_named(fn, f"engine_prefill_{chunk_pad}x{ctx_pad}"),
+                        donate_argnums=(1,) if self._donate else ()),
                 f"prefill:{chunk_pad}x{ctx_pad}")
         return self._prefill_fns[key]
 
@@ -627,7 +641,8 @@ class ServingEngine:
                 return cache
 
             self._prefill_packed_fns[frame] = self._maybe_aot(
-                jax.jit(fn, donate_argnums=(1,) if self._donate else ()),
+                jax.jit(_named(fn, f"engine_prefill_packed_{frame}"),
+                        donate_argnums=(1,) if self._donate else ()),
                 f"prefill_packed:{frame}")
         return self._prefill_packed_fns[frame]
 
@@ -769,7 +784,8 @@ class ServingEngine:
                 return tokens, accepted, new_keys, cache
 
             self._verify_fns[k] = self._maybe_aot(
-                jax.jit(fn, donate_argnums=(1,) if self._donate else ()),
+                jax.jit(_named(fn, f"engine_verify_{k}"),
+                        donate_argnums=(1,) if self._donate else ()),
                 f"verify:{k}")
         return self._verify_fns[k]
 
@@ -783,7 +799,8 @@ class ServingEngine:
                         for name, a in cache.items()}
 
             self._copy_fn = jax.jit(
-                fn, donate_argnums=(0,) if self._donate else ())
+                _named(fn, "engine_copy_page"),
+                donate_argnums=(0,) if self._donate else ())
         return self._copy_fn
 
     def _extract_page(self):
@@ -794,7 +811,7 @@ class ServingEngine:
             def fn(cache, src):
                 return {name: a[:, :, src] for name, a in cache.items()}
 
-            self._extract_fn = jax.jit(fn)
+            self._extract_fn = jax.jit(_named(fn, "engine_extract_page"))
         return self._extract_fn
 
     def _restore_page(self):
@@ -807,7 +824,8 @@ class ServingEngine:
                         for name, a in cache.items()}
 
             self._restore_fn = jax.jit(
-                fn, donate_argnums=(0,) if self._donate else ())
+                _named(fn, "engine_restore_page"),
+                donate_argnums=(0,) if self._donate else ())
         return self._restore_fn
 
     def configure_speculation(self, spec_k: int | None = None,
@@ -861,7 +879,16 @@ class ServingEngine:
         # under _step_lock: a concurrent step() must never see the request
         # as admittable before its RNG key (and draft table) exist — the
         # submit-vs-step gap was a real KeyError under bursty feeders
-        with self._step_lock:
+        t0 = time.perf_counter()
+        with obs_tracing.span("engine.submit_wait", component="engine"):
+            # a driver holds the lock for the whole of step() and takes it
+            # again at once: what a submitter waits here is time to first
+            # token that no scheduler counter sees
+            self._step_lock.acquire()
+        try:
+            waited = time.perf_counter() - t0
+            self._submit_wait_s += waited
+            self._submit_wait_max_s = max(self._submit_wait_max_s, waited)
             try:
                 rid = self.scheduler.submit(req)
             except Exception:
@@ -871,6 +898,8 @@ class ServingEngine:
             self._keys[rid] = self._new_key()
             if self.spec_k > 0:
                 self._proposer.add_request(rid, req.prompt)
+        finally:
+            self._step_lock.release()
         return rid
 
     def _new_key(self) -> np.ndarray:
@@ -962,51 +991,58 @@ class ServingEngine:
         shared verbatim by the continuous scheduler and the static-batch
         baseline so both provably run the same program. `finisher(req)`
         releases a request that just hit its stop condition."""
+        # the host's four parts of a decode step, each a span of its own
+        # (docs/observability.md): only `readback` waits for the device
+        span = obs_tracing.span
         b, pmax = self.decode_batch, self.pages_per_seq
-        ids = np.zeros(b, np.int32)
-        lens = np.zeros(b, np.int32)
-        pt = np.zeros((b, pmax), np.int32)
-        keys = np.zeros((b, 2), np.uint32)
-        temp = np.zeros(b, np.float32)
-        top_k = np.zeros(b, np.int32)
-        top_p = np.ones(b, np.float32)
-        arows = self._pack_adapter_rows(active, b)
-        for i, req in enumerate(active):
-            # NOT req.context[-1]: that concatenates prompt+generated every
-            # step (O(len) per token -> O(len^2) per stream)
-            ids[i] = (req.generated[-1] if req.generated
-                      else int(req.prompt[-1]))
-            lens[i] = req.total_len
-            pt[i] = self.allocator.page_table_row(req.rid, pmax)
-            keys[i] = self._keys[req.rid]
-            temp[i] = req.temperature
-            top_k[i] = req.top_k
-            top_p[i] = req.top_p
-        aslots, apools, bpools = self._adapter_args(arows) \
-            if arows is not None else (None, None, None)
-        tokens, new_keys, self._cache = self._decode()(
-            self._params, self._cache, jnp.asarray(ids),
-            jnp.asarray(lens), jnp.asarray(pt), jnp.asarray(keys),
-            jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
-            aslots, apools, bpools)
-        toks = np.asarray(tokens)
-        nkeys = np.asarray(new_keys)
-        now = time.perf_counter()
-        for i, req in enumerate(active):
-            tok = int(toks[i])
-            req.generated.append(tok)
-            req.token_times.append(now)
-            self._keys[req.rid] = nkeys[i]
-            self._bill_tenant(req)
-            if req.stream_cb is not None:
-                req.stream_cb(req, tok)
-            if ((req.eos_id is not None and tok == req.eos_id)
-                    or len(req.generated) >= req.max_new_tokens):
-                finisher(req)
-        self._committed_tokens += len(active)
-        self._slot_steps += len(active)
-        self._decode_steps += 1
-        self._util_samples.append(self.allocator.utilization())
+        with span("engine.decode.pack", component="engine"):
+            ids = np.zeros(b, np.int32)
+            lens = np.zeros(b, np.int32)
+            pt = np.zeros((b, pmax), np.int32)
+            keys = np.zeros((b, 2), np.uint32)
+            temp = np.zeros(b, np.float32)
+            top_k = np.zeros(b, np.int32)
+            top_p = np.ones(b, np.float32)
+            arows = self._pack_adapter_rows(active, b)
+            for i, req in enumerate(active):
+                # NOT req.context[-1]: that concatenates prompt+generated
+                # every step (O(len) per token -> O(len^2) per stream)
+                ids[i] = (req.generated[-1] if req.generated
+                          else int(req.prompt[-1]))
+                lens[i] = req.total_len
+                pt[i] = self.allocator.page_table_row(req.rid, pmax)
+                keys[i] = self._keys[req.rid]
+                temp[i] = req.temperature
+                top_k[i] = req.top_k
+                top_p[i] = req.top_p
+        with span("engine.decode.dispatch", component="engine"):
+            aslots, apools, bpools = self._adapter_args(arows) \
+                if arows is not None else (None, None, None)
+            tokens, new_keys, self._cache = self._decode()(
+                self._params, self._cache, jnp.asarray(ids),
+                jnp.asarray(lens), jnp.asarray(pt), jnp.asarray(keys),
+                jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
+                aslots, apools, bpools)
+        with span("engine.decode.readback", component="engine"):
+            toks = np.asarray(tokens)
+            nkeys = np.asarray(new_keys)
+        with span("engine.decode.apply", component="engine"):
+            now = time.perf_counter()
+            for i, req in enumerate(active):
+                tok = int(toks[i])
+                req.generated.append(tok)
+                req.token_times.append(now)
+                self._keys[req.rid] = nkeys[i]
+                self._bill_tenant(req)
+                if req.stream_cb is not None:
+                    req.stream_cb(req, tok)
+                if ((req.eos_id is not None and tok == req.eos_id)
+                        or len(req.generated) >= req.max_new_tokens):
+                    finisher(req)
+            self._committed_tokens += len(active)
+            self._slot_steps += len(active)
+            self._decode_steps += 1
+            self._util_samples.append(self.allocator.utilization())
 
     def _verify_once(self, active, finisher):
         """Pack `active` requests into the fixed [batch, K+1] verify
@@ -1259,10 +1295,11 @@ class ServingEngine:
     def _step_locked(self) -> bool:
         if self._handoff_channel is not None:
             self._drain_handoffs()
-        self._admit()
-        self.scheduler.grow()
-        self._apply_tier_ops()   # grow()'s reclaims demote before CoW writes
-        self._apply_cow()
+        with obs_tracing.span("engine.admit", component="engine"):
+            self._admit()
+            self.scheduler.grow()
+            self._apply_tier_ops()   # grow()'s reclaims demote before CoW
+            self._apply_cow()
         running = list(self.scheduler.running)
         if not running:
             if self._pending_handoff:
@@ -1754,6 +1791,15 @@ class ServingEngine:
             # (what a scaled-up replica's operator checks to confirm the
             # cold start LOADED instead of compiling)
             "program_cache": self.program_cache_stats(),
+            # what submit() waited for the step lock: time to first token
+            # spent before the scheduler has the request
+            "submit_lock_wait_ms_total": round(self._submit_wait_s * 1e3, 3),
+            "submit_lock_wait_ms_max": round(
+                self._submit_wait_max_s * 1e3, 3),
+            # JAX's compile log for the engine's programs (process-wide:
+            # every engine's programs are named jit(engine_...)); all zero
+            # until core.compile_cache.start_compile_log()
+            "compile": compile_totals("jit(engine_"),
         }
 
     def program_cache_stats(self) -> dict:

@@ -153,8 +153,8 @@ class TestDeviceFeeder:
                 for _ in feeder:
                     pass
         names = {e["name"] for e in prof._events}
-        assert "DeviceFeeder::place" in names
-        assert "DeviceFeeder::fetch" in names
+        assert "train.feed.place" in names
+        assert "train.feed.fetch" in names
 
     def test_nested_batch_structure_preserved(self):
         batch = {"x": (np.zeros((2, 2), np.float32),

@@ -175,18 +175,88 @@ class TestTracing:
         assert by_name["inner"]["args"]["component"] == "engine"
         assert by_name["inner"]["dur"] <= by_name["outer"]["dur"]
 
-    def test_record_event_mirrors_into_trace(self):
+    def test_record_event_is_a_span_of_the_one_store(self):
         from paddle_tpu.profiler import RecordEvent
 
         obs_tracing.start_tracing()
         try:
             with obs_tracing.trace_context("abc"):
-                with RecordEvent("CompiledTrainStep::place"):
+                with RecordEvent("train.place", attrs={"k": 1}):
                     pass
+                ev2 = RecordEvent("explicit")
+                ev2.end()            # end() without begin() records nothing
+                ev2.begin()
+                ev2.end()
         finally:
             evs = obs_tracing.stop_tracing()
-        (ev,) = [e for e in evs if e["name"] == "CompiledTrainStep::place"]
-        assert ev["args"]["trace_id"] == "abc"
+        (ev,) = [e for e in evs if e["name"] == "train.place"]
+        assert ev["args"]["trace_id"] == "abc" and ev["args"]["k"] == 1
+        assert [e["name"] for e in evs].count("explicit") == 1
+
+    def test_profiler_reads_the_one_store(self):
+        """No second collector: a Profiler opens a window of the tracing
+        store, or reads its slice of one that is open and leaves it open."""
+        import paddle_tpu.profiler as profiler
+
+        assert not hasattr(profiler, "_Collector")
+        assert not hasattr(profiler, "_collector")
+        assert not hasattr(obs_tracing, "_device_trace_events")
+        with profiler.Profiler() as prof:
+            assert obs_tracing.collecting()
+            with obs_tracing.span("by_span"):
+                with profiler.RecordEvent("by_record_event"):
+                    pass
+        assert not obs_tracing.collecting()
+        assert [e["name"] for e in prof._events] == ["by_record_event",
+                                                     "by_span"]
+        assert "by_span" in prof.summary()
+        obs_tracing.start_tracing()
+        with obs_tracing.span("before"):
+            pass
+        with profiler.Profiler() as inner:
+            with obs_tracing.span("inside"):
+                pass
+        assert obs_tracing.collecting()          # not the profiler's to close
+        assert [e["name"] for e in inner._events] == ["inside"]
+        assert [e["name"] for e in obs_tracing.stop_tracing()] == [
+            "before", "inside"]
+
+    def test_span_names_its_enclosing_span(self):
+        """args.parent: a layer's self time is its span less its
+        children. record_span (measured by the caller) gets it too; an
+        unbound span carries one and is nobody's parent."""
+        obs_tracing.start_tracing()
+        try:
+            with obs_tracing.span("outer"):
+                with obs_tracing.span("mid", bind=False):
+                    with obs_tracing.span("inner"):
+                        pass
+                obs_tracing.record_span("measured", time.perf_counter_ns(),
+                                        1000, {"component": "c"})
+            with obs_tracing.span("alone"):
+                pass
+        finally:
+            evs = {e["name"]: e for e in obs_tracing.stop_tracing()}
+        assert "parent" not in evs["outer"]["args"]
+        assert evs["mid"]["args"]["parent"] == "outer"
+        assert evs["inner"]["args"]["parent"] == "outer"
+        assert evs["measured"]["args"]["parent"] == "outer"
+        assert "parent" not in evs["alone"]["args"]
+
+    def test_span_cost_with_everything_off(self):
+        """Neither a collection window nor a profiler session: a span is
+        two checks and costs under 2 us (median of 10,000)."""
+        assert not obs_tracing.tracing_active()
+        clock = time.perf_counter_ns
+        costs = []
+        for _ in range(10_000):
+            t0 = clock()
+            with obs_tracing.span("off", component="c"):
+                pass
+            costs.append(clock() - t0)
+        costs.sort()
+        assert costs[len(costs) // 2] < 2000, costs[len(costs) // 2]
+        assert obs_tracing.events_snapshot() == []
 
     def test_unbound_span_leaves_thread_context_alone(self):
         """bind=False (the generator-wrapping mode the router uses): two
@@ -226,11 +296,285 @@ class TestTracing:
         summary = obs_tracing.export_chrome(
             path, extra_events=[{"name": "dev", "ph": "X", "ts": 0,
                                  "dur": 1, "pid": 9, "tid": 9}])
-        assert summary["host_events"] == 1
+        assert summary == {"host_events": 1, "path": path}
+        with pytest.raises(TypeError):      # merged nothing, and went
+            obs_tracing.export_chrome(path, device_trace_dir=str(tmp_path))
         with open(path) as f:
             doc = json.load(f)
         names = {e["name"] for e in doc["traceEvents"]}
         assert names == {"a", "dev"}
+
+
+# ---------------------------------------------------------------------------
+# the profiler's clock: spans, kernel names, the compile log
+# ---------------------------------------------------------------------------
+def _host_events(trace_dir):
+    """{name: [stats dict, ...]} of `/host:CPU` in the newest xplane."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    paths = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    data = ProfileData.from_file(max(paths, key=os.path.getmtime))
+    (plane,) = [p for p in data.planes if p.name == "/host:CPU"]
+    out = {}
+    for line in plane.lines:
+        for e in line.events:
+            out.setdefault(e.name, []).append(
+                (e.start_ns, e.duration_ns, dict(e.stats)))
+    return out
+
+
+class TestProfilerClock:
+    def test_spans_reach_the_xplane_by_themselves(self, tmp_path):
+        """Under a jax.profiler session, and WITHOUT start_tracing(), a
+        span is a TraceAnnotation of /host:CPU: scalar attributes are its
+        stats, lists stay out, and a span measured by the caller leaves a
+        marker carrying its length."""
+        import jax
+
+        assert not obs_tracing.tracing_active()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            assert obs_tracing.tracing_active()
+            assert not obs_tracing.collecting()
+            with obs_tracing.span("engine.t_outer", component="engine",
+                                  rid=7, trace_ids=["a", "b"]):
+                with obs_tracing.span("engine.t_inner"):
+                    time.sleep(0.002)
+            obs_tracing.record_span("scheduler.t_wait",
+                                    time.perf_counter_ns(), 5_000_000,
+                                    {"component": "scheduler", "rid": 7})
+        finally:
+            jax.profiler.stop_trace()
+        assert not obs_tracing.tracing_active()
+        assert obs_tracing.events_snapshot() == []   # no window was open
+        evs = _host_events(str(tmp_path))
+        (outer,), (inner,) = evs["engine.t_outer"], evs["engine.t_inner"]
+        assert outer[2]["component"] == "engine" and outer[2]["rid"] == 7
+        assert "trace_ids" not in outer[2]
+        assert outer[0] <= inner[0]
+        assert inner[0] + inner[1] <= outer[0] + outer[1]
+        assert inner[1] >= 2e6
+        (wait,) = evs["scheduler.t_wait"]
+        assert wait[2]["dur_us"] == 5000
+
+    def test_train_step_spans_and_bit_equal_loss(self, tmp_path):
+        """A tiny CompiledTrainStep under a profiler session leaves its own
+        spans in /host:CPU without start_tracing(); the session changes
+        neither the loss (bit-equal) nor the number of traces (one)."""
+        import jax
+
+        from paddle_tpu.core.compile_cache import start_compile_log
+
+        start_compile_log()
+        st_plain, ids = _tiny_step(False, seed=3)
+        st_prof, _ = _tiny_step(False, seed=3)
+        plain = [float(st_plain(ids, ids, ids)) for _ in range(3)]
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            prof = [float(st_prof(ids, ids, ids)) for _ in range(3)]
+        finally:
+            jax.profiler.stop_trace()
+        assert plain == prof
+        assert st_plain._jitted._cache_size() == 1
+        assert st_prof._jitted._cache_size() == 1
+        evs = _host_events(str(tmp_path))
+        for name in ("train.place", "train.dispatch",
+                     "train.run_ahead_wait", "train.call"):
+            assert len(evs[name]) == 3, (name, sorted(evs))
+        assert len(evs["train.build"]) == 2      # the program, its compile
+        assert [e[2]["step"] for e in evs["train.dispatch"]] == [1, 2, 3]
+        assert [e[2]["step_num"] for e in evs["train.call"]] == [1, 2, 3]
+        # every part lies inside its step's train.call
+        calls = sorted(evs["train.call"])
+        for name in ("train.place", "train.dispatch",
+                     "train.run_ahead_wait"):
+            for (s, d, _), (cs, cd, _) in zip(sorted(evs[name]), calls):
+                assert cs <= s and s + d <= cs + cd
+        c = st_prof.host_counters()
+        assert c["steps"] == 3 and c["builds"] == 1
+        assert c["build_s"] > c["dispatch_s"] > 0 and c["place_s"] > 0
+        assert c["run_ahead_wait_s"] >= 0
+        # the compile log knows the step's program by JAX's name for it
+        assert c["compile"]["programs"] >= 2 and c["compile"]["secs"] > 0
+
+    def test_scopes_name_the_steps_operations(self):
+        """jax.named_scope in the step and the model: metadata only, and
+        every scope the docs promise is in the lowered program."""
+        st, ids = _tiny_step(False, seed=4)
+        st(ids, ids, ids)
+        text = st._jitted.lower(*st._abstract_args).as_text(debug_info=True)
+        for scope in ("loss", "optimizer", "embed", "attn", "mlp",
+                      "final_norm", "head_ce"):
+            assert f"/{scope}/" in text or f"({scope})" in text, scope
+        assert "transpose(jvp(loss))" in text
+
+
+def _pallas_names(fn, *args):
+    """(name, metadata) of every pallas_call in the jaxpr of fn(*args)."""
+    import jax
+    from jax._src import core
+
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append((eqn.params["name"],
+                              dict(eqn.params["metadata"] or {})))
+            for sub in core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def _kernel_cases():
+    """One call of each public kernel of ops/pallas (interpret mode), with
+    the names its pallas_calls must carry."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    fa, ce, pa, gm, rk = (
+        importlib.import_module("paddle_tpu.ops.pallas." + m)
+        for m in ("flash_attention", "fused_ce", "paged_attention",
+                  "grouped_matmul", "rmsnorm_kernel"))
+    f32, i32 = jnp.float32, jnp.int32
+
+    def flash(q):
+        with fa.force_interpret():
+            return jax.grad(lambda q: fa.flash_attention_bshd(
+                q, q, q, causal=True).sum())(q)
+
+    def paged(q, kv, table, lens):
+        with pa.force_interpret():
+            return pa.paged_decode_attention(q, kv, kv, table, lens)
+
+    def paged_count(lens):
+        with pa.force_interpret():
+            return pa.page_visit_counts(lens, 16, 4)
+
+    lab = jnp.zeros((32,), i32)
+    gids = jnp.repeat(jnp.arange(2, dtype=i32), 16)
+    lens = jnp.array([5, 9], i32)
+    return {
+        "flash_attention": (
+            flash, (jnp.ones((1, 128, 2, 64), f32),),
+            ["flash_fwd", "flash_dq", "flash_dkv"]),
+        "flash_block_count": (
+            lambda s: fa.segment_block_visit_counts(s, interpret=True),
+            (jnp.zeros((1, 256), i32),), ["flash_block_count"]),
+        "fused_ce": (
+            lambda x, w: jax.grad(
+                lambda x: ce.fused_linear_cross_entropy_loss(
+                    x, w, lab, variant="pallas").sum())(x),
+            (jnp.ones((32, 128), f32), jnp.ones((128, 256), f32)),
+            ["ce_stats"]),
+        "paged_decode": (
+            paged, (jnp.ones((2, 2, 64), f32), jnp.ones((2, 8, 16, 64), f32),
+                    jnp.zeros((2, 4), i32), lens), ["paged_decode"]),
+        "paged_block_count": (paged_count, (lens,), ["paged_block_count"]),
+        "rmsnorm": (
+            lambda x, w: jax.grad(lambda x: rk.rmsnorm(x, w).sum())(x),
+            (jnp.ones((16, 128), f32), jnp.ones((128,), f32)),
+            ["rmsnorm_fwd", "rmsnorm_bwd"]),
+        "grouped_matmul": (
+            lambda x, w: jax.grad(
+                lambda x, w: gm.grouped_matmul(
+                    x, w, gids, block_rows=16, backend="pallas").sum(),
+                argnums=(0, 1))(x, w),
+            (jnp.ones((32, 128), f32), jnp.ones((2, 128, 128), f32)),
+            ["grouped_matmul", "grouped_matmul", "grouped_matmul_dw"]),
+        "grouped_matmul_block_count": (
+            lambda g: gm.grouped_matmul_visit_counts(g, 2, 16, True),
+            (gids,), ["grouped_matmul_block_count"]),
+    }
+
+
+class TestKernelNames:
+    @pytest.mark.parametrize("case", [
+        "flash_attention", "flash_block_count", "fused_ce", "paged_decode",
+        "paged_block_count", "rmsnorm", "grouped_matmul",
+        "grouped_matmul_block_count"])
+    def test_every_pallas_call_is_named(self, case):
+        fn, args, want = _kernel_cases()[case]
+        found = _pallas_names(fn, *args)
+        assert [n for n, _ in found] == want
+        assert all(n and meta == {"kernel": n} for n, meta in found)
+
+    def test_no_call_site_is_left_out(self):
+        """Every pl.pallas_call( of ops/pallas passes a kernel_name: the
+        cases above cover each file, this counts the call sites."""
+        import re
+
+        root = os.path.join(os.path.dirname(paddle.__file__), "ops", "pallas")
+        calls = names = 0
+        for f in sorted(os.listdir(root)):
+            if f.endswith(".py"):
+                src = open(os.path.join(root, f)).read()
+                calls += len(re.findall(r"pl\.pallas_call\(", src))
+                names += len(re.findall(r"\*\*_compat\.kernel_name\(", src))
+        assert calls == names == 12
+
+
+class TestCompileLog:
+    def test_hit_and_miss_land_on_the_right_program(self, tmp_path):
+        """With a temporary persistent cache: the first compile of a
+        program is a 'miss' (compiled and written), the second a 'hit',
+        each under its own fun_name with its phases' seconds."""
+        import jax
+        import jax.numpy as jnp
+        from jax._src import compilation_cache
+
+        from paddle_tpu.core import compile_cache as cc
+
+        def alpha():
+            # the same program from a new function: JAX's in-memory cache
+            # does not know it, the persistent cache does
+            def log_alpha(x):
+                return x * 2 + 1
+            return log_alpha
+
+        def log_beta(x):
+            return x - 3
+
+        keys = {"jax_compilation_cache_dir": str(tmp_path),
+                "jax_persistent_cache_min_compile_time_secs": 0.0,
+                "jax_persistent_cache_min_entry_size_bytes": 0}
+        before = {k: getattr(jax.config, k) for k in keys}
+        cc.start_compile_log()
+        cc.start_compile_log()                   # registers once
+        n0 = len(cc.compile_log())
+        t_before = time.perf_counter()
+        try:
+            for k, v in keys.items():
+                jax.config.update(k, v)
+            compilation_cache.reset_cache()
+            x = jnp.ones(3, jnp.float32)
+            jax.jit(alpha())(x)
+            jax.jit(log_beta)(x)
+            jax.jit(alpha())(x)
+        finally:
+            for k, v in before.items():
+                jax.config.update(k, v)
+            compilation_cache.reset_cache()
+        mine = [e for e in cc.compile_log()[n0:] if "log_" in e["fun_name"]]
+        assert [(e["fun_name"], e["cache"]) for e in mine] == [
+            ("jit(log_alpha)", "miss"), ("jit(log_beta)", "miss"),
+            ("jit(log_alpha)", "hit")]
+        for e in mine:
+            assert t_before < e["t0"] < time.perf_counter()
+            assert e["trace_s"] > 0 and e["lower_s"] > 0 and e["compile_s"] > 0
+        tot = cc.compile_totals("jit(log_alpha)")
+        assert (tot["programs"], tot["hits"], tot["misses"]) == (2, 1, 1)
+        assert tot["secs"] == pytest.approx(sum(
+            e["trace_s"] + e["lower_s"] + e["compile_s"]
+            for e in mine if e["fun_name"] == "jit(log_alpha)"))
+        assert cc.compile_totals()["traces"] >= 3
 
 
 # ---------------------------------------------------------------------------
@@ -581,6 +925,49 @@ class TestRealEngineObservability:
         for e in decodes:
             traced.update(e["args"].get("trace_ids", []))
         assert traced == {f"tr{r}" for r in rids}
+        # the host's parts of a decode step: one of each a step, in order,
+        # inside the step's span and naming it as their parent
+        parts = ["engine.decode.pack", "engine.decode.dispatch",
+                 "engine.decode.readback", "engine.decode.apply"]
+        by_part = [sorted((e["ts"], e["dur"]) for e in evs
+                          if e["name"] == p) for p in parts]
+        assert all(len(p) == len(decodes) for p in by_part)
+        for step, *subs in zip(sorted((e["ts"], e["dur"]) for e in decodes),
+                               *by_part):
+            ends = [ts + dur for ts, dur in subs]
+            assert step[0] <= subs[0][0] and ends[-1] <= step[0] + step[1]
+            assert all(end <= nxt[0] for end, nxt in zip(ends, subs[1:]))
+        assert {e["args"]["parent"] for e in evs
+                if e["name"] in parts} == {"engine.decode_step"}
+        waits = [e for e in evs if e["name"] == "engine.submit_wait"]
+        assert len(waits) == len(rids)
+        assert [e for e in evs if e["name"] == "engine.admit"]
+        st = eng.stats()
+        assert st["submit_lock_wait_ms_total"] >= st[
+            "submit_lock_wait_ms_max"] >= 0
+        assert set(st["compile"]) >= {"programs", "secs", "hits", "misses"}
+
+    def test_submit_wait_counts_the_step_lock(self, real_engine):
+        """A submitter that finds the step lock held waits, and the wait is
+        summed and maxed into stats()."""
+        eng = real_engine
+        before = eng.stats()
+        rids = []
+        with eng._step_lock:
+            t = threading.Thread(
+                target=lambda: rids.append(eng.submit(
+                    np.arange(1, 6, dtype=np.int32), max_new_tokens=2)))
+            t.start()
+            time.sleep(0.05)
+        t.join(10)
+        assert not t.is_alive()
+        while not eng.scheduler.idle:
+            eng.step()
+        eng.release(rids[0])
+        after = eng.stats()
+        assert after["submit_lock_wait_ms_max"] >= 40
+        assert (after["submit_lock_wait_ms_total"]
+                - before["submit_lock_wait_ms_total"]) >= 40
 
     def test_metrics_endpoint_alongside_healthz_and_stats(self, real_engine):
         eng = real_engine
