@@ -3653,9 +3653,12 @@ def main():
         vs_baseline = round(tps_7b_v5p / h100_bar, 4)
         # fused-head accounting: the unfused arm's full logits vs the
         # fused kernel's largest live tile (fp32 elements x 4 bytes)
-        from paddle_tpu.ops.pallas.fused_ce import resolve_chunks
+        from paddle_tpu.ops.pallas.fused_ce import (resolve_bwd_chunk,
+                                                    resolve_chunks)
 
-        ct, _ = resolve_chunks(batch * seq, 32000)
+        # the largest tile is the backward's (its own, deeper blocking)
+        ct = max(resolve_chunks(batch * seq, 32000)[0],
+                 resolve_bwd_chunk(batch * seq, 32000))
         projection = {
             "per_layer_ms": round(per_layer_s * 1e3, 2),
             "embed_head_ms": round(head_m["step_s"] * 1e3, 2),

@@ -118,7 +118,7 @@ define_flag("use_fused_cross_entropy", True,
             "chunked fused softmax-CE fast path in F.cross_entropy (escape hatch: set False)")
 define_flag("use_fused_head_loss", True,
             "fuse LM-head projection + CE in models/pipeline head stages (escape hatch: set False)")
-define_flag("fused_ce_chunk_tokens", 0, "fused-CE token chunk override (0 = auto ~4M-element tiles)", type=int)
+define_flag("fused_ce_chunk_tokens", 0, "fused-CE token chunk override, forward and backward (0 = auto: ~4M-element forward tiles, the backward's own depth)", type=int)
 define_flag("fused_ce_chunk_vocab", 0, "fused-CE vocab chunk override (0 = auto)", type=int)
 define_flag("fused_ce_variant", "auto", "fused-CE strategy: auto|tokens|vocab|pallas")
 define_flag("moe_dispatch", "capacity",

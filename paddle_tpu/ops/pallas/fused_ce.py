@@ -14,8 +14,9 @@ forward OR backward:
 
 * **token-chunked** (`variant="tokens"`): `lax.scan` over token chunks; each
   chunk materializes only a `[C, V]` logits tile in fp32, reduces it to the
-  per-token stats, and is freed before the next chunk. Backward replays the
-  same chunking, recomputing the tile and accumulating `dW`/`db` in fp32.
+  per-token stats, and is freed before the next chunk. Backward scans token
+  chunks too, recomputing the tile and accumulating `dW`/`db` in fp32, at a
+  depth of its own (below).
 * **vocab-chunked** (`variant="vocab"`): `lax.scan` over vocab chunks with
   online (flash-style) max/sum-exp rescaling — the right shape when the
   token count is small but the vocabulary is huge.
@@ -32,6 +33,16 @@ forward OR backward:
   with `pmax`/`psum` over the axis (Megatron fwd), and backward `psum`s the
   partial `dx` while `dW` stays shard-local (Megatron bwd) — no rank ever
   holds a full vocab row.
+
+Backward blocking: with a head, every iteration of the backward's scan
+reads and writes the whole fp32 `dW` accumulator and streams the head twice,
+whatever the chunk, so it does not inherit the forward's 4M-element tile
+(128 tokens at vocab 32768: 96 such passes a 12K-token step). It takes
+`resolve_bwd_chunk`'s depth instead (2048 tokens, byte-capped), unless a
+`chunk_tokens` the caller or `FLAGS_fused_ce_chunk_tokens` SET binds it as a
+memory bound; a tuned or heuristic forward tile does not. The logits-level
+loss (no head, no accumulator) keeps the forward's chunk. The depth is no
+knob: `last_resolution("fused_ce").derived` shows it.
 
 Numerics: per-chunk logits, all stats and all gradient accumulators are
 fp32 regardless of input dtype (bf16-safe); label smoothing, ignore_index
@@ -58,7 +69,7 @@ from paddle_tpu.ops.pallas import _compat
 from paddle_tpu.ops.pallas._compat import x64_off
 
 __all__ = ["fused_linear_cross_entropy_loss", "softmax_cross_entropy_loss",
-           "resolve_chunks", "x64_off"]
+           "resolve_chunks", "resolve_bwd_chunk", "x64_off"]
 
 _NEG_INF = float(np.finfo(np.float32).min)
 
@@ -85,6 +96,10 @@ class _CECfg(NamedTuple):
     mp_axis: str | None   # bound shard_map axis name, or None
     has_w: bool
     has_bias: bool
+    # token depth of `_bwd_tokens` (resolve_bwd_chunk, or the caller's /
+    # flag's chunk_tokens when one was set; variant "vocab" scans the
+    # vocabulary in its backward and does not read it)
+    bwd_chunk_tokens: int
     # fp8_policy='matmuls+head': the head projection (and the backward
     # dx/dW matmuls, with the d-logits tile in e5m2) run through float8 with
     # current scaling; per-token softmax stats and accumulators stay fp32
@@ -112,6 +127,34 @@ def resolve_chunks(n_tokens: int, vocab: int, chunk_tokens: int = 0,
     cv = chunk_vocab if chunk_vocab > 0 else max(
         128, min(vocab, target // max(n_tokens, 1)))
     return min(ct, max(n_tokens, 1)), min(cv, max(vocab, 1))
+
+
+# The head's backward carries the fp32 dW accumulator [hidden, vocab_local]
+# through its scan and reads and writes all of it (8 bytes an element) every
+# iteration to add a product that is only `chunk` deep: 2*chunk operations
+# for 8 bytes, chunk/4 an HBM byte, whatever the hidden size. A v5e's ridge
+# (197 TFLOP/s over 819 GB/s = 240) is met at 960 tokens; 2048 pays for the
+# pass twice over. The fp32 logits tile is the only memory that grows with
+# the depth, so it is what the depth is capped by.
+_BWD_DEPTH = 2048
+_BWD_TILE_BYTES = 256 << 20
+
+
+def resolve_bwd_chunk(n_tokens: int, vocab_local: int) -> int:
+    """Token depth of the head's backward scan (`_bwd_tokens` with a head):
+    `_BWD_DEPTH`, less where the fp32 `[chunk, vocab_local]` logits tile
+    would pass `_BWD_TILE_BYTES`, a multiple of 128, at most all tokens.
+    Tokens are spread evenly over the iterations, so a ragged tail pads by
+    under 128 rows an iteration instead of up to a whole chunk. Reads the
+    two shapes only: no flag, no device query."""
+    n = max(int(n_tokens), 1)
+    cap = _BWD_TILE_BYTES // (4 * max(int(vocab_local), 1))
+    depth = max(128, min(_BWD_DEPTH, cap) // 128 * 128)
+    if n <= depth:
+        return n
+    iters = -(-n // depth)
+    per_iter = -(-n // iters)
+    return -(-per_iter // 128) * 128
 
 
 # ---------------------------------------------------------------------------
@@ -388,7 +431,7 @@ def _bwd_tokens(cfg: _CECfg, x, w, b, labels, lse, ct):
     vloc = w.shape[1] if cfg.has_w else x.shape[-1]
     lab_loc, axis, v_total = _local_labels(cfg, labels, vloc)
     ctv, coef_p = _bwd_coefs(cfg, labels, lse, ct)
-    c = cfg.chunk_tokens
+    c = cfg.bwd_chunk_tokens
     xp, lp, nc = _pad_tokens(x, lab_loc, c)
     aux = jnp.stack([jnp.pad(lse, (0, nc * c - n)),
                      jnp.pad(ctv, (0, nc * c - n)),
@@ -545,14 +588,16 @@ def _build_softmax_ce(cfg: _CECfg):
 def _resolve_cfg(n, vloc, ignore_index, label_smoothing, z_loss, chunk_tokens,
                  chunk_vocab, variant, mp_axis, has_w, has_bias):
     from paddle_tpu.core.flags import flag
+    from paddle_tpu.tuning.blocks import (Resolution, note_derived,
+                                          resolve_blocks)
 
     if chunk_tokens > 0 or chunk_vocab > 0:
         # caller-supplied chunking wins outright (resolve_chunks fills a
         # partially-specified pair from the heuristic)
         ct, cv = resolve_chunks(n, vloc, chunk_tokens, chunk_vocab)
+        res = Resolution("fused_ce", {"chunk_tokens": ct, "chunk_vocab": cv},
+                         "caller", "chunk_tokens / chunk_vocab arguments")
     else:
-        from paddle_tpu.tuning.blocks import resolve_blocks
-
         res = resolve_blocks(
             "fused_ce", {"n_tokens": int(n), "vocab": int(vloc)},
             default=lambda g: resolve_chunks(n, vloc))
@@ -572,13 +617,27 @@ def _resolve_cfg(n, vloc, ignore_index, label_smoothing, z_loss, chunk_tokens,
                    else "tokens")
     if fp8 and variant == "pallas":
         variant = "tokens"
+    # the backward's own depth. A chunk_tokens the caller or the flag set is
+    # a memory bound someone asked for and binds the backward too; a tuned
+    # or heuristic forward tile does not (it was sized for, and timed on,
+    # the forward alone). Without a head there is no accumulator to amortise
+    # and the backward keeps the forward's chunk.
+    if chunk_tokens > 0:
+        bwd, bwd_from = ct, "caller"
+    elif res.provenance == "flag" and flag("fused_ce_chunk_tokens") > 0:
+        bwd, bwd_from = ct, "flag"
+    elif has_w:
+        bwd, bwd_from = resolve_bwd_chunk(n, vloc), "shape"
+    else:
+        bwd, bwd_from = ct, "forward"
+    note_derived(res, bwd_chunk_tokens=bwd, bwd_chunk_from=bwd_from)
     if mp_axis == "auto":
         from paddle_tpu.distributed.collective import _bound_axes
         from paddle_tpu.distributed.fleet.layers.mpu.mp_ops import MP_AXIS
 
         mp_axis = MP_AXIS if _bound_axes((MP_AXIS,)) else None
     return _CECfg(int(ignore_index), float(label_smoothing), float(z_loss),
-                  ct, cv, variant, mp_axis, has_w, has_bias, fp8)
+                  ct, cv, variant, mp_axis, has_w, has_bias, bwd, fp8)
 
 
 def fused_linear_cross_entropy_loss(x, w, labels, bias=None, *,

@@ -31,11 +31,11 @@ import json
 import os
 import threading
 import warnings
-from dataclasses import dataclass
+from dataclasses import dataclass, field, replace
 
 __all__ = ["KERNELS", "Resolution", "resolve_blocks", "last_resolution",
-           "trial_blocks", "cache_key", "TuningCache", "TUNING_SCHEMA",
-           "tuning_counters", "bump_counter"]
+           "note_derived", "trial_blocks", "cache_key", "TuningCache",
+           "TUNING_SCHEMA", "tuning_counters", "bump_counter"]
 
 TUNING_SCHEMA = "paddle_tpu-tune1"
 
@@ -87,12 +87,15 @@ class Resolution:
     """What ran and why: `values` maps the kernel's param names to the
     chosen ints; `provenance` is one of flag|tuned|default|trial|caller;
     `source` is the human detail ('FLAGS_flash_block_q/k', the cache key,
-    'heuristic', ...)."""
+    'heuristic', ...); `derived` holds what the kernel worked out FROM the
+    resolution and its shapes (fused_ce's backward depth): shown here, but
+    no `params` entry, so no flag, no tuning-cache field, no search axis."""
 
     kernel: str
     values: dict
     provenance: str
     source: str
+    derived: dict = field(default_factory=dict)
 
     def as_tuple(self) -> tuple:
         return tuple(self.values[p] for p in KERNELS[self.kernel].params)
@@ -133,6 +136,15 @@ def last_resolution(kernel: str) -> Resolution | None:
     """The most recent Resolution recorded for `kernel` in this process —
     the provenance assertion surface of the acceptance criteria."""
     return _last.get(kernel)
+
+
+def note_derived(res: Resolution, **derived) -> Resolution:
+    """Record `res` with `derived` attached as its kernel's last resolution
+    (a caller-supplied block never went through `resolve_blocks`, so this
+    is also where provenance 'caller' gets recorded)."""
+    res = replace(res, derived=derived)
+    _last[res.kernel] = res
+    return res
 
 
 def trial_blocks(kernel: str, values: dict):

@@ -20,9 +20,12 @@ import paddle_tpu.nn as nn
 import paddle_tpu.nn.functional as F
 from paddle_tpu.core.flags import flag, set_flags
 from paddle_tpu.distributed.mesh import shard_map_compat
+from paddle_tpu.ops.pallas import fused_ce
 from paddle_tpu.ops.pallas.fused_ce import (fused_linear_cross_entropy_loss,
+                                            resolve_bwd_chunk,
                                             resolve_chunks,
                                             softmax_cross_entropy_loss)
+from paddle_tpu.tuning.blocks import last_resolution, trial_blocks
 
 # deliberately awkward geometry: N not divisible by chunk_tokens (7),
 # V not divisible by chunk_vocab (13) or the mp world (handled by padding
@@ -176,6 +179,37 @@ class TestMpShardedParity:
         for gf, gr in zip(g_f, g_r):
             np.testing.assert_allclose(gf, gr, rtol=2e-5, atol=2e-5)
 
+    @pytest.mark.parametrize("eps,z_loss", [(0.0, 0.0), (0.1, 1e-3)])
+    def test_linear_ce_mp_deep_backward_matches_single_device(self, eps,
+                                                               z_loss):
+        """No chunk set: each shard's backward runs at the depth of ITS
+        vocabulary slice (2 x 1280 rows of 13 columns, ragged tail)."""
+        mesh = self._mesh()
+        v = 52
+        x, w, b, lab = _data(n=N_DEEP, v=v)
+
+        def body(x_, w_, lab_):
+            return fused_linear_cross_entropy_loss(
+                x_, w_, lab_, label_smoothing=eps, z_loss=z_loss,
+                variant="tokens", mp_axis="mp")
+
+        def ref(x_, w_):
+            return _ref_nll(x_, w_, None, lab, eps=eps, z_loss=z_loss)
+
+        sharded = shard_map_compat(body, mesh,
+                                   in_specs=(P(), P(None, "mp"), P()),
+                                   out_specs=P())
+        np.testing.assert_allclose(sharded(x, w, lab), ref(x, w),
+                                   rtol=2e-5, atol=2e-5)
+        assert last_resolution("fused_ce").derived == {
+            "bwd_chunk_tokens": 1280, "bwd_chunk_from": "shape"}
+        g_f = jax.grad(lambda x_, w_: jnp.sum(sharded(x_, w_, lab)),
+                       argnums=(0, 1))(x, w)
+        g_r = jax.grad(lambda x_, w_: jnp.sum(ref(x_, w_)),
+                       argnums=(0, 1))(x, w)
+        for gf, gr in zip(g_f, g_r):
+            np.testing.assert_allclose(gf, gr, rtol=2e-5, atol=1e-4)
+
     def test_sharded_logits_softmax_matches_single_device(self):
         mesh = self._mesh()
         v = 52
@@ -219,6 +253,126 @@ class TestMpShardedParity:
 
         np.testing.assert_allclose(run(True), run(False),
                                    rtol=2e-5, atol=2e-5)
+
+
+# tokens enough that the backward's own depth (resolve_bwd_chunk: 2 x 1280)
+# differs from the forward's chunk (all 2500 at this vocab) and leaves a
+# ragged tail of 60 padded rows
+N_DEEP = 2500
+
+
+def _scan_out_shapes(jaxpr):
+    """Shapes of every scan's outputs in `jaxpr`, nested jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            found.append([tuple(v.aval.shape) for v in eqn.outvars])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_scan_out_shapes(sub))
+    return found
+
+
+class TestBackwardDepth:
+    """The head's backward chunks by its own depth (resolve_bwd_chunk), not
+    by the forward's tile; a chunk_tokens someone SET binds it all the same."""
+
+    @pytest.mark.parametrize("variant", ["tokens", "pallas"])
+    @pytest.mark.parametrize("eps,z_loss", [(0.0, 0.0), (0.1, 1e-3)])
+    def test_deep_backward_parity(self, variant, eps, z_loss):
+        x, w, _, lab = _data(n=N_DEEP)   # every fifth label is ignore_index
+
+        def fused(x_, w_, *rest):
+            return fused_linear_cross_entropy_loss(
+                x_, w_, lab, label_smoothing=eps, z_loss=z_loss,
+                variant=variant, mp_axis=None)
+
+        def ref(x_, w_, *rest):
+            return _ref_nll(x_, w_, None, lab, eps=eps, z_loss=z_loss)
+
+        np.testing.assert_allclose(fused(x, w), ref(x, w),
+                                   rtol=2e-5, atol=2e-5)
+        res = last_resolution("fused_ce")
+        assert res.derived == {"bwd_chunk_tokens": 1280,
+                               "bwd_chunk_from": "shape"}
+        assert res.values["chunk_tokens"] == N_DEEP   # the forward's tile
+        assert "bwd_chunk_tokens" not in res.values   # not a tunable
+        for gf, gr in zip(_grads(fused, x, w, lab), _grads(ref, x, w, lab)):
+            # dw sums 2000 tokens in fp32, in another order than the
+            # reference's one product
+            np.testing.assert_allclose(gf, gr, rtol=2e-5, atol=1e-4)
+
+    @pytest.mark.parametrize("n,vocab,want", [
+        (12288, 32768, 2048),    # the benchmark's cell: 6 iterations
+        (12288, 16384, 2048),    # its mp=2 shard: the depth, not the cap
+        (12288, 92544, 640),     # InternLM2's vocabulary: the byte cap
+        (5000, 32768, 1792),     # 3 even iterations, not 2048 + 2048 + 904
+        (24, 50, 24),            # tiny: all tokens
+    ])
+    def test_resolve_bwd_chunk(self, n, vocab, want):
+        got = resolve_bwd_chunk(n, vocab)
+        assert got == want
+        assert got * vocab * 4 <= fused_ce._BWD_TILE_BYTES
+        assert got == n or got % 128 == 0
+
+    @pytest.mark.parametrize("how", ["caller", "flag"])
+    def test_set_chunk_binds_backward(self, how):
+        """chunk_tokens from the caller or FLAGS_fused_ce_chunk_tokens is a
+        memory bound: the lowered loss + grads hold no [rows, vocab] tile
+        of more rows than it."""
+        import re
+
+        n, h, v = 96, 8, 640
+        x, w, _, lab = _data(n=n, h=h, v=v, with_ignored=False)
+        kw = {"chunk_tokens": 16} if how == "caller" else {}
+        prev = flag("fused_ce_chunk_tokens")
+        try:
+            if how == "flag":
+                set_flags({"fused_ce_chunk_tokens": 16})
+            txt = jax.jit(jax.value_and_grad(
+                lambda a, b: jnp.sum(fused_linear_cross_entropy_loss(
+                    a, b, lab, variant="tokens", mp_axis=None, **kw)),
+                argnums=(0, 1))).lower(x, w).as_text()
+        finally:
+            set_flags({"fused_ce_chunk_tokens": prev})
+        assert last_resolution("fused_ce").derived == {
+            "bwd_chunk_tokens": 16, "bwd_chunk_from": how}
+        rows = [int(r) for r in re.findall(rf"tensor<(\d+)x{v}x", txt)]
+        assert rows and max(rows) == 16
+
+    def test_forward_tile_that_nobody_set_does_not_bind(self):
+        """A tuned / trial / heuristic forward tile was sized for the
+        forward alone: the backward keeps the shape rule's depth."""
+        x, w, _, lab = _data()
+        with trial_blocks("fused_ce", {"chunk_tokens": 8, "chunk_vocab": V}):
+            g = _grads(lambda a, b, *r: fused_linear_cross_entropy_loss(
+                a, b, lab, variant="tokens", mp_axis=None), x, w, lab)
+        res = last_resolution("fused_ce")
+        assert res.provenance == "trial" and res.values["chunk_tokens"] == 8
+        assert res.derived == {"bwd_chunk_tokens": N,
+                               "bwd_chunk_from": "shape"}
+        for gf, gr in zip(g, _grads(
+                lambda a, b, *r: _ref_nll(a, b, None, lab), x, w, lab)):
+            np.testing.assert_allclose(gf, gr, rtol=2e-5, atol=2e-5)
+
+    def test_backward_scan_runs_ceil_n_over_depth_iterations(self):
+        x, w, _, lab = _data(n=N_DEEP)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda a, b: jnp.sum(fused_linear_cross_entropy_loss(
+                a, b, lab, variant="tokens", mp_axis=None)),
+            argnums=(0, 1)))(x, w)
+        shapes = _scan_out_shapes(jaxpr.jaxpr)
+        # the stacked dx: [iterations, depth, hidden]
+        assert any((2, 1280, H) in outs for outs in shapes), shapes
+
+    def test_logits_level_backward_keeps_the_forward_chunk(self):
+        """No head, no accumulator, nothing to amortise."""
+        x, w, _, lab = _data()
+        jax.grad(lambda lg: jnp.sum(softmax_cross_entropy_loss(
+            lg, lab, mp_axis=None)))(jnp.dot(x, w))
+        res = last_resolution("fused_ce")
+        assert res.derived == {
+            "bwd_chunk_tokens": res.values["chunk_tokens"],
+            "bwd_chunk_from": "forward"}
 
 
 class TestNoFullLogitsMaterialized:
