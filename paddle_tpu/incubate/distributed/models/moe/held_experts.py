@@ -1,0 +1,162 @@
+"""One chip's share of an expert-parallel layer: gated (SwiGLU) experts, a
+shared expert every token passes through, and a router as wide as the whole
+layer.
+
+`HeldExpertsMoE` is told which experts it holds (`held_experts`, a range).
+It scores and chooses over ALL `num_expert` experts as the whole layer does,
+computes the part of the result its own experts give for the token-expert
+pairs routed to them, adds the shared expert's output, and adds nothing for
+the experts other chips hold: on one chip there is no exchange. The shares
+of all chips, with the shared expert counted once, add up to the whole
+layer (tests/test_kimi_linear.py::test_shares_add_up).
+
+The pairs go through the dropless dispatcher's own layout
+(`dropless.ragged_layout`: sorted by expert into block-aligned buckets) and
+`ops.pallas.grouped_matmul(aligned=True)`, whose kernels skip the row blocks
+no pair fell into: device time follows the pairs routed here. Every shape is
+static. A chip that holds ALL the experts lays out every pair and drops
+none. A chip that holds a share lays out `CAPACITY_FACTOR` times the share a
+balanced router sends it (`tokens x top_k x held / num_expert` rows) and
+COUNTS the pairs past that (`step_stats[3]`, `moe.dropped`): the router's
+correction bias is what keeps the count at zero, moved after every step by
+the balancing rule (`SigmoidGate.next_bias`), which `CompiledTrainStep`
+applies outside the gradient.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.core.tensor import apply_op
+from paddle_tpu.incubate.distributed.models.moe.dropless import (
+    _round_up, ragged_layout)
+from paddle_tpu.incubate.distributed.models.moe.moe_layer import (
+    SigmoidGate, _route)
+from paddle_tpu.nn import initializer as I
+from paddle_tpu.nn.layer.layers import Layer
+from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul, pick_block_rows
+
+__all__ = ["HeldExpertsMoE", "held_rows"]
+
+# rows laid out for a share's pairs, in shares of a balanced router. The
+# largest share a step saw on the CPU at published widths was 1.2 (PR 27)
+CAPACITY_FACTOR = 4.0
+
+
+def held_rows(pairs: int, held: int, num_expert: int, block_rows: int = 0,
+              capacity_factor: float = CAPACITY_FACTOR) -> tuple[int, int]:
+    """(rows laid out for the pairs routed here, rows of a block): every
+    pair where all experts are held, else `capacity_factor` shares."""
+    share = pairs * held // num_expert
+    bm = block_rows or pick_block_rows(max(share, 1), held)
+    if held == num_expert:
+        return pairs, bm
+    return min(_round_up(max(int(capacity_factor * share), bm), bm), pairs), bm
+
+
+def _held_moe(xv, logits, bias, wg, wu, wd, sg_w, su_w, sd_w, *, k, first,
+              routing, rows, block_rows, backend):
+    """The layer on plain arrays: xv [N, d], logits [N, E] float32, the
+    held experts' weights [G, ...] (experts first .. first + G - 1) and the
+    shared expert's. Returns (out [N, d], [pairs routed here, largest and
+    mean load of a held expert, pairs left out], load of ALL experts [E])."""
+    n, d = xv.shape
+    E, G = logits.shape[1], wg.shape[0]
+    topv, topi, _ = _route(logits.astype(jnp.float32), None, k=k,
+                           routing=routing, bias=bias)
+    flat = topi.reshape(-1)
+    local = flat - first
+    gids = jnp.where((local >= 0) & (local < G), local, G).astype(jnp.int32)
+    order, _, dest, gbuf, counts = ragged_layout(gids, G, block_rows, rows=rows)
+    here = jnp.take(gids, order) < G                  # routed pairs sort first
+    tok = (order // k).astype(jnp.int32)
+    wgt = jnp.take(topv.reshape(-1), order) * here
+    buf = jnp.zeros((gbuf.shape[0], d), xv.dtype).at[dest].set(
+        jnp.take(xv, tok, axis=0), mode="drop")
+    mm = functools.partial(grouped_matmul, gids=gbuf, block_rows=block_rows,
+                           backend=backend, aligned=True)
+    act = (jax.nn.silu(mm(buf, wg)) * mm(buf, wu)).astype(xv.dtype)
+    y = jnp.take(mm(act, wd), dest, axis=0, mode="fill", fill_value=0.0)
+    routed = jnp.zeros((n, d), jnp.float32).at[tok].add(y * wgt[:, None])
+    shared = (jax.nn.silu(xv @ sg_w) * (xv @ su_w)) @ sd_w
+    load = counts.astype(jnp.float32)
+    n_here = jnp.sum(load)
+    stats = jnp.stack([n_here, jnp.max(load), jnp.mean(load),
+                       jnp.maximum(n_here - rows, 0.0)])
+    load_all = jnp.zeros((E,), jnp.float32).at[flat].add(1.0)
+    return (routed + shared.astype(jnp.float32)).astype(xv.dtype), stats, load_all
+
+
+class HeldExpertsMoE(Layer):
+    """The experts `held_experts = (first, stop)` of a layer of `num_expert`
+    gated experts of width `d_hidden`, its sigmoid router (all `num_expert`
+    wide, `top_k` a token, its correction bias moved by `bias_update_rate`)
+    and its shared expert of width `d_hidden * num_shared`. After a forward
+    `step_stats` holds [pairs routed here, largest load, mean load of a held
+    expert, pairs left out] and `gate.next_bias` the bias after this step.
+
+    A share's router should be frozen (`gate.gate_weight.stop_gradient =
+    True`) unless an exchange brings the other experts' outputs: its
+    gradient is the held experts' alone and teaches it to avoid them."""
+
+    def __init__(self, d_model, num_expert, d_hidden, top_k, *,
+                 held_experts=None, routed_scale=1.0, renormalize=True,
+                 num_shared=1, bias_update_rate=0.0, block_rows=0,
+                 backend=None, recompute=False):
+        super().__init__()
+        first, stop = held_experts or (0, num_expert)
+        if not 0 <= first < stop <= num_expert:
+            raise ValueError(f"held_experts={held_experts!r} is no range of "
+                             f"the layer's {num_expert} experts")
+        self.num_expert, self.top_k = num_expert, top_k
+        self.held_experts = (first, stop)
+        self.block_rows, self.backend = block_rows, backend
+        self.recompute = bool(recompute)
+        held = stop - first
+        self.gate = SigmoidGate(d_model, num_expert, topk=top_k,
+                                routed_scale=routed_scale,
+                                renormalize=renormalize,
+                                bias_update_rate=bias_update_rate)
+        init = I.XavierNormal()
+        mk = lambda *shape: self.create_parameter(      # noqa: E731
+            list(shape), None, default_initializer=init)
+        self.w_gate, self.w_up = mk(held, d_model, d_hidden), mk(held, d_model, d_hidden)
+        self.w_down = mk(held, d_hidden, d_model)
+        hs = d_hidden * num_shared
+        self.shared_gate, self.shared_up = mk(d_model, hs), mk(d_model, hs)
+        self.shared_down = mk(hs, d_model)
+        self.l_aux = None           # balanced by the router's bias, no loss
+        self.tokens_dropped = None
+        self.step_stats = None
+
+    def forward(self, x):
+        from paddle_tpu.tuning.blocks import Resolution, note_derived
+
+        shape = x.shape
+        x2 = x.reshape([-1, shape[-1]])
+        first, stop = self.held_experts
+        pairs = x2.shape[0] * self.top_k
+        rows, bm = held_rows(pairs, stop - first, self.num_expert,
+                             self.block_rows)
+        # static a compiled program, as `last_resolution("kda")` is
+        note_derived(Resolution("held_experts", {"rows": rows, "block_rows": bm},
+                                "caller" if self.block_rows else "default",
+                                "CAPACITY_FACTOR"),
+                     pairs=pairs, buffer_rows=_round_up(rows, bm) + (stop - first) * bm)
+        fn = functools.partial(
+            _held_moe, k=self.top_k, first=first,
+            routing=self.gate.routing_config(self.training), rows=rows,
+            block_rows=bm, backend=self.backend)
+        if self.recompute:
+            fn = jax.checkpoint(fn)
+        out, stats, load = apply_op(
+            fn, x2, self.gate(x2), self.gate.e_score_correction_bias,
+            self.w_gate, self.w_up, self.w_down, self.shared_gate,
+            self.shared_up, self.shared_down, name="held_experts_moe",
+            n_outputs=3)
+        self.step_stats = stats
+        self.tokens_dropped = stats[3]
+        self.gate.next_bias = self.gate.balanced(load)
+        return out.reshape(shape)

@@ -44,7 +44,8 @@ from paddle_tpu.core.tensor import Tensor, apply_op
 from paddle_tpu.nn import initializer as I
 from paddle_tpu.nn.layer.layers import Layer
 
-__all__ = ["MoELayer", "ExpertFFN", "NaiveGate", "GShardGate", "SwitchGate"]
+__all__ = ["MoELayer", "ExpertFFN", "NaiveGate", "GShardGate", "SwitchGate",
+           "SigmoidGate"]
 
 EP_AXIS = "ep"
 
@@ -119,6 +120,54 @@ class SwitchGate(NaiveGate):
         return float(self.capacity[0 if training else 1])
 
 
+class SigmoidGate(NaiveGate):
+    """Sigmoid router with a correction bias (DeepSeek-V3 / Kimi family):
+    scores `s = sigmoid(x W)` in float32, the k experts with the largest
+    `s + b` are chosen, and a chosen expert weighs `scale * s_e / sum of the
+    chosen s` (`renormalize`), else `scale * s_e`. `b` balances load
+    without an auxiliary loss. It is a parameter no gradient reaches
+    (`stop_gradient`, float32 whatever the model is cast to); the balancing
+    rule moves it: after a step, up by `bias_update_rate` for an expert
+    that saw fewer tokens than the mean and down for one that saw more
+    (`balanced`). The layer that routes leaves the next value in `next_bias`
+    and `CompiledTrainStep` stores it with the step's new parameters.
+    Groups of experts (`num_expert_group`) are not limited here: with one
+    group that is the identity."""
+
+    def __init__(self, d_model, num_expert, world_size=1, topk=8,
+                 routed_scale=1.0, renormalize=True, bias_update_rate=0.0):
+        super().__init__(d_model, num_expert, world_size, topk)
+        self.routed_scale = float(routed_scale)
+        self.renormalize = bool(renormalize)
+        self.bias_update_rate = float(bias_update_rate)
+        self.e_score_correction_bias = self.create_parameter(
+            [num_expert], None, dtype="float32",
+            default_initializer=I.Constant(0.0))
+        self.e_score_correction_bias.stop_gradient = True
+        self.e_score_correction_bias.keep_dtype = True
+        self.next_bias = None
+
+    def forward(self, x):
+        # float32 scores from the operands as they are: bfloat16 products
+        # are exact in float32, so only the order of the sum is left open
+        return apply_op(lambda xv, w: jnp.dot(
+            xv, w, preferred_element_type=jnp.float32), x, self.gate_weight,
+            name="router_scores")
+
+    def balanced(self, load):
+        """The correction bias after a step in which expert e was chosen by
+        `load[e]` tokens (summed over the data-parallel ranks, where there
+        are any)."""
+        rate = self.bias_update_rate
+        return apply_op(
+            lambda b, n: b + rate * jnp.sign(jnp.mean(n) - n),
+            self.e_score_correction_bias, load, name="balance_bias")
+
+    def routing_config(self, training: bool) -> tuple:
+        return (("kind", "sigmoid"), ("routed_scale", self.routed_scale),
+                ("renormalize", self.renormalize))
+
+
 class ExpertFFN(Layer):
     """Batched expert MLPs: weights [E, d, dff] / [E, dff, d], expert dim
     sharded over the ep axis (the per-rank expert list of the reference)."""
@@ -148,13 +197,21 @@ class ExpertFFN(Layer):
         return apply_op(f, x, self.w1, self.b1, self.w2, self.b2, name="expert_ffn")
 
 
-def _route(logits, rng, *, k, routing):
+def _route(logits, rng, *, k, routing, bias=None):
     """Pure gate routing: logits [N, float32] -> (topv, topi) [N, k], with
     dropped selections marked topi == -1. Implements the reference gates'
     semantics (gshard_gate.py:77-84 random routing, switch_gate.py:48-52
-    jitter) as jnp ops."""
+    jitter) as jnp ops; `sigmoid` is SigmoidGate's (`bias` its correction
+    bias, which moves the choice and never the weight)."""
     cfg = dict(routing or ())
     kind = cfg.get("kind", "naive")
+    if kind == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, topi = jax.lax.top_k(scores if bias is None else scores + bias, k)
+        topv = jnp.take_along_axis(scores, topi, axis=-1)
+        if cfg.get("renormalize", True):
+            topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+        return topv * cfg.get("routed_scale", 1.0), topi, scores
     if kind == "switch" and cfg.get("switch_eps", 0.0) > 0.0:
         eps = cfg["switch_eps"]
         rng, sub = jax.random.split(rng)

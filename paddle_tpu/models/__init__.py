@@ -12,3 +12,7 @@ from paddle_tpu.models.gpt_moe import (  # noqa: F401
 from paddle_tpu.models.gpt import (  # noqa: F401
     GptConfig, GptForCausalLM, gpt_tiny_config,
 )
+from paddle_tpu.models.kimi_linear import (  # noqa: F401
+    KimiLinearConfig, KimiLinearForCausalLM, KimiLinearModel,
+    kimi_linear_tiny_config,
+)

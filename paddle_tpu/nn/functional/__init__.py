@@ -1383,7 +1383,9 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
         if valid is not None:
             scores = jnp.where(valid, scores, 2.0 * _NEG_BIAS)
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        out = jnp.einsum("bhgst,bhtd->bhgsd", probs, vh).reshape(b, hq, s_len, d)
+        # v's own width (latent attention: 128 beside a query/key width of 192)
+        out = jnp.einsum("bhgst,bhtd->bhgsd", probs, vh).reshape(
+            b, hq, s_len, vh.shape[-1])
         return jnp.swapaxes(out, 1, 2)
 
     args = [_t(query), _t(key), _t(value)]

@@ -227,7 +227,10 @@ class Layer:
         if dtype is not None:
             d = to_jax_dtype(dtype)
             for p in self.parameters():
-                if jnp.issubdtype(p._value.dtype, np.floating):
+                # `keep_dtype`: a value that accumulates small steps (a
+                # router's correction bias) stays in the type it was made in
+                if (jnp.issubdtype(p._value.dtype, np.floating)
+                        and not getattr(p, "keep_dtype", False)):
                     p._set_value(p._value.astype(d))
             for b in self.buffers():
                 if jnp.issubdtype(b._value.dtype, np.floating):

@@ -171,7 +171,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int, causal: bool,
 
     m0 = jnp.full((bq, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc0 = jnp.zeros((bq, q_ref.shape[-1]), jnp.float32)
+    acc0 = jnp.zeros((bq, v_ref.shape[-1]), jnp.float32)   # v's width, not q's
     m, l, acc = jax.lax.fori_loop(0, last, body, (m0, l0, acc0))
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
     lse_ref[0] = (m + jnp.log(jnp.maximum(l, 1e-30))).astype(jnp.float32)
@@ -254,9 +254,11 @@ def _block_skip_enabled() -> bool:
 
 def _flash_fwd(q, k, v, seg, causal: bool, scale: float, group: int,
                heads_q: int, interpret: bool):
-    """q: [BHq, S, D]; k,v: [BHkv, S, D] with BHq == BHkv*group;
-    seg: [B, S] int32 or None -> (out, lse)."""
+    """q: [BHq, S, D]; k: [BHkv, S, D]; v: [BHkv, S, Dv] with BHq ==
+    BHkv*group (Dv may differ from D: latent attention's 192 / 128);
+    seg: [B, S] int32 or None -> (out [BHq, S, Dv], lse)."""
     bh, s, d = q.shape
+    dv = v.shape[-1]
     block_q, block_k = _pick_blocks(s)
     grid = (bh, s // block_q)
     segmented = seg is not None
@@ -268,7 +270,7 @@ def _flash_fwd(q, k, v, seg, causal: bool, scale: float, group: int,
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         pl.BlockSpec((1, s, d), lambda b, i: (b // group, 0, 0)),
-        pl.BlockSpec((1, s, d), lambda b, i: (b // group, 0, 0)),
+        pl.BlockSpec((1, s, dv), lambda b, i: (b // group, 0, 0)),
     ]
     args = [q, k, v]
     if segmented:
@@ -281,17 +283,30 @@ def _flash_fwd(q, k, v, seg, causal: bool, scale: float, group: int,
     # Interpret mode keeps the ambient x64 (see kernel_trace_ctx): an outer
     # jit lowers the grid loops after this context exits, and an x32-traced /
     # x64-lowered jaxpr trips the StableHLO verifier on weak int literals.
+    # the forward keeps a head's whole K and V in VMEM, twice (the pipeline's
+    # two buffers). Past the compiler's own 16 MiB scope (8192 x 192 + 128:
+    # 12 MiB, refused) the call asks for what it needs; below it, as at
+    # 4096 x 128, nothing is passed and the call compiles as it always has
+    lanes = lambda n: -(-n // 128) * 128            # noqa: E731
+    resident = 2 * s * (lanes(d) + lanes(dv)) * q.dtype.itemsize
+    params = {}
+    if resident > 8 * 2**20 and not interpret:
+        from jax.experimental.pallas import tpu as pltpu
+
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=min(resident + 24 * 2**20, 100 * 2**20))
     with _kernel_trace_ctx(interpret):
         out, lse = pl.pallas_call(
             kernel,
             grid=grid,
+            **params,
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
                 pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+                jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
                 jax.ShapeDtypeStruct((bh, s, 1), jnp.float32),
             ],
             interpret=interpret,
@@ -417,6 +432,7 @@ def _flash_bwd(q, k, v, seg, out, lse, do, causal: bool, scale: float,
     """Blocked flash-2 backward. q/do/out/lse: [BHq, ...]; k/v: [BHkv, ...];
     seg: [B, S] int32 or None."""
     bhq, s, d = q.shape
+    dv = v.shape[-1]
     bhkv = k.shape[0]
     heads_kv = heads_q // group
     block_q, block_k = _pick_blocks_bwd(s)
@@ -430,8 +446,8 @@ def _flash_bwd(q, k, v, seg, out, lse, do, causal: bool, scale: float,
     dq_in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // group, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // group, j, 0)),
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b // group, j, 0)),
+        pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
     ]
@@ -462,8 +478,8 @@ def _flash_bwd(q, k, v, seg, out, lse, do, causal: bool, scale: float,
             pl.BlockSpec((1, block_q, d),
                          lambda b, j, qj: (b * group + qj // q_blocks, qj % q_blocks, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, qj: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, qj: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d),
+            pl.BlockSpec((1, block_k, dv), lambda b, j, qj: (b, j, 0)),
+            pl.BlockSpec((1, block_q, dv),
                          lambda b, j, qj: (b * group + qj // q_blocks, qj % q_blocks, 0)),
             pl.BlockSpec((1, block_q, 1),
                          lambda b, j, qj: (b * group + qj // q_blocks, qj % q_blocks, 0)),
@@ -487,11 +503,11 @@ def _flash_bwd(q, k, v, seg, out, lse, do, causal: bool, scale: float,
             in_specs=dkv_in_specs,
             out_specs=[
                 pl.BlockSpec((1, block_k, d), lambda b, j, qj: (b, j, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, j, qj: (b, j, 0)),
+                pl.BlockSpec((1, block_k, dv), lambda b, j, qj: (b, j, 0)),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((bhkv, s, d), jnp.float32),
-                jax.ShapeDtypeStruct((bhkv, s, d), jnp.float32),
+                jax.ShapeDtypeStruct((bhkv, s, dv), jnp.float32),
             ],
             interpret=interpret,
             **_compat.kernel_name("flash_dkv"),
@@ -611,7 +627,8 @@ _flash3_seg.defvjp(_flash3_seg_fwd, _flash3_seg_bwd)
 def flash_attention_bhsd(q, k, v, causal: bool = False,
                          scale: float | None = None, segment_ids=None,
                          interpret: bool | None = None):
-    """q: [B, Hq, S, D]; k,v: [B, Hkv, S, D] with Hq % Hkv == 0 (GQA/MQA).
+    """q: [B, Hq, S, D]; k: [B, Hkv, S, D]; v: [B, Hkv, S, Dv] with
+    Hq % Hkv == 0 (GQA/MQA). Dv may differ from D; the output is v's width.
     segment_ids: [B, S] int32 packed-document ids (attention is then
     block-diagonal per document, with whole K blocks skipped when no segment
     overlaps the Q block)."""
@@ -637,13 +654,13 @@ def flash_attention_bhsd(q, k, v, causal: bool = False,
         b, hq, hkv = q.shape[0], q.shape[1], k.shape[1]   # this shard's
         q3 = q.reshape(b * hq, s, d)
         k3 = k.reshape(b * hkv, s, d)
-        v3 = v.reshape(b * hkv, s, d)
+        v3 = v.reshape(b * hkv, s, v.shape[-1])
         if seg:
             out = _flash3_seg(q3, k3, v3, seg[0], causal, scale, group, hq,
                               interpret)
         else:
             out = _flash3(q3, k3, v3, causal, scale, group, interpret)
-        return out.reshape(b, hq, s, d)
+        return out.reshape(b, hq, s, v.shape[-1])
 
     args = (q, k, v) + (() if seg is None else (seg,))
     mesh = _compat.gspmd_mesh(q, k, v)

@@ -11,22 +11,27 @@ its rows — O(actual tokens), not O(E*C).
 Kernel design (the PR-5/PR-9 ragged-block pattern, expert buckets as one
 more segment vocabulary):
 
-  * forward — grid (row_blocks, G). The output tile [bm, h] for row block i
-    accumulates over the trailing (sequential on TPU) group dim; a group g
-    is SKIPPED for row block i unless g intersects the block's group-id
-    range — the SAME `_seg_blocks_can_touch` predicate the flash/paged
-    attention kernels use for packed-segment block skipping. With the
-    dispatcher's block-aligned layout each row block matches exactly one
-    group, so the kernel visits (row_blocks) of (row_blocks*G) tiles.
+  * forward — grid (column tiles, row blocks, span). Each row block's group
+    range is prefetched into SMEM; step (j, i, s) takes group gmin[i] + s,
+    is SKIPPED past the block's largest id, and its weight index map is
+    clamped into the block's range so that a skipped step moves no data.
+    The output tile [bm, bn] accumulates over the trailing span. With the
+    dispatcher's block-aligned layout span is 1 and the weights of a group
+    stay in VMEM while its row blocks pass. The weight is tiled over its
+    output columns ([K, bn], bn from `_col_tile`): a whole [2304, 1024]
+    expert in float32 does not fit the chip's 16 MB scope.
   * dx — the forward kernel over `w` transposed (same skip structure).
-  * dw — grid (G, row_blocks): dw[g] accumulates masked x_blk^T @ dy_blk
-    across the trailing row-block dim under the same predicate.
-  * `grouped_matmul_visit_counts` runs the predicate as its own kernel so
-    the bench counter provably counts what the compute kernels execute
+  * dw — the same grid; dw[g]'s [K, bn] tile is the resident output while
+    the row blocks of g pass (groups come sorted, so they follow one
+    another), zeroed at the first; groups with no row block are zeroed
+    outside.
+  * `grouped_matmul_visit_counts` runs the range predicate as its own
+    kernel so the bench counter counts what the compute kernels execute
     (mirrors `segment_block_visit_counts`).
 
 Accumulation is fp32 (the returned array is fp32; callers cast), so bf16
-inputs meet the dense-reference parity bounds.
+inputs meet the dense-reference parity bounds; operands reach the MXU in
+their own type.
 
 Backends: `pallas` (TPU, or interpret mode under `force_interpret()` so
 tier-1 CPU tests exercise the exact kernel code), and an `xla` fallback —
@@ -47,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.pallas import _compat
 from paddle_tpu.ops.pallas._compat import x64_off as _x64_off
@@ -94,84 +100,158 @@ def _resolve_backend(backend: str | None) -> str:
 # ---------------------------------------------------------------------------
 # pallas kernels
 # ---------------------------------------------------------------------------
+#
+# Grid (column tiles, row blocks, span). Each row block's smallest and
+# largest group id are prefetched as scalars, so the index maps can follow
+# them: step (j, i, s) works on group gmin[i] + s and asks for THAT group's
+# [K, bn] weight tile, clamped to the block's own range, so a step that has
+# nothing to do names the block its neighbour had and moves no data. `span`
+# is how many groups a row block may hold: 1 under the dispatcher's
+# block-aligned layout (one launch a row block and column tile, no row
+# mask), the number of groups for any sorted layout. The weights are tiled
+# over their output columns (a [2304, 1024] expert whole, cast to float32,
+# was 19-36 MB of the 16 MB scope); operands go to the MXU in their own type
+# and accumulate in float32.
 
-def _gmm_fwd_kernel(gid_ref, x_ref, w_ref, o_ref):
-    g = pl.program_id(1)
+_TILE_ELEMS = 1_250_000        # K x bn of one weight / dw tile
 
-    @pl.when(g == 0)
+
+def _col_tile(k: int, n: int) -> int:
+    """Columns of a weight tile: the widest divisor of n that is a multiple
+    of 128 lanes and keeps the [k, bn] tile under _TILE_ELEMS; n whole when
+    it is small or has no such divisor."""
+    if k * n <= _TILE_ELEMS or n % 128:
+        return n
+    fits = [b for b in range(128, n + 1, 128) if n % b == 0 and k * b <= _TILE_ELEMS]
+    return max(fits) if fits else 128
+
+
+def _group_of(gmin_ref, gmax_ref, i, s, num_groups):
+    """(group step (i, s) works on, whether it has work, the weight / dw
+    block it names)."""
+    g = gmin_ref[i] + s
+    needed = jnp.logical_and(g <= gmax_ref[i], g < num_groups)
+    block = jnp.minimum(jnp.minimum(g, gmax_ref[i]), num_groups - 1)
+    return g, needed, block
+
+
+def _group_tile_map(num_groups: int):
+    """Index map of a [1, K, bn] tile of the weights (forward) or of dw:
+    the group step (j, i, s) works on, column tile j."""
+    def index(j, i, s, gmin_ref, gmax_ref):
+        return (_group_of(gmin_ref, gmax_ref, i, s, num_groups)[2], 0, j)
+
+    return index
+
+
+def _gmm_fwd_kernel(gmin_ref, gmax_ref, gid_ref, x_ref, w_ref, o_ref, *,
+                    num_groups: int, span: int):
+    i, s = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(s == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    gid = gid_ref[0]                                     # [bm] int32
-    needed = _seg_blocks_can_touch(jnp.min(gid), jnp.max(gid), g, g)
+    g, needed, _ = _group_of(gmin_ref, gmax_ref, i, s, num_groups)
 
     @pl.when(needed)
     def _compute():
-        x = x_ref[...].astype(jnp.float32)               # [bm, d]
-        w = w_ref[0].astype(jnp.float32)                 # [d, h]
-        mask = (gid == g).astype(jnp.float32)[:, None]
-        o_ref[...] += jax.lax.dot(x * mask, w,
-                                  preferred_element_type=jnp.float32)
+        x = x_ref[...]                                   # [bm, K]
+        if span > 1:        # rows of other groups share the block
+            x = x * (gid_ref[...] == g).astype(x.dtype)      # [bm, 1]: a column
+        o_ref[...] += jax.lax.dot(x, w_ref[0],
+                                  preferred_element_type=jnp.float32
+                                  ).astype(o_ref.dtype)
 
 
-def _gmm_dw_kernel(gid_ref, x_ref, dy_ref, dw_ref):
-    g = pl.program_id(0)
-    i = pl.program_id(1)
+def _gmm_dw_kernel(gmin_ref, gmax_ref, gid_ref, x_ref, dy_ref, dw_ref, *,
+                   num_groups: int, span: int):
+    i, s = pl.program_id(1), pl.program_id(2)
+    g, needed, block = _group_of(gmin_ref, gmax_ref, i, s, num_groups)
+    # the block the step before named: groups come sorted, so a group's steps
+    # follow one another and its tile is zeroed when they begin
+    i0 = jnp.maximum(i - 1, 0)
+    before = jnp.where(s > 0,
+                       _group_of(gmin_ref, gmax_ref, i, s - 1, num_groups)[2],
+                       _group_of(gmin_ref, gmax_ref, i0, span - 1, num_groups)[2])
+    first = jnp.logical_or(jnp.logical_and(i == 0, s == 0), before != block)
 
-    @pl.when(i == 0)
+    @pl.when(jnp.logical_and(needed, first))
     def _init():
         dw_ref[...] = jnp.zeros_like(dw_ref)
 
-    gid = gid_ref[0]
-    needed = _seg_blocks_can_touch(jnp.min(gid), jnp.max(gid), g, g)
-
     @pl.when(needed)
     def _compute():
-        x = x_ref[...].astype(jnp.float32)               # [bm, d]
-        dy = dy_ref[...].astype(jnp.float32)             # [bm, h]
-        mask = (gid == g).astype(jnp.float32)[:, None]
-        dw_ref[0] += jax.lax.dot((x * mask).T, dy,
-                                 preferred_element_type=jnp.float32)
+        x = x_ref[...]
+        if span > 1:
+            x = x * (gid_ref[...] == g).astype(x.dtype)      # [bm, 1]: a column
+        dw_ref[0] += jax.lax.dot_general(
+            x, dy_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
 
-def _gmm_fwd_pallas(x, w, gids, block_rows, interpret):
-    m, d = x.shape
-    num_groups, _, h = w.shape
-    gid2 = gids.reshape(1, m)
+def _block_ranges(gids, block_rows):
+    gb = gids.reshape(-1, block_rows)
+    return jnp.min(gb, axis=1), jnp.max(gb, axis=1)
+
+
+def _gmm_fwd_pallas(x, w, gids, block_rows, interpret, span=0,
+                    out_dtype=jnp.float32):
+    m, k = x.shape
+    num_groups, _, n = w.shape
+    span = span or num_groups
+    bn = _col_tile(k, n)
+    gmin, gmax = _block_ranges(gids, block_rows)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n // bn, m // block_rows, span),
+        in_specs=[
+            pl.BlockSpec((block_rows, 1), lambda j, i, s, *_: (i, 0)),
+            pl.BlockSpec((block_rows, k), lambda j, i, s, *_: (i, 0)),
+            pl.BlockSpec((1, k, bn), _group_tile_map(num_groups)),
+        ],
+        out_specs=pl.BlockSpec((block_rows, bn), lambda j, i, s, *_: (i, j)),
+    )
     with _x64_off():
         return pl.pallas_call(
-            _gmm_fwd_kernel,
-            grid=(m // block_rows, num_groups),
-            in_specs=[
-                pl.BlockSpec((1, block_rows), lambda i, g: (0, i)),
-                pl.BlockSpec((block_rows, d), lambda i, g: (i, 0)),
-                pl.BlockSpec((1, d, h), lambda i, g: (g, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((block_rows, h), lambda i, g: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((m, h), jnp.float32),
+            functools.partial(_gmm_fwd_kernel, num_groups=num_groups, span=span),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
             interpret=interpret,
             **_compat.kernel_name("grouped_matmul"),
-        )(gid2, x, w)
+        )(gmin, gmax, gids.reshape(m, 1), x, w)
 
 
-def _gmm_dw_pallas(x, dy, gids, num_groups, block_rows, interpret):
-    m, d = x.shape
-    h = dy.shape[1]
-    gid2 = gids.reshape(1, m)
+def _gmm_dw_pallas(x, dy, gids, num_groups, block_rows, interpret, span=0):
+    m, k = x.shape
+    n = dy.shape[1]
+    span = span or num_groups
+    bn = _col_tile(k, n)
+    gmin, gmax = _block_ranges(gids, block_rows)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n // bn, m // block_rows, span),
+        in_specs=[
+            pl.BlockSpec((block_rows, 1), lambda j, i, s, *_: (i, 0)),
+            pl.BlockSpec((block_rows, k), lambda j, i, s, *_: (i, 0)),
+            pl.BlockSpec((block_rows, bn), lambda j, i, s, *_: (i, j)),
+        ],
+        out_specs=pl.BlockSpec((1, k, bn), _group_tile_map(num_groups)),
+    )
     with _x64_off():
-        return pl.pallas_call(
-            _gmm_dw_kernel,
-            grid=(num_groups, m // block_rows),
-            in_specs=[
-                pl.BlockSpec((1, block_rows), lambda g, i: (0, i)),
-                pl.BlockSpec((block_rows, d), lambda g, i: (i, 0)),
-                pl.BlockSpec((block_rows, h), lambda g, i: (i, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, d, h), lambda g, i: (g, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((num_groups, d, h), jnp.float32),
+        dw = pl.pallas_call(
+            functools.partial(_gmm_dw_kernel, num_groups=num_groups, span=span),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((num_groups, k, n), jnp.float32),
             interpret=interpret,
             **_compat.kernel_name("grouped_matmul_dw"),
-        )(gid2, x, dy)
+        )(gmin, gmax, gids.reshape(m, 1), x, dy)
+    # a group no row block holds is never visited: its tile was never written
+    ids = jnp.arange(num_groups, dtype=gids.dtype)[:, None]
+    held = jnp.any(jnp.logical_and(gmin[None] <= ids, ids <= gmax[None]), axis=1)
+    return jnp.where(held[:, None, None], dw, 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -179,15 +259,19 @@ def _gmm_dw_pallas(x, dy, gids, num_groups, block_rows, interpret):
 # a batched matmul over w[blk_gid], exact for block-aligned layouts)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _gmm(x, w, gids, num_groups, block_rows, backend, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _gmm(x, w, gids, num_groups, block_rows, backend, interpret, span):
     return _gmm_forward(x, w, gids, num_groups, block_rows, backend,
-                        interpret)
+                        interpret, span)
 
 
-def _gmm_forward(x, w, gids, num_groups, block_rows, backend, interpret):
+def _gmm_forward(x, w, gids, num_groups, block_rows, backend, interpret,
+                 span=0, out_dtype=jnp.float32):
+    """x [M, K] @ w[gids] [G, K, N] -> [M, N]. `span` is the most groups a
+    row block holds (0: any sorted layout; 1: block-aligned)."""
     if backend == "pallas":
-        return _gmm_fwd_pallas(x, w, gids, block_rows, interpret)
+        return _gmm_fwd_pallas(x, w, gids, block_rows, interpret, span,
+                               out_dtype)
     m, d = x.shape
     bm = block_rows
     xb = x.reshape(m // bm, bm, d)
@@ -198,12 +282,14 @@ def _gmm_forward(x, w, gids, num_groups, block_rows, backend, interpret):
     xm = xb.astype(jnp.float32) * mask.astype(jnp.float32)[..., None]
     y = jnp.einsum("bmd,bdh->bmh", xm, wb.astype(jnp.float32),
                    preferred_element_type=jnp.float32)
-    return y.reshape(m, w.shape[-1])
+    return y.reshape(m, w.shape[-1]).astype(out_dtype)
 
 
-def _gmm_backward_dw(x, dy, gids, num_groups, block_rows, backend, interpret):
+def _gmm_backward_dw(x, dy, gids, num_groups, block_rows, backend, interpret,
+                     span=0):
     if backend == "pallas":
-        return _gmm_dw_pallas(x, dy, gids, num_groups, block_rows, interpret)
+        return _gmm_dw_pallas(x, dy, gids, num_groups, block_rows, interpret,
+                              span)
     m, d = x.shape
     h = dy.shape[1]
     bm = block_rows
@@ -220,19 +306,22 @@ def _gmm_backward_dw(x, dy, gids, num_groups, block_rows, backend, interpret):
         per_block * (blk_g < num_groups).astype(jnp.float32)[:, None, None])
 
 
-def _gmm_vjp_fwd(x, w, gids, num_groups, block_rows, backend, interpret):
-    y = _gmm_forward(x, w, gids, num_groups, block_rows, backend, interpret)
+def _gmm_vjp_fwd(x, w, gids, num_groups, block_rows, backend, interpret,
+                 span):
+    y = _gmm_forward(x, w, gids, num_groups, block_rows, backend, interpret,
+                     span)
     return y, (x, w, gids)
 
 
-def _gmm_vjp_bwd(num_groups, block_rows, backend, interpret, res, dy):
+def _gmm_vjp_bwd(num_groups, block_rows, backend, interpret, span, res, dy):
     x, w, gids = res
     # dx: the SAME grouped structure over w transposed; dw: per-group
     # accumulation under the same block-skip predicate
-    dx = _gmm_forward(dy, jnp.swapaxes(w, 1, 2).astype(jnp.float32), gids,
-                      num_groups, block_rows, backend, interpret)
-    dw = _gmm_backward_dw(x, dy, gids, num_groups, block_rows, backend,
-                          interpret)
+    dyl = dy.astype(x.dtype)
+    dx = _gmm_forward(dyl, jnp.swapaxes(w, 1, 2), gids,
+                      num_groups, block_rows, backend, interpret, span)
+    dw = _gmm_backward_dw(x, dyl, gids, num_groups, block_rows, backend,
+                          interpret, span)
     dgids = np.zeros(gids.shape, jax.dtypes.float0)
     return dx.astype(x.dtype), dw.astype(w.dtype), dgids
 
@@ -241,7 +330,7 @@ _gmm.defvjp(_gmm_vjp_fwd, _gmm_vjp_bwd)
 
 
 def grouped_matmul(x, w, gids, *, block_rows: int | None = None,
-                   backend: str | None = None):
+                   backend: str | None = None, aligned: bool = False):
     """y[i] = x[i] @ w[gids[i]] over ragged, group-contiguous rows.
 
     x: [M, d]; w: [G, d, h]; gids: [M] int32 in [0, G] — rows with
@@ -250,11 +339,12 @@ def grouped_matmul(x, w, gids, *, block_rows: int | None = None,
     input dtype). Differentiable in x and w (custom-vjp; dx/dw run the
     grouped kernels, never a dense [M, G] mask).
 
-    Layout contract: rows grouped by id with each block_rows-row block
-    belonging to one group (what the MoE dispatcher emits). The pallas
-    backend additionally masks within blocks, so it is exact for any
-    grouped layout; the xla fallback zeroes rows that disagree with their
-    block's leading id.
+    Layout contract: rows sorted by id. The pallas backend masks within
+    blocks, so it is exact for any sorted layout; with `aligned=True` the
+    caller states that every block_rows-row block belongs to one group
+    (what the MoE dispatcher emits) and the kernels take one step a block
+    with no mask. The xla fallback always needs the aligned layout: it
+    zeroes rows that disagree with their block's leading id.
     """
     m, d = x.shape
     num_groups = w.shape[0]
@@ -283,7 +373,7 @@ def grouped_matmul(x, w, gids, *, block_rows: int | None = None,
     backend = _resolve_backend(backend)
     interpret = _interpret_mode() if backend == "pallas" else False
     return _gmm(x, w, gids.astype(jnp.int32), num_groups, bm, backend,
-                interpret)
+                interpret, 1 if aligned else 0)
 
 
 # ---------------------------------------------------------------------------

@@ -508,13 +508,23 @@ class CompiledTrainStep:
         # checkpoint, so collection is limited to remat-off steps)
         self._moe_layers = []
         if self._telemetry and not self.remat:
-            from paddle_tpu.incubate.distributed.models.moe import MoELayer
+            from paddle_tpu.incubate.distributed.models.moe import (
+                HeldExpertsMoE, MoELayer)
 
             self._moe_layers = [
                 l for l in getattr(model, "sublayers", lambda: [])()
-                if isinstance(l, MoELayer)]
+                if isinstance(l, (MoELayer, HeldExpertsMoE))]
         if self._moe_layers:
             self._metric_keys += ["moe_aux", "moe_dropped"]
+        # layers that hold a share of their experts also count the
+        # token-expert pairs routed to them and the held experts' load
+        self._moe_load = any(hasattr(l, "step_stats") for l in self._moe_layers)
+        if self._moe_load:
+            self._metric_keys += ["moe_routed_slots", "moe_max_expert_load",
+                                  "moe_mean_expert_load"]
+        # summed over settled steps: host_counters()["moe"]
+        self._moe_totals = {"steps": 0, "routed_slots": 0.0, "dropped": 0.0,
+                            "max_expert_load": 0.0, "mean_expert_load": 0.0}
         self._pending_metrics: list = []
         self._last_metrics: dict | None = None
         self._prev_metric_wall: float | None = None
@@ -566,6 +576,19 @@ class CompiledTrainStep:
                     self.scan_layers = True
         self._trainable = ([not p.stop_gradient for p in self._outer_params]
                            + [True] * len(self._group_cols))
+        # routers whose correction bias the balancing rule moves
+        # (`SigmoidGate.bias_update_rate`): the layer that routes leaves the
+        # next value in the trace (`next_bias`), outside the gradient, and
+        # the step stores it with the new parameters. Not under whole-loss
+        # remat, where a value of the trace cannot leave the checkpoint.
+        self._bias_gates = []
+        if not self.remat:
+            index = {id(p): i for i, p in enumerate(self._outer_params)}
+            self._bias_gates = [
+                (g, index[id(g.e_score_correction_bias)])
+                for g in getattr(model, "sublayers", lambda: [])()
+                if getattr(g, "bias_update_rate", 0.0) > 0.0
+                and id(g.e_score_correction_bias) in index]
         self.zero_stage = zero_stage
         # offload needs the mesh-based shardings to stream states H2D in-step
         self._offload = (offload_optimizer and host_memory_supported()
@@ -859,7 +882,17 @@ class CompiledTrainStep:
                 if l.tokens_dropped is not None:
                     dropped = (dropped
                                + l.tokens_dropped._value.astype(jnp.float32))
-            return jnp.stack([aux, dropped])
+            parts = [aux, dropped]
+            if self._moe_load:
+                # [pairs routed here, largest load, mean load, dropped] a
+                # layer: pairs summed, the largest load over the layers,
+                # the mean load averaged over them
+                st = jnp.stack([l.step_stats._value.astype(jnp.float32)
+                                for l in self._moe_layers
+                                if getattr(l, "step_stats", None) is not None])
+                parts += [jnp.sum(st[:, 0]), jnp.max(st[:, 1]),
+                          jnp.mean(st[:, 2])]
+            return jnp.stack(parts)
 
         def loss_all(train_vals, fp8_s):
             full = list(param_vals)
@@ -870,19 +903,20 @@ class CompiledTrainStep:
             with jax.named_scope("loss"):
                 loss = run_loss(full, fp8_s)
             moe_vec = moe_stats() if self._moe_layers else None
+            biases = [g.next_bias._value for g, _ in self._bias_gates]
             # float16 loss scaling happens INSIDE the differentiated fn so
             # the whole backward benefits; the aux output reports the
             # unscaled loss
             if scaling:
-                return loss * scaler_scale.astype(loss.dtype), (loss,
-                                                                moe_vec)
-            return loss, (loss, moe_vec)
+                return loss * scaler_scale.astype(loss.dtype), (
+                    loss, moe_vec, biases)
+            return loss, (loss, moe_vec, biases)
 
         train_vals = [param_vals[i] for i in trainable_idx]
         # the gradient of the loss w.r.t. the fp8 amax histories IS their
         # updated value (the fp8_dot custom-vjp's state-as-gradient
         # contract), so new_fp8 below is next step's state pytree
-        (_, (loss, moe_vec)), (grads, new_fp8) = jax.value_and_grad(
+        (_, (loss, moe_vec, next_biases)), (grads, new_fp8) = jax.value_and_grad(
             loss_all, argnums=(0, 1), has_aux=True)(train_vals, fp8_in)
 
         found_inf = None
@@ -939,7 +973,7 @@ class CompiledTrainStep:
                     jnp.max(jnp.stack([jnp.max(l) for l in leaves]))
                     if leaves else jnp.zeros((), jnp.float32))
             if self._moe_layers:
-                parts.extend([moe_vec[0], moe_vec[1]])
+                parts.extend(list(moe_vec))
             step_metrics = jnp.stack(parts)
         new_params = list(param_vals)
         new_states = list(opt_states) if opt_states is not None else None
@@ -983,6 +1017,9 @@ class CompiledTrainStep:
                            for k, v in ns_.items()}
                 new_params[i] = np_
                 new_states[i] = ns_
+        for (_, i), b in zip(self._bias_gates, next_biases):
+            new_params[i] = (b if found_inf is None
+                             else jnp.where(found_inf, param_vals[i], b))
         if fp8_on or scaling or self._anomaly:
             flag_out = (found_inf.astype(jnp.float32) if found_inf is not None
                         else jnp.zeros((), jnp.float32))
@@ -1237,6 +1274,14 @@ class CompiledTrainStep:
                     (wall - self._prev_metric_wall) * 1e3, 3)
             self._prev_metric_wall = wall
             self._last_metrics = rec
+            if self._moe_load:
+                tot = self._moe_totals
+                tot["steps"] += 1
+                tot["routed_slots"] += rec["moe_routed_slots"]
+                tot["dropped"] += rec["moe_dropped"]
+                # per-step readings, summed: divide by `steps` for the mean
+                tot["max_expert_load"] += rec["moe_max_expert_load"]
+                tot["mean_expert_load"] += rec["moe_mean_expert_load"]
 
     def last_metrics(self) -> dict | None:
         """The most recent SETTLED step's telemetry: {step, loss,
@@ -1258,12 +1303,22 @@ class CompiledTrainStep:
         and building (`train.build`: the first call's program construction,
         trace and compile), with the number of calls (`steps`) and builds,
         and what JAX's compile log (`core.compile_cache`) holds for this
-        class's program, process-wide."""
+        class's program, process-wide. With telemetry on and a model that
+        holds a share of its experts, `moe` sums over the SETTLED steps
+        (read with the loss, never by a sync of their own) the token-expert
+        pairs routed here (`moe.routed_slots`), the pairs past the rows
+        laid out for them (`moe.dropped`) and each step's largest and mean
+        load of a held expert (`moe.max_expert_load`,
+        `moe.mean_expert_load`); `steps` is how many steps the sums hold."""
         from paddle_tpu.core.compile_cache import compile_totals
 
-        return {"steps": self._calls, "builds": self._builds,
-                **{f"{k}_s": v for k, v in self._host_s.items()},
-                "compile": compile_totals("jit(_step_fn)")}
+        out = {"steps": self._calls, "builds": self._builds,
+               **{f"{k}_s": v for k, v in self._host_s.items()},
+               "compile": compile_totals("jit(_step_fn)")}
+        if self._telemetry and self._moe_load:
+            self.settle_metrics(block=False)
+            out["moe"] = dict(self._moe_totals)
+        return out
 
     def cost_analysis(self) -> dict:
         """XLA's own cost model for ONE compiled step (flops, bytes

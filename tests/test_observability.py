@@ -439,10 +439,10 @@ def _kernel_cases():
     import jax
     import jax.numpy as jnp
 
-    fa, ce, pa, gm, rk = (
+    fa, ce, pa, gm, rk, kda = (
         importlib.import_module("paddle_tpu.ops.pallas." + m)
         for m in ("flash_attention", "fused_ce", "paged_attention",
-                  "grouped_matmul", "rmsnorm_kernel"))
+                  "grouped_matmul", "rmsnorm_kernel", "kda"))
     f32, i32 = jnp.float32, jnp.int32
 
     def flash(q):
@@ -492,6 +492,11 @@ def _kernel_cases():
         "grouped_matmul_block_count": (
             lambda g: gm.grouped_matmul_visit_counts(g, 2, 16, True),
             (gids,), ["grouped_matmul_block_count"]),
+        "kda": (
+            lambda q, g, b: jax.grad(lambda q: kda.kda_chunked(
+                q, q, q, g, b, interpret=True).sum())(q),
+            (jnp.ones((1, 64, 2, 128), f32), -jnp.ones((1, 64, 2, 128), f32),
+             jnp.ones((1, 64, 2), f32) * 0.5), ["kda_fwd", "kda_fwd", "kda_bwd"]),
     }
 
 
@@ -499,7 +504,7 @@ class TestKernelNames:
     @pytest.mark.parametrize("case", [
         "flash_attention", "flash_block_count", "fused_ce", "paged_decode",
         "paged_block_count", "rmsnorm", "grouped_matmul",
-        "grouped_matmul_block_count"])
+        "grouped_matmul_block_count", "kda"])
     def test_every_pallas_call_is_named(self, case):
         fn, args, want = _kernel_cases()[case]
         found = _pallas_names(fn, *args)
@@ -518,7 +523,7 @@ class TestKernelNames:
                 src = open(os.path.join(root, f)).read()
                 calls += len(re.findall(r"pl\.pallas_call\(", src))
                 names += len(re.findall(r"\*\*_compat\.kernel_name\(", src))
-        assert calls == names == 12
+        assert calls == names == 14       # PR 27: kda_fwd, kda_bwd
 
 
 class TestCompileLog:

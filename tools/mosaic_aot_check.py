@@ -2,7 +2,8 @@
 """Does Mosaic accept the Pallas kernels at 7B-width shapes? Compile-only.
 
     python tools/mosaic_aot_check.py            # the dense train/serve path
-    python tools/mosaic_aot_check.py --extra    # + the kernels off that path
+    python tools/mosaic_aot_check.py --extra    # + the kernels off that path,
+                                                #   Kimi-Linear's among them
 
 Each case is lowered and fully compiled for a TPU — block-shape checks,
 Mosaic's own lowering, the scoped-VMEM limit — and reported as `ok` or
@@ -124,7 +125,67 @@ def _cases(extra: bool):
         "segment flash fwd seq 4096 batch 2": (
             flash, [((2, s, h, d), bf)] * 3 + [((2, s), i32)]),
     }
+    off_path.update(_kimi_linear_cases())
     return dense, off_path
+
+
+def _kimi_linear_cases():
+    """The kernels of `train_kimilinear_seq8k` at its shapes: 2 rows x 8192,
+    32 heads x 128 (KDA), query/key 192 beside value 128 (latent attention),
+    8 held experts of [2304, 1024] over some 4096 routed rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.incubate.distributed.models.moe.held_experts import (
+        _held_moe, held_rows)
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_bshd
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+    from paddle_tpu.ops.pallas.kda import kda_chunked
+
+    bf, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
+    rows, s, h, hid, inter = 2, 8192, 32, 2304, 1024
+
+    def kda_grad(q, k, v, g, beta):
+        return jax.grad(lambda *a: kda_chunked(*a, interpret=False).astype(f32).sum(),
+                        argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+
+    def mla_grad(q, k, v):
+        return jax.grad(lambda *a: flash_attention_bshd(
+            *a, causal=True, interpret=False).astype(f32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    def gmm_grad(x, w, g):
+        return jax.grad(lambda a, b: grouped_matmul(
+            a, b, g, block_rows=128, backend="pallas", aligned=True).sum(),
+            argnums=(0, 1))(x, w)
+
+    laid_out, bm = held_rows(rows * s * 8, 8, 256)
+    routing = (("kind", "sigmoid"), ("routed_scale", 2.446), ("renormalize", True))
+
+    def experts_grad(x, logits, bias, *w):
+        # the whole layer as the step runs it: route over 256, lay out the
+        # pairs of the 8 held experts, three grouped products, the shared expert
+        return jax.grad(lambda a, *b: _held_moe(
+            a, logits, bias, *b, k=8, first=0, routing=routing, rows=laid_out,
+            block_rows=bm, backend="pallas")[0].astype(f32).sum(),
+            argnums=tuple(range(7)))(x, *w)
+
+    head = [((rows, s, h, 128), bf)] * 3
+    return {
+        "KDA scan fwd+bwd 2 x 8192 x 32 heads x 128": (
+            kda_grad, head + [((rows, s, h, 128), f32), ((rows, s, h), f32)]),
+        "flash fwd+bwd seq 8192, q/k 192, v 128": (
+            mla_grad, [((rows, s, h, 192), bf)] * 2 + [((rows, s, h, 128), bf)]),
+        "grouped matmul fwd+dx+dw 5120 x 2304 -> 8 x [2304,1024]": (
+            gmm_grad, [((5120, hid), bf), ((8, hid, inter), bf), ((5120,), i32)]),
+        "grouped matmul fwd+dx+dw 5120 x 1024 -> 8 x [1024,2304]": (
+            gmm_grad, [((5120, inter), bf), ((8, inter, hid), bf), ((5120,), i32)]),
+        "held experts layer fwd+bwd 16384 tokens x 8 of 256": (
+            experts_grad,
+            [((rows * s, hid), bf), ((rows * s, 256), f32), ((256,), f32),
+             ((8, hid, inter), bf), ((8, hid, inter), bf), ((8, inter, hid), bf),
+             ((hid, inter), bf), ((hid, inter), bf), ((inter, hid), bf)]),
+    }
 
 
 def _mesh_case(devices):
