@@ -71,7 +71,8 @@ class KimiLinearConfig:
     dtype: str = "float32"
     # every mixer and every feed-forward part keeps only its input between
     # the forward and the backward pass and is computed again there: a KDA
-    # layer's q, k, v, decays and gates at 16k tokens are some 2.5 GB
+    # layer's q, k, v, decays and gates at 16k tokens are some 2.5 GB (a KDA
+    # row also keeps its scan's output, a fortieth of that: KimiDeltaAttention)
     recompute: bool = True
 
 
@@ -114,8 +115,8 @@ def _unit(x, scale=1.0):
 class _Block(nn.Layer):
     recompute = True
 
-    def _fn(self, fn):
-        return jax.checkpoint(fn) if self.recompute else fn
+    def _fn(self, fn, policy=None):
+        return jax.checkpoint(fn, policy=policy) if self.recompute else fn
 
     def _mat(self, *shape, init=None):
         return self.create_parameter(list(shape), None,
@@ -127,7 +128,15 @@ class _Block(nn.Layer):
 
 
 class KimiDeltaAttention(_Block):
-    """x + W_o (RMSNorm(KDA(q, k, v, g, beta)) * gate): docs/linear_attention.md."""
+    """x + W_o (RMSNorm(KDA(q, k, v, g, beta)) * gate): docs/linear_attention.md.
+
+    With `recompute` a row keeps its input AND the scan's output `o`
+    (`kda_chunked` names it `KDA_OUT`): `2 * T * H * V` bytes a row, 64 MiB at
+    8192 x 32 x 128 in bfloat16, against the 2.5 GB the row's other
+    intermediates would be. The backward makes those again from the input,
+    but not `o`: `rms_norm(o) * gate` and `@ wo` need it, and making it again
+    is the whole chunked forward, on top of the once that `kda_chunked`'s own
+    backward makes the chunks again."""
 
     def __init__(self, config: KimiLinearConfig):
         super().__init__()
@@ -150,7 +159,7 @@ class KimiDeltaAttention(_Block):
         self.wo = self._mat(inner, h)
 
     def forward(self, x):
-        from paddle_tpu.ops.pallas.kda import kda_chunked
+        from paddle_tpu.ops.pallas.kda import KDA_OUT, kda_chunked
 
         heads, hd, eps = self.heads, self.hd, self.eps
 
@@ -173,10 +182,12 @@ class KimiDeltaAttention(_Block):
             return x + o.reshape(b, t, heads * hd) @ wo
 
         def rows(x, *w):
-            # a row at a time, each kept as its input alone: at 2 x 8192 a
-            # layer's float32 decays, gates and their transposes are 256 MB
-            # apiece, and both rows' at once do not fit beside the state
-            one = self._fn(lambda xr, *w: mix(xr[None], *w)[0])
+            # a row at a time, each kept as its input and the scan's output:
+            # at 2 x 8192 a layer's float32 decays, gates and their
+            # transposes are 256 MB apiece, and both rows' at once do not fit
+            # beside the state
+            one = self._fn(lambda xr, *w: mix(xr[None], *w)[0],
+                           jax.checkpoint_policies.save_only_these_names(KDA_OUT))
             return jax.lax.map(lambda xr: one(xr, *w), x)
 
         return apply_op(rows, x, self.input_norm, self.wq, self.wk, self.wv,

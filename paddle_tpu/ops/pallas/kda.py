@@ -41,15 +41,19 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.pallas import _compat
 from paddle_tpu.ops.pallas.flash_attention import _interpret_mode
 
-__all__ = ["kda_chunked", "CHUNK", "resolve_head_block"]
+__all__ = ["kda_chunked", "CHUNK", "KDA_OUT", "resolve_head_block"]
 
 CHUNK = 64
+# the `checkpoint_name` of `kda_chunked`'s result: what a caller's
+# `save_only_these_names` policy keeps it by
+KDA_OUT = "kda_out"
 _HI = jax.lax.Precision.HIGHEST
 _EXP_CAP = 80.0
 
@@ -265,8 +269,12 @@ def kda_chunked(q, k, v, g, beta, *, interpret: bool | None = None):
     q and k arrive as the layer means them (normalised, q scaled). Returns
     [B, T, H, V] in v's type. Any T: the tail is padded with tokens that
     leave the state alone (k = 0, beta = 0, g = 0). What the chunks hold is
-    made again in the backward pass (`jax.checkpoint`): only the arguments
-    are kept."""
+    made again in the backward pass, a block of heads at a time
+    (`jax.checkpoint`): of this call only the arguments are kept. The result
+    carries the name `KDA_OUT`: a caller that recomputes its own forward
+    (models/kimi_linear.py) keeps it by that name, 2 * B * T * H * V bytes in
+    bfloat16, and so does not run the chunks a third time for it. Outside
+    such a policy the name does nothing."""
     b, t, h, kd = q.shape
     if interpret is None:
         interpret = _interpret_mode()
@@ -285,4 +293,4 @@ def kda_chunked(q, k, v, g, beta, *, interpret: bool | None = None):
     blocks = [x.reshape(b * h // hb, hb, *x.shape[1:]) for x in blocks]
     o = jax.lax.map(lambda xs: run(*xs), tuple(blocks))
     o = o.reshape(b * h, t + pad, -1)[:, :t].reshape(b, h, t, -1)
-    return jnp.moveaxis(o, 1, 2).astype(v.dtype)
+    return checkpoint_name(jnp.moveaxis(o, 1, 2).astype(v.dtype), KDA_OUT)

@@ -113,6 +113,65 @@ def test_kda_resolution_is_recorded():
     assert res.derived["grid"] == (1, 1) and res.derived["state_block"] == (4, 128, 128)
 
 
+def _kda_layer_grad(recompute, dtype):
+    """The gradient, by the input and every leaf, of one KDA layer of the tiny
+    config over 2 rows of 128 tokens; its arguments; their names."""
+    from paddle_tpu.models.kimi_linear import KimiDeltaAttention
+    from paddle_tpu.parallel import functional_call
+
+    paddle.seed(3)
+    layer = KimiDeltaAttention(kimi_linear_tiny_config(recompute=recompute))
+    rs = np.random.RandomState(3)
+    names, params = zip(*layer.named_parameters())
+    leaves = [jnp.asarray(p.numpy() + 0.05 * rs.randn(*p.shape), dtype) for p in params]
+    x = jnp.asarray(rs.randn(2, 128, 64), dtype)
+
+    def loss(x, *w):
+        out = functional_call(layer, w, (x,))._value
+        return jnp.sum(jnp.sin(out.astype(jnp.float32)))
+
+    return (jax.grad(loss, argnums=tuple(range(len(leaves) + 1))), (x, *leaves),
+            ("x", *names))
+
+
+def _pallas_calls(jaxpr, counts):
+    from jax._src import core
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            counts[eqn.params["name"]] = counts.get(eqn.params["name"], 0) + 1
+        for sub in core.jaxprs_in_params(eqn.params):
+            _pallas_calls(sub, counts)
+    return counts
+
+
+def test_kda_layer_runs_the_scan_twice_a_step_not_three_times():
+    """A row keeps the scan's output beside its input, so its backward does
+    not run `kda_chunked` forward for `o`: the chunks are made once more, in
+    `kda_chunked`'s own backward (the count the device trace shows as
+    `kda_fwd` events a step: PERF.md section 5)."""
+    with force_interpret():
+        for recompute in (True, False):
+            grad, args, _ = _kda_layer_grad(recompute, jnp.float32)
+            counts = _pallas_calls(jax.make_jaxpr(grad)(*args).jaxpr, {})
+            assert counts == {"kda_fwd": 2, "kda_bwd": 1}, (recompute, counts)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 5e-5), ("bfloat16", 1e-2)])
+def test_kda_layer_gradients_do_not_depend_on_what_is_kept(dtype, tol):
+    """`recompute=True` (a row's input and `o` kept) against `recompute=False`
+    (everything kept): the kept `o` IS the one the backward would make again,
+    so both read 0 apart here; the room is for another fusion's rounding."""
+    with force_interpret():
+        grads = []
+        for recompute in (True, False):
+            grad, args, names = _kda_layer_grad(recompute, jnp.dtype(dtype))
+            grads.append(jax.jit(grad)(*args))
+    for name, a, b in zip(names, *grads):
+        assert bool(jnp.isfinite(a.astype(jnp.float32)).all()) and float(jnp.abs(b).max()) > 0
+        _close(a.astype(jnp.float32), b.astype(jnp.float32), tol, name)
+
+
 # ---------------------------------------------------------------------------
 # latent attention: query/key width 192, value width 128
 # ---------------------------------------------------------------------------
