@@ -2,9 +2,9 @@
 
 A chip machine is handed out fresh per call, and a 7B-width train step plus
 the serving engine's decode/prefill programs take minutes to compile cold, so
-every entry point that runs on a chip (`chip_smoke.py`, `bench.py`, the
-`inference/serve.py` CLI) calls `enable_compile_cache()` once, before its
-first compile.
+every entry point that runs on a chip (`chip_smoke.py`, `benchmark/run.py`,
+the `inference/serve.py` CLI) calls `enable_compile_cache()` once, before
+its first compile.
 
 The cache is placed from OUTSIDE the program: when `JAX_COMPILATION_CACHE_DIR`
 is set JAX reads it by itself and nothing is set in code; otherwise the cache
@@ -20,14 +20,17 @@ a `jax.monitoring` listener that `enable_compile_cache()` registers (or
 for every program JAX compiled: its `fun_name`, when it started, the seconds
 spent tracing, lowering and backend-compiling (or fetching from the
 persistent cache), and whether the cache was asked, hit or missed — so a
-set-up that is slow can say WHICH program missed.
+set-up that is slow can say WHICH program missed. The process metrics
+registry reads the same log at scrape time (`compile_cache_hits_total`,
+`compile_cache_misses_total`).
 
 Stdlib + jax only, and loadable by file path: the standalone serving CLI
-runs under an import hook that forbids every `paddle_tpu.*` import.
+loads it without the `paddle_tpu` package (no registry there, no series).
 """
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 
@@ -138,10 +141,26 @@ def _on_event(event, **kw):
         p["cache"] = "miss"       # JAX's "miss": compiled AND written back
 
 
+def _collect(reg):
+    t = compile_totals()
+    reg.counter("compile_cache_hits_total",
+                "programs fetched from JAX's persistent compilation cache"
+                ).labels()._set_total(float(t["hits"]))
+    reg.counter("compile_cache_misses_total",
+                "programs compiled and written to JAX's persistent "
+                "compilation cache").labels()._set_total(float(t["misses"]))
+
+
 def start_compile_log():
     """Register the listeners, once a process. `enable_compile_cache()` does
     it; call it alone to account for compiling without a persistent cache."""
     global _listening
+    # every call: `registry().reset()` drops collectors. `import paddle_tpu`
+    # loads the registry; the standalone CLI has none and must not import
+    # the package to get one
+    metrics = sys.modules.get("paddle_tpu.observability.metrics")
+    if metrics is not None:
+        metrics.registry().ensure_collector(_collect)
     with _log_lock:
         if _listening:
             return

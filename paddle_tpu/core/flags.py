@@ -414,9 +414,3 @@ define_flag("tuning_cache_dir", "",
             "directory of the JSON block-shape tuning cache consumed by "
             "FLAGS_autotune=load|search; empty disables the cache tier "
             "of the resolver")
-define_flag("program_cache_dir", "",
-            "directory of the persistent AOT compiled-program cache: "
-            "CompiledTrainStep and the serving engine's decode/verify/"
-            "prefill programs serialize compiled executables keyed by "
-            "(HLO fingerprint, platform, flags, jax version) so a cold "
-            "process LOADS instead of recompiling; empty disables")

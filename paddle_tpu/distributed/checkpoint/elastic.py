@@ -25,7 +25,7 @@ checkpoint loadable. Keep-last-K GC runs after commit and never touches the
 newest committed snapshot. Every phase boundary honors the
 unified fault registry's ``ckpt.*`` points (`FAULT_POINTS`; the legacy
 ``FLAGS_ckpt_fault_injection`` knob still arms them), which the
-crash-consistency tests and ``bench.py checkpointing`` drive.
+crash-consistency tests drive.
 
 **Cross-mesh resume.** Snapshots store mesh-agnostic NAMES (model state-dict
 keys; optimizer slots keyed by the owning parameter's name) and
@@ -75,7 +75,7 @@ _TMP = "tmp"
 
 
 class CheckpointFaultInjected(faults.FaultInjected):
-    """Raised at an armed ckpt.* fault point — the test/bench stand-in for
+    """Raised at an armed ckpt.* fault point — the tests' stand-in for
     a kill -9 at that exact phase of the commit protocol. Armed through the
     unified registry (resilience.faults) or the legacy
     FLAGS_ckpt_fault_injection string knob."""
@@ -128,7 +128,7 @@ def _device_copy(v):
 # one jitted optimization_barrier over ALL leaves: produces bit-exact new
 # buffers (no input forwarding/aliasing without donation) in a single
 # dispatch, instead of one eager jnp.copy dispatch per leaf — the per-save
-# caller-thread cost the bench's capture_ms measures. jit caches per
+# caller-thread cost of a capture. jit caches per
 # (structure, shapes), which is stable across a training run's saves.
 _copy_jit = None
 

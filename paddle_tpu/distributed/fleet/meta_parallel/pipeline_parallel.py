@@ -16,7 +16,7 @@ TPU-native design: two execution paths with identical math:
    mesh slice, activations hop stages via collective_permute over ICI (the
    batched-isend/irecv analog), microbatches streamed with lax.scan. Used by
    train_batch when `strategy.pipeline_configs['compile']` (default on TPU) and
-   by dryrun_multichip/bench.
+   by dryrun_multichip.
 """
 from __future__ import annotations
 

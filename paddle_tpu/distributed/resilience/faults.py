@@ -4,7 +4,7 @@ subsystem.
 Reference analog: the fleet elastic layer proves its protocols by killing
 trainers at chosen moments; here every subsystem that has a crash-consistency
 or recovery story declares NAMED INJECTION POINTS and calls them on its hot
-path, so tests, the bench chaos arm, and operators drive *all* of them
+path, so tests and operators drive *all* of them
 through one registry instead of one ad-hoc flag per subsystem (the
 `FLAGS_ckpt_fault_injection` string knob PR 8 introduced is migrated onto
 this registry; its flag keeps working as a legacy arming alias).

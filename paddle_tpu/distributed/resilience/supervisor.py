@@ -27,7 +27,7 @@ through the faults a real pod throws at it:
   save-and-exit path; the supervisor then RESTARTS in-process from the
   checkpoint that path just committed (a SIGTERM preemption, by contrast,
   exits with status "preempted" — the pod is going away). The
-  `watchdog.hang` fault point simulates a hung step for tests/bench.
+  `watchdog.hang` fault point simulates a hung step for tests.
 
 Every event lands in a JSONL incident log (`IncidentLog`): anomaly /
 rollback / quarantine / feeder_retry / ckpt_save_failed / hang / halt

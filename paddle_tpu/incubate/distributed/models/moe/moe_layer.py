@@ -274,7 +274,7 @@ def _sparse_moe(xv, gv, rng, w1, b1, w2, b2, *, E, k, cf, act,
     valid = chosen & (pos >= 0) & (pos < limit)
     dropped = jnp.sum((chosen & ~valid).astype(jnp.float32))
     # per-expert PROCESSED token counts (valid selections only) — the
-    # load-balance telemetry the registry/bench surface
+    # load-balance telemetry the registry surfaces
     counts = jnp.zeros((E,), jnp.float32).at[jnp.clip(flat_e, 0, E - 1)].add(
         valid.astype(jnp.float32))
     dest = (jnp.clip(flat_e, 0, E - 1) * C

@@ -184,7 +184,7 @@ def pad_examples(examples: Iterable, seq_len: int, batch_size: int,
                  ignore_index: int = IGNORE_INDEX) -> Iterator[dict]:
     """The PADDED baseline with the same schema: one document per row,
     truncated to seq_len. Same labels/positions semantics as the packer, so
-    packed-vs-padded comparisons (bench `packing` arm, the equivalence
+    packed-vs-padded comparisons (the equivalence
     test) differ ONLY in row layout."""
     rows: list[dict] = []
 

@@ -111,7 +111,7 @@ def serve_delta(v, a_pool, b_pool, binding: ServeBinding):
     backend, bm = binding.backend, binding.block_rows
     if backend == "auto":
         # TPU: the real Pallas kernel over block_rows tiles. Elsewhere
-        # (CPU CI, the bench's interpret path): the xla backend at
+        # (CPU CI, the interpret path): the xla backend at
         # block_rows=1, where each row IS its own block — an exact
         # per-row w[gids[i]] gather for ANY slot mix, without paying the
         # interpret loop a (block, group) tile per distinct slot.

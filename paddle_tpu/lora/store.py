@@ -284,7 +284,7 @@ class AdapterStore:
         every target's pools (eager `.at[].set` — compiled scatter
         programs, the `_copy_page` idiom; the decode program itself never
         changes). Timed + journaled: this is the latency a cold tenant
-        pays once, and the hot-swap latency the bench reports."""
+        pays once, and the hot-swap latency `swap_ms_mean` reports."""
         t0 = time.perf_counter()
         rows = self._host[adapter_id]
         for i, (a, b) in enumerate(rows):

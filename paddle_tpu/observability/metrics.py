@@ -14,8 +14,8 @@ Prometheus. This registry is the ONE place every component reports through
     callback that maps them into gauges/counters AT SCRAPE TIME, so the
     hot path pays nothing. Collectors are owner-weakref'd: a dead engine's
     collector unregisters itself;
-  * **snapshot()** — plain nested dicts for programmatic gates
-    (bench_regression reads this);
+  * **snapshot()** — plain nested dicts for programmatic gates (the
+    tier-1 tests read this);
   * **prometheus_text()** — text exposition format 0.0.4, served as
     ``GET /metrics`` by the serve.py chassis;
   * **export_jsonl()** — stream the snapshot into a
@@ -125,7 +125,7 @@ class _CounterChild:
     def _set_total(self, v: float):
         """Mirror a monotonic source (e.g. Router.completed) at scrape
         time — collector-only API. A LOWER value is accepted as a source
-        reset (engine.reset_stats() between bench arms): standard
+        reset (engine.reset_stats() between runs): standard
         Prometheus counter-reset semantics, which rate() handles."""
         with self._lock:
             self._value = float(v)
@@ -231,7 +231,7 @@ class _HistogramChild:
 
     def quantile(self, q: float) -> float:
         """Linear-interpolated quantile estimate from the buckets (the
-        p99 the bench gates read — honest to bucket resolution)."""
+        p99 readers get — honest to bucket resolution)."""
         cum = self.cumulative()
         if not self.count:
             return 0.0
@@ -317,6 +317,13 @@ class MetricsRegistry:
         ref = weakref.ref(owner) if owner is not None else None
         with self._lock:
             self._collectors.append((fn, ref))
+
+    def ensure_collector(self, fn):
+        """`add_collector(fn)` unless `fn` is registered already — for
+        process-lifetime collectors, which re-register after a `reset()`."""
+        with self._lock:
+            if not any(f is fn for f, _ in self._collectors):
+                self._collectors.append((fn, None))
 
     def run_collectors(self):
         with self._lock:

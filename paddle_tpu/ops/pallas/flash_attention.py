@@ -517,7 +517,7 @@ def _flash_bwd(q, k, v, seg, out, lse, do, causal: bool, scale: float,
 
 
 # ---------------------------------------------------------------------------
-# block-visit counter (the bench/test proof of the sparsity claim)
+# block-visit counter (the tests' proof of the sparsity claim)
 # ---------------------------------------------------------------------------
 
 def _visit_kernel(seg_ref, cnt_ref, *, block_q: int, block_k: int,
@@ -550,7 +550,7 @@ def segment_block_visit_counts(segment_ids, block_q: int | None = None,
     computed by running the forward kernel's exact skip predicate
     (`_seg_blocks_can_touch` + the causal diagonal bound) as its own Pallas
     kernel. Returns int32 [B, q_blocks]; sum()/total_blocks is the visited
-    fraction the bench `packing` arm reports (~sum len_i^2 / S^2 under
+    fraction a packed batch visits (~sum len_i^2 / S^2 under
     packing vs ~1/2 causal dense)."""
     seg = jnp.asarray(segment_ids, jnp.int32)
     b, s = seg.shape

@@ -26,7 +26,7 @@ more segment vocabulary):
     another), zeroed at the first; groups with no row block are zeroed
     outside.
   * `grouped_matmul_visit_counts` runs the range predicate as its own
-    kernel so the bench counter counts what the compute kernels execute
+    kernel so the visit counter counts what the compute kernels execute
     (mirrors `segment_block_visit_counts`).
 
 Accumulation is fp32 (the returned array is fp32; callers cast), so bf16
@@ -377,7 +377,7 @@ def grouped_matmul(x, w, gids, *, block_rows: int | None = None,
 
 
 # ---------------------------------------------------------------------------
-# visit-count kernel (the bench counter)
+# visit-count kernel (the tests' counter)
 # ---------------------------------------------------------------------------
 
 def _visit_kernel(gid_ref, o_ref, *, num_groups: int):
@@ -395,7 +395,7 @@ def grouped_matmul_visit_counts(gids, num_groups: int, block_rows: int,
     computed by running the forward kernel's exact `_seg_blocks_can_touch`
     predicate as its own Pallas kernel (mirror of
     `segment_block_visit_counts`). int32 [M // block_rows];
-    sum()/ (blocks * G) is the visited fraction the MOE bench arm reports.
+    sum()/ (blocks * G) is the visited fraction of the tiles.
     Padding rows (`gids == num_groups`) never match any group."""
     gids = jnp.asarray(gids, jnp.int32)
     (m,) = gids.shape
@@ -417,7 +417,7 @@ def grouped_matmul_visit_counts(gids, num_groups: int, block_rows: int,
 
 def expected_visit_counts(gids, num_groups: int, block_rows: int):
     """The same predicate evaluated in plain numpy — the cross-check the
-    bench asserts against the kernel counter."""
+    tests assert against the kernel counter."""
     g = np.asarray(gids, np.int32).reshape(-1, block_rows)
     gmin = g.min(axis=1)[:, None]
     gmax = g.max(axis=1)[:, None]

@@ -28,7 +28,7 @@ Ragged cost: a page contributes only when the query's valid key range
 ``_seg_blocks_can_touch(0, len-1, p*ps, p*ps+ps-1)``, THE predicate the
 flash kernels share — so decode compute is O(sum_b ceil(len_b / ps))
 pages, not O(batch * pages_per_seq). `page_visit_counts` runs that same
-predicate as a standalone kernel = the bench utilization counter.
+predicate as a standalone kernel = the utilization counter.
 
 On a CPU backend the public entry point routes to a jnp gather reference
 (`paged_attention_reference`, identical math) the way
@@ -358,7 +358,7 @@ def paged_attention(q, k_pages, v_pages, page_table, context_lens,
 
 
 # ---------------------------------------------------------------------------
-# page-visit counter (the bench/test proof of the O(sum active tokens) claim)
+# page-visit counter (the tests' proof of the O(sum active tokens) claim)
 # ---------------------------------------------------------------------------
 
 def _visit_kernel(lens_ref, cnt_ref, *, page_size: int, pages_per_seq: int):
@@ -381,7 +381,7 @@ def page_visit_counts(context_lens, page_size: int, pages_per_seq: int,
     from the exact predicate it runs (`_seg_blocks_can_touch` over the page
     position range). int32 [B]; sum()/(B*pages_per_seq) is the visited
     fraction, == sum(ceil(len_b/ps)) / (B*pages_per_seq) — the serving
-    bench's ragged-cost counter."""
+    tests' ragged-cost counter."""
     lens = jnp.asarray(context_lens, jnp.int32).reshape(1, -1)
     b = lens.shape[1]
     if interpret is None:

@@ -131,7 +131,7 @@ class ScanShardInfo:
     being computed (mp-only sharding). mode: ``"ahead"`` = double-buffered
     gather of layer k+1 while layer k computes (at most 2 layers of full
     weights live); ``"start"`` = all-gather the whole stack up front (the
-    overlap-free baseline the bench compares against).
+    overlap-free baseline).
     """
 
     __slots__ = ("mesh", "cols", "mode", "axis", "act_spec")
@@ -337,7 +337,7 @@ def _zero3_scan(template, stacked_vals, x, args, kwargs,
     reduce-scatter instead of an all-reduce, and the optimizer update runs
     on the shard.
 
-    mode "start" (the bench baseline) shares this exact vjp structure —
+    mode "start" (the baseline) shares this exact vjp structure —
     identical residuals, identical per-layer dW scatter — but gathers the
     WHOLE stack before each loop instead of one layer ahead, so the
     measured difference between the modes is purely the gather schedule."""

@@ -366,8 +366,7 @@ class CompiledTrainStep:
       buffered gather-ahead — layer k+1's weights all-gather while layer k
       computes and backward re-gathers + reduce-scatters grads, so at most
       2 layers of full weights are ever live; 'start' = all-gather the whole
-      stack before the loop (the overlap-free baseline bench.py compares
-      against).
+      stack before the loop (the overlap-free baseline).
     offload_optimizer: place optimizer state in pinned host memory
       (reference sharding offload variants); requires backend host-memory
       support (TPU), silently stays in HBM otherwise.
@@ -754,8 +753,6 @@ class CompiledTrainStep:
                 self._state_shardings.append(st_sh)
 
         self._jitted = None
-        self._dispatch = None
-        self._program_cache_status: dict = {}
         self._donate = donate
 
     def _resume_states(self, optimizer):
@@ -1071,23 +1068,6 @@ class CompiledTrainStep:
             donate = (((0, 1, 6) if extended else (0, 1))
                       if self._donate else ())
             self._jitted = jax.jit(self._step_fn, donate_argnums=donate)
-        # persistent AOT program cache (FLAGS_program_cache_dir): the first
-        # real dispatch lowers and LOADS yesterday's executable instead of
-        # recompiling — the cold-trainer time-to-first-step path of
-        # docs/autotuning.md. Off (the default) this is self._jitted.
-        from paddle_tpu.tuning.program_cache import AotProgram, process_cache
-
-        if process_cache() is not None:
-            self._dispatch = AotProgram(self._jitted, "train_step",
-                                        self._program_cache_status)
-        else:
-            self._dispatch = self._jitted
-
-    @property
-    def program_cache(self) -> dict:
-        """{'status': hit|miss, 'ms': ...} of this step's AOT program-cache
-        resolution; {} when the cache is off or nothing dispatched yet."""
-        return dict(self._program_cache_status.get("train_step", {}))
 
     # -- public --------------------------------------------------------------
     def __call__(self, *batch):
@@ -1188,9 +1168,9 @@ class CompiledTrainStep:
                 _abstractify, args)
         if building:
             with obs_tracing.span("train.build", part="compile"):
-                outs = self._dispatch(*args)
+                outs = self._jitted(*args)
         else:
-            outs = self._dispatch(*args)
+            outs = self._jitted(*args)
         step_metrics = None
         if self._telemetry:
             step_metrics = outs[-1]
@@ -1332,11 +1312,7 @@ class CompiledTrainStep:
             raise RuntimeError(
                 "cost_analysis() needs at least one executed step (the "
                 "abstract argument signature is captured at first dispatch)")
-        # an AOT-cached dispatch already holds the compiled step — reuse it
-        # instead of lowering/compiling a second executable
-        compiled = getattr(self._dispatch, "_compiled", None)
-        if compiled is None:
-            compiled = self._jitted.lower(*self._abstract_args).compile()
+        compiled = self._jitted.lower(*self._abstract_args).compile()
         ca = compiled.cost_analysis()
         if isinstance(ca, (list, tuple)):
             ca = ca[0] if ca else {}

@@ -13,7 +13,7 @@ repeating the last token (cheap, and right for degenerate loops).
 
 Cost per committed token is O(max_order) dict updates; per step,
 O(K * max_order) lookups — microseconds against a decode dispatch, and
-measured anyway (`draft_ms`) so the bench can report draft overhead
+measured anyway (`draft_ms`) so a run can report draft overhead
 honestly.
 """
 from __future__ import annotations

@@ -414,12 +414,6 @@ class ServingEngine:
         self._prefill_traces = 0
         self._decode_traces_at_warmup: int | None = None
         self._donate = (jax.devices()[0].platform == "tpu")
-        from collections import deque
-        # AOT program cache (FLAGS_program_cache_dir): per-program
-        # {tag: {"status": hit|miss, "ms"}} — /stats surfaces it and
-        # mark_warmup snapshots it as the replica's time-to-ready record
-        self._program_cache_status: dict = {}
-        self._program_cache_at_warmup: dict | None = None
         self._decode_fn = None
         self._verify_fns: dict[int, object] = {}    # draft window K -> fn
         self._copy_fn = None
@@ -456,7 +450,7 @@ class ServingEngine:
         self._handoff_ms_total = 0.0
         self._handoff_ms_last = 0.0
         # speculation / prefix-sharing accounting (stats() surfaces these;
-        # the bench's accepted-tokens/step and prefix-hit-rate gates read
+        # accepted-tokens/step and prefix-hit-rate read
         # them): committed counts REAL tokens delivered to requests, steps
         # counts decode/verify dispatches, draft_ms the host proposer time
         self._committed_tokens = 0
@@ -468,9 +462,6 @@ class ServingEngine:
         self._draft_ms = 0.0
         self._prefix_admit_tokens = 0
         self._prefix_matched_tokens = 0
-        # bounded: a long-lived server must not grow a sample per decode
-        # step forever (utilization_mean is a recent-window statistic)
-        self._util_samples: deque = deque(maxlen=65536)
         import threading
         self._http_lock = threading.Lock()
         # serializes device work between this engine's driver and any
@@ -492,7 +483,7 @@ class ServingEngine:
     def _ctx_cap(self) -> int:
         return self.pages_per_seq * self.page_size
 
-    # read-only views of the page pools (tests/bench peek at page bytes;
+    # read-only views of the page pools (tests peek at page bytes;
     # the MUTABLE handle is the single donated `_cache` pytree)
     @property
     def _ck(self):
@@ -543,18 +534,6 @@ class ServingEngine:
                 rows[i] = self.adapters.slot_of(req.adapter)
         return rows
 
-    def _maybe_aot(self, jitted, tag: str):
-        """Route a compiled serving program through the persistent AOT
-        cache when FLAGS_program_cache_dir is set: a cold replica LOADS
-        the serialized decode/verify/prefill executables instead of
-        recompiling them — the seconds-fast scale-up path of ROADMAP
-        item 4. The plain jitted callable when the cache is off."""
-        from paddle_tpu.tuning.program_cache import AotProgram, process_cache
-
-        if process_cache() is None:
-            return jitted
-        return AotProgram(jitted, tag, self._program_cache_status)
-
     def _decode(self):
         if self._decode_fn is None:
             from paddle_tpu.parallel.train_step import functional_call
@@ -578,9 +557,9 @@ class ServingEngine:
                 # stay live between steps for nothing
                 return tokens, new_keys, cache
 
-            self._decode_fn = self._maybe_aot(jax.jit(
+            self._decode_fn = jax.jit(
                 _named(fn, "engine_decode"),
-                donate_argnums=(1,) if self._donate else ()), "decode")
+                donate_argnums=(1,) if self._donate else ())
         return self._decode_fn
 
     def _prefill(self, chunk_pad: int, ctx_pad: int):
@@ -609,10 +588,9 @@ class ServingEngine:
                         training=False, method="decode_forward")
                 return cache
 
-            self._prefill_fns[key] = self._maybe_aot(
-                jax.jit(_named(fn, f"engine_prefill_{chunk_pad}x{ctx_pad}"),
-                        donate_argnums=(1,) if self._donate else ()),
-                f"prefill:{chunk_pad}x{ctx_pad}")
+            self._prefill_fns[key] = jax.jit(
+                _named(fn, f"engine_prefill_{chunk_pad}x{ctx_pad}"),
+                donate_argnums=(1,) if self._donate else ())
         return self._prefill_fns[key]
 
     def _prefill_packed(self, frame: int):
@@ -640,10 +618,9 @@ class ServingEngine:
                         training=False, method="decode_forward")
                 return cache
 
-            self._prefill_packed_fns[frame] = self._maybe_aot(
-                jax.jit(_named(fn, f"engine_prefill_packed_{frame}"),
-                        donate_argnums=(1,) if self._donate else ()),
-                f"prefill_packed:{frame}")
+            self._prefill_packed_fns[frame] = jax.jit(
+                _named(fn, f"engine_prefill_packed_{frame}"),
+                donate_argnums=(1,) if self._donate else ())
         return self._prefill_packed_fns[frame]
 
     def _plan_frames(self, seq, length_of):
@@ -783,10 +760,9 @@ class ServingEngine:
                     keyc, accepted[:, None, None], axis=1)[:, 0]
                 return tokens, accepted, new_keys, cache
 
-            self._verify_fns[k] = self._maybe_aot(
-                jax.jit(_named(fn, f"engine_verify_{k}"),
-                        donate_argnums=(1,) if self._donate else ()),
-                f"verify:{k}")
+            self._verify_fns[k] = jax.jit(
+                _named(fn, f"engine_verify_{k}"),
+                donate_argnums=(1,) if self._donate else ())
         return self._verify_fns[k]
 
     def _copy_page(self):
@@ -1027,11 +1003,9 @@ class ServingEngine:
             toks = np.asarray(tokens)
             nkeys = np.asarray(new_keys)
         with span("engine.decode.apply", component="engine"):
-            now = time.perf_counter()
             for i, req in enumerate(active):
                 tok = int(toks[i])
                 req.generated.append(tok)
-                req.token_times.append(now)
                 self._keys[req.rid] = nkeys[i]
                 self._bill_tenant(req)
                 if req.stream_cb is not None:
@@ -1042,7 +1016,6 @@ class ServingEngine:
             self._committed_tokens += len(active)
             self._slot_steps += len(active)
             self._decode_steps += 1
-            self._util_samples.append(self.allocator.utilization())
 
     def _verify_once(self, active, finisher):
         """Pack `active` requests into the fixed [batch, K+1] verify
@@ -1098,7 +1071,6 @@ class ServingEngine:
         toks = np.asarray(tokens)
         acc = np.asarray(accepted)
         nkeys = np.asarray(new_keys)
-        now = time.perf_counter()
         for i, req in enumerate(active):
             # the verified chain: accepted drafts + the first divergent
             # (or bonus) sample — each token is exactly what plain decode
@@ -1108,7 +1080,6 @@ class ServingEngine:
             for tok in toks[i, :int(acc[i]) + 1]:
                 tok = int(tok)
                 req.generated.append(tok)
-                req.token_times.append(now)
                 self._committed_tokens += 1
                 self._bill_tenant(req)
                 if self.spec_k > 0:
@@ -1121,7 +1092,6 @@ class ServingEngine:
                     break
         self._slot_steps += len(active)
         self._decode_steps += 1
-        self._util_samples.append(self.allocator.utilization())
 
     def _apply_cow(self):
         """Apply the scheduler's pending copy-on-write page copies
@@ -1492,7 +1462,7 @@ class ServingEngine:
         return outs
 
     # ------------------------------------------------------------------
-    # static-batch baseline (the bench strawman)
+    # static-batch baseline (the strawman the tests compare with)
     # ------------------------------------------------------------------
     def static_batch_generate(self, prompts, max_new_tokens, **kw):
         """Naive static batching: groups of `decode_batch` requests run to
@@ -1711,12 +1681,8 @@ class ServingEngine:
     # ------------------------------------------------------------------
     def mark_warmup(self):
         """Call after the first real decode step: any trace past this point
-        is a retrace bug (`decode_retraces_after_warmup`). Also snapshots
-        the AOT program-cache outcomes (which programs loaded vs compiled
-        on the way to ready) — the replica's time-to-ready record."""
+        is a retrace bug (`decode_retraces_after_warmup`)."""
         self._decode_traces_at_warmup = self._decode_traces
-        self._program_cache_at_warmup = {
-            tag: dict(st) for tag, st in self._program_cache_status.items()}
 
     @property
     def decode_retraces_after_warmup(self) -> int:
@@ -1787,10 +1753,6 @@ class ServingEngine:
             "handoff_pages": self._handoff_pages,
             "handoff_ms": round(self._handoff_ms_last, 3),
             "handoff_ms_total": round(self._handoff_ms_total, 3),
-            # PR-20 AOT program cache: per-program hit/miss + resolution ms
-            # (what a scaled-up replica's operator checks to confirm the
-            # cold start LOADED instead of compiling)
-            "program_cache": self.program_cache_stats(),
             # what submit() waited for the step lock: time to first token
             # spent before the scheduler has the request
             "submit_lock_wait_ms_total": round(self._submit_wait_s * 1e3, 3),
@@ -1800,17 +1762,6 @@ class ServingEngine:
             # every engine's programs are named jit(engine_...)); all zero
             # until core.compile_cache.start_compile_log()
             "compile": compile_totals("jit(engine_"),
-        }
-
-    def program_cache_stats(self) -> dict:
-        from paddle_tpu.core.flags import flag
-
-        return {
-            "enabled": bool(str(flag("program_cache_dir"))),
-            "dir": str(flag("program_cache_dir")),
-            "programs": {tag: dict(st)
-                         for tag, st in self._program_cache_status.items()},
-            "at_warmup": self._program_cache_at_warmup,
         }
 
     @property
@@ -1841,11 +1792,7 @@ class ServingEngine:
     def draft_ms_total(self) -> float:
         return self._draft_ms
 
-    def utilization_mean(self) -> float:
-        return float(np.mean(self._util_samples)) if self._util_samples else 0.0
-
     def reset_stats(self):
-        self._util_samples.clear()
         self._committed_tokens = 0
         self._decode_steps = 0
         self._slot_steps = 0
@@ -1869,25 +1816,3 @@ class ServingEngine:
         self.allocator.cold_hits = 0
         self.allocator.dropped_cold = 0
         self.allocator.promote_failures = 0
-
-    @staticmethod
-    def latency_stats(requests) -> dict:
-        """Per-token latency over finished requests: a request's first
-        token is timed from ARRIVAL (queueing + prefill + decode — what a
-        caller feels), later tokens from the previous token."""
-        gaps = []
-        for req in requests:
-            prev = req.arrival_t
-            for t in req.token_times:
-                gaps.append((t - prev) * 1e3)
-                prev = t
-        if not gaps:
-            return {"tokens": 0}
-        gaps.sort()
-
-        def pct(p):
-            return round(gaps[min(int(len(gaps) * p / 100),
-                                  len(gaps) - 1)], 3)
-
-        return {"tokens": len(gaps), "p50_ms": pct(50), "p99_ms": pct(99),
-                "mean_ms": round(float(np.mean(gaps)), 3)}

@@ -72,7 +72,6 @@ class Request:
     generated: list = field(default_factory=list)
     arrival_t: float = field(default_factory=time.perf_counter)
     admitted_t: float = 0.0
-    token_times: list = field(default_factory=list)
     evictions: int = 0
     # tokens of req.context covered by prefix-shared pages adopted at the
     # LAST admission: the engine's prefill starts here (0 = no match);

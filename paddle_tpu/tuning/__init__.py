@@ -1,6 +1,4 @@
-"""paddle_tpu.tuning — block-size autotuning + persistent program cache.
-
-Two caches, one precedence story (docs/autotuning.md):
+"""paddle_tpu.tuning — block-size autotuning.
 
 * `blocks.resolve_blocks` — the ONE resolution helper every Pallas
   kernel's block shapes go through: explicit FLAGS override > tuning-cache
@@ -8,18 +6,17 @@ Two caches, one precedence story (docs/autotuning.md):
 * `autotune` — searches the legal block lattice by timing real kernel
   invocations; winners persist in the JSON tuning cache
   (FLAGS_tuning_cache_dir, FLAGS_autotune=load|search).
-* `program_cache` — serialized AOT executables keyed by (HLO fingerprint,
-  platform, flags, jax version) under FLAGS_program_cache_dir; the tuned
-  block shapes are part of the lowered HLO, so the tuning cache FEEDS the
-  program cache key — re-tuning invalidates exactly the programs whose
-  blocks changed.
 
-Observability: `compile_cache_hits_total`/`compile_cache_misses_total`,
-`autotune_trials_total`, `block_resolutions_total{provenance=}` and the
-`program_load_ms` gauge are mirrored into the process metrics registry by
-a scrape-time collector (registered lazily and re-registered after a
-test-isolation `registry().reset()`); journal events ride component
-"tuning" (`autotune`, `program_load`, `cache_reject`, `program_corrupt`).
+The tuned block shapes are part of the lowered HLO, so JAX's persistent
+compilation cache (`core/compile_cache.py`, the one compile cache) keys on
+them by itself: re-tuning recompiles exactly the programs whose blocks
+changed.
+
+Observability: `autotune_trials_total`, `tuning_cache_rejects_total` and
+`block_resolutions_total{provenance=}` are mirrored into the process
+metrics registry by a scrape-time collector (registered lazily and
+re-registered after a test-isolation `registry().reset()`); journal events
+ride component "tuning" (`autotune`, `autotune_skip`, `cache_reject`).
 """
 from __future__ import annotations
 
@@ -27,33 +24,14 @@ from paddle_tpu.tuning.blocks import (KERNELS, Resolution, TuningCache,
                                       TUNING_SCHEMA, cache_key,
                                       last_resolution, resolve_blocks,
                                       trial_blocks, tuning_counters)
-from paddle_tpu.tuning.program_cache import (PROGRAM_SCHEMA, AotProgram,
-                                             ProgramCache, process_cache,
-                                             program_counters)
 
 __all__ = ["KERNELS", "Resolution", "TuningCache", "TUNING_SCHEMA",
            "cache_key", "last_resolution", "resolve_blocks", "trial_blocks",
-           "tuning_counters", "PROGRAM_SCHEMA", "AotProgram", "ProgramCache",
-           "process_cache", "program_counters", "ensure_metrics_collector"]
+           "tuning_counters", "ensure_metrics_collector"]
 
 
 def _collect(reg):
-    from paddle_tpu.tuning.blocks import tuning_counters as tc
-    from paddle_tpu.tuning.program_cache import program_counters as pc
-
-    t, p = tc(), pc()
-    reg.counter("compile_cache_hits_total",
-                "AOT program-cache loads that skipped a compile"
-                ).labels()._set_total(float(p["hits"]))
-    reg.counter("compile_cache_misses_total",
-                "AOT program-cache misses (compiled fresh, then stored)"
-                ).labels()._set_total(float(p["misses"]))
-    reg.counter("compile_cache_corrupt_total",
-                "unusable program-cache entries (fell back to compile)"
-                ).labels()._set_total(float(p["corrupt"]))
-    reg.gauge("program_load_ms",
-              "last AOT program-cache resolution time: deserialize ms on "
-              "a hit, compile ms on a miss").set(float(p["last_load_ms"]))
+    t = tuning_counters()
     reg.counter("autotune_trials_total",
                 "block-lattice candidates timed by the autotuner"
                 ).labels()._set_total(float(t["autotune_trials"]))
@@ -76,8 +54,4 @@ def ensure_metrics_collector():
     and counter bumps are never on a per-step hot path."""
     from paddle_tpu.observability import metrics as obs
 
-    reg = obs.registry()
-    with reg._lock:
-        if any(fn is _collect for fn, _ in reg._collectors):
-            return
-    reg.add_collector(_collect)
+    obs.registry().ensure_collector(_collect)

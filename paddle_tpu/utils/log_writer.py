@@ -22,7 +22,7 @@ import numpy as np
 __all__ = ["LogWriter", "LogReader", "VisualDLCallback"]
 
 # durability: every live writer flushes at interpreter exit, so a
-# short-lived run (a bench arm, a crashed script) never drops the tail of
+# short-lived run (a short script, a crashed one) never drops the tail of
 # its buffered JSONL events. Weak set — registration must not keep
 # writers (and their open files) alive.
 _LIVE_WRITERS: "weakref.WeakSet[LogWriter]" = weakref.WeakSet()

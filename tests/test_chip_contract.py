@@ -270,7 +270,7 @@ def test_compile_cache_helper(monkeypatch, tmp_path):
 def test_one_place_sets_the_compile_cache_dir():
     name = "jax_compilation_" + "cache_dir"
     hits = []
-    files = [os.path.join(REPO, f) for f in ("bench.py", "chip_smoke.py")]
+    files = [os.path.join(REPO, "chip_smoke.py")]
     for root, _, names in os.walk(os.path.join(REPO, "paddle_tpu")):
         files += [os.path.join(root, n) for n in names if n.endswith(".py")]
     for path in files:
@@ -421,5 +421,7 @@ def test_no_tracked_file_mentions_the_old_remote_tpu_plugin():
             continue
         hits += [(os.path.relpath(path, REPO), w) for w in words if w in text]
     assert not hits, hits
-    for gone in ("BENCH_r01.json", "BENCH_r05.json", "MULTICHIP_r01.json"):
+    for gone in ("BENCH_r01.json", "BENCH_r05.json", "MULTICHIP_r01.json",
+                 "bench.py", "tools/bench_regression.py",
+                 "BENCH_BASELINE.json", "BENCH_FULL_r03.json"):
         assert not os.path.exists(os.path.join(REPO, gone))

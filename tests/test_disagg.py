@@ -103,10 +103,28 @@ def _chain_pages(eng, rid, n_tokens):
     return out
 
 
-def _packed_vs_sequential(m, cfg, lens, n_new, pack_frame=64):
+def _bf16_ulps_apart(a, b):
+    """Elementwise distance of two bfloat16 arrays in representable values
+    (sign-magnitude bit patterns mapped onto one ordered integer line)."""
+    def ordinal(x):
+        u = np.ascontiguousarray(x).view(np.uint16).astype(np.int32)
+        return np.where(u & 0x8000, -(u & 0x7FFF), u)
+    return np.abs(ordinal(a) - ordinal(b))
+
+
+def _packed_vs_sequential(m, cfg, lens, n_new, pack_frame=64,
+                          bf16_last_ulp=False):
     """Submit the same prompts to a sequential-prefill engine and a
     packed-prefill engine, compare page contents after the first step and
-    the full greedy streams after completion. Returns the pack engine."""
+    the full greedy streams after completion. Returns the pack engine.
+
+    Pages are compared bit for bit. `bf16_last_ulp` is for bfloat16 pools
+    only: the [1, 64] packed frame and the [1, 32] sequential frames are
+    two different XLA programs, and XLA:CPU may round a float32
+    intermediate to bfloat16 at another point of the fused projection, so
+    a value can land on the neighbouring bfloat16. There the pools must
+    agree to ONE bfloat16 ulp with at most 0.1% of a pool's elements off;
+    the greedy streams stay exactly equal either way."""
     rng = np.random.RandomState(11)
     prompts = _prompts(rng, cfg, lens)
     seq = _engine(m, prefill_pack=False)
@@ -120,8 +138,16 @@ def _packed_vs_sequential(m, cfg, lens, n_new, pack_frame=64):
     for rs, rp, n in zip(rids[seq], rids[pack], lens):
         sp, pp = _chain_pages(seq, rs, n), _chain_pages(pack, rp, n)
         for name in sp:
-            assert np.array_equal(sp[name], pp[name]), \
-                f"packed prefill diverged from sequential in pool {name!r}"
+            if bf16_last_ulp:
+                apart = _bf16_ulps_apart(sp[name], pp[name])
+                assert apart.max() <= 1 and (apart > 0).mean() <= 1e-3, (
+                    f"packed prefill diverged from sequential in pool "
+                    f"{name!r}: {int((apart > 0).sum())} of {apart.size} "
+                    f"elements, up to {int(apart.max())} bfloat16 ulps")
+            else:
+                assert np.array_equal(sp[name], pp[name]), (
+                    f"packed prefill diverged from sequential in pool "
+                    f"{name!r}")
     outs = {}
     for eng in (seq, pack):
         eng.run_until_idle()
@@ -154,7 +180,8 @@ class TestPackedPrefillParity:
         try:
             m, cfg = _model(num_key_value_heads=2)
             m.to(dtype="bfloat16")
-            _packed_vs_sequential(m, cfg, (18, 29), n_new=2)
+            _packed_vs_sequential(m, cfg, (18, 29), n_new=2,
+                                  bf16_last_ulp=True)
         finally:
             set_flags({"flash_block_q": 0, "flash_block_k": 0})
 

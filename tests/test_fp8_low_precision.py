@@ -169,7 +169,7 @@ class TestCompiledStepFp8:
 
     def test_loss_parity_vs_bf16(self):
         """Short-horizon parity: the fp8 arm must track the bf16 trajectory
-        (the bench arm runs the >=100-step gate; this is the quick guard)."""
+        (20 steps: the quick guard)."""
         ids = None
         finals = {}
         for pol in ("none", "matmuls"):

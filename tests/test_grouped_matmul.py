@@ -151,7 +151,7 @@ class TestVisitCounts:
                                                     interpret=True))
         blk = gids.reshape(-1, bm)[:, 0]
         np.testing.assert_array_equal(vc, (blk < G).astype(np.int32))
-        # the sparsity the bench reports: visited / (blocks * G)
+        # the sparsity the layer sees: visited / (blocks * G)
         assert vc.sum() == (blk < G).sum() < vc.size * G
 
     def test_pick_block_rows(self):

@@ -19,7 +19,7 @@ from paddle_tpu.ops.pallas.paged_attention import (force_interpret,
                                                    paged_decode_attention)
 from paddle_tpu.quantization import AbsmaxChannelWiseObserver, absmax_scale
 from paddle_tpu.serving import (PageAllocator, ServingConfig, ServingEngine,
-                                kv_page_bytes)
+                                kv_page_bytes, pages_for_budget)
 
 
 def _model(**over):
@@ -334,6 +334,54 @@ class TestEngineHierarchy:
         total = sum(len(s) for s in out_ref)
         assert match / total >= 0.99
 
+    def test_int8_pages_per_byte_budget_at_7b_geometry(self):
+        """A count, not a speed: at the 7B serving geometry (32 layers x
+        32 KV heads x 128, pages of 16) int8 codes WITH their float32
+        per-slot scales admit 1.94x the pages of bfloat16 at 4 GiB."""
+        L, H, ps, D = 32, 32, 16, 128
+        pb_bf16 = kv_page_bytes(L, H, ps, D, 2)
+        pb_int8 = kv_page_bytes(L, H, ps, D, 1) + 2 * L * H * ps * 4
+        ratio = (pages_for_budget(4 << 30, pb_int8)
+                 / pages_for_budget(4 << 30, pb_bf16))
+        assert 1.9 <= ratio < 2.0, ratio
+        assert round(ratio, 2) == 1.94
+
+    def test_one_byte_budget_int8_serves_the_burst_model_dtype_evicts(
+            self, shared):
+        """The capacity realised, structurally: at ONE byte budget a full
+        decode batch exceeds the model-dtype pool, which evicts and
+        re-prefills, while the int8 pool (codes + scales) holds the same
+        burst with ZERO evictions; both finish every stream."""
+        m, cfg = shared
+        L, H = cfg.num_hidden_layers, cfg.num_key_value_heads
+        D = cfg.hidden_size // cfg.num_attention_heads
+        ps = 4
+        pb_model = kv_page_bytes(L, H, ps, D, 4)     # float32 on the CPU
+        pb_int8 = kv_page_bytes(L, H, ps, D, 1) + 2 * L * H * ps * 4
+        budget = 12 * pb_model
+        pages = {"model": pages_for_budget(budget, pb_model),
+                 "int8": pages_for_budget(budget, pb_int8)}
+        # 4 slots x (10 prompt + 12 new) tokens = 24 pages, and the null page;
+        # the model-dtype pool admits three prompts and cannot grow them
+        assert pages["model"] == 12 < 25 <= pages["int8"]
+        rng = np.random.RandomState(5)
+        prompts = [rng.randint(1, cfg.vocab_size, 10).astype(np.int32)
+                   for _ in range(6)]
+        evictions = {}
+        for mode in ("model", "int8"):
+            eng = ServingEngine(m, ServingConfig(
+                page_size=ps, num_pages=pages[mode], decode_batch=4,
+                prefill_chunk=8, max_seq_len=32, kv_cache_dtype=mode))
+            rids = [eng.submit(p, max_new_tokens=12) for p in prompts]
+            eng.run_until_idle()
+            reqs = [eng.scheduler.get(r) for r in rids]
+            assert all(len(r.generated) == 12 for r in reqs)
+            evictions[mode] = sum(r.evictions for r in reqs)
+            for r in rids:
+                eng.release(r)
+            eng.allocator.check_consistency()
+        assert evictions["int8"] == 0 < evictions["model"], evictions
+
     def test_tier_roundtrip_stream_equality_zero_retraces(self, shared):
         """Chaos-shaped acceptance: fill the pool so a finished request's
         committed pages demote to host, then re-admit the same prompt —
@@ -442,6 +490,62 @@ class TestPrefixAffinityPlacement:
             assert r.stats()["placement_mode"] == "prefix"
         finally:
             r.close()
+
+    def test_fleet_prefix_hit_prefix_placement_beats_session(self, shared):
+        """Three real engines behind the router, 4 groups of 3 requests
+        sharing a 48-token prefix (12 full pages) with distinct tails, each
+        group's bare prefix served once first: prefix-affinity placement
+        sends a group to the replica that holds its pages (fleet prefix
+        hit >= 0.9 of admitted context tokens), session placement scatters
+        it (lower by more than 0.1). Counts of tokens, no clock."""
+        from paddle_tpu.serving import InProcessReplica
+        from paddle_tpu.serving.router import Router, RouterConfig
+
+        m, cfg = shared
+        fp, groups, per = 48, 4, 3
+        rng = np.random.RandomState(23)
+        prefixes = [rng.randint(1, cfg.vocab_size, fp) for _ in range(groups)]
+        tails = [[rng.randint(1, cfg.vocab_size, int(rng.randint(2, 5)))
+                  for _ in range(per)] for _ in range(groups)]
+
+        def fleet_hit(placement):
+            # host tier on: a finished request's prefix pages stay
+            # radix-indexed, so LATER same-prefix requests can hit
+            engines = [ServingEngine(m, ServingConfig(
+                page_size=4, num_pages=96, decode_batch=4, prefill_chunk=16,
+                max_seq_len=64, prefix_sharing=True, host_cache_mb=8))
+                for _ in range(3)]
+            reps = [InProcessReplica(e, replica_id=k)
+                    for k, e in enumerate(engines)]
+            router = Router(reps, RouterConfig(
+                placement=placement, prefix_tokens=fp,
+                probe_interval_s=0.05))
+            try:
+                for g in range(groups):
+                    _, term = router.generate(
+                        {"prompt_ids": [int(x) for x in prefixes[g]],
+                         "max_new_tokens": 2, "session": f"seed{g}"})
+                    assert term.get("done"), term
+                for e in engines:
+                    e.reset_stats()
+                for g in range(groups):
+                    for i in range(per):
+                        p = np.concatenate([prefixes[g], tails[g][i]])
+                        _, term = router.generate(
+                            {"prompt_ids": [int(x) for x in p],
+                             "max_new_tokens": 3, "session": f"s{g}-{i}"})
+                        assert term.get("done"), term
+                assert router.stats()["placement_mode"] == placement
+            finally:
+                router.close()
+                for rep in reps:
+                    rep.close()
+            return (sum(e._prefix_matched_tokens for e in engines)
+                    / sum(e._prefix_admit_tokens for e in engines))
+
+        hit = {mode: fleet_hit(mode) for mode in ("prefix", "session")}
+        assert hit["prefix"] >= 0.9, hit
+        assert hit["prefix"] > hit["session"] + 0.1, hit
 
     def test_session_mode_preserves_pr11_behavior(self):
         r = self._router("session")

@@ -437,6 +437,75 @@ class TestRouterTenancy:
             rep.close()
 
 
+    def test_replica_kill_fails_over_adapter_traffic(self, served):
+        """Failover composed with the adapter plane: two replicas, every
+        payload carries an adapter and a tenant, replica 1 is killed while
+        it serves. Nothing is lost, every stream equals the one the
+        surviving engine gives that adapter alone (the re-prefilled request
+        re-pins its adapter on the survivor's store), and the survivor's
+        decode does not retrace."""
+        import threading
+        import time
+
+        from paddle_tpu.serving.replica import InProcessReplica
+        from paddle_tpu.serving.router import Router, RouterConfig
+
+        m, store, eng, d = served
+        paddle.seed(0)
+        m2 = LlamaForCausalLM(_config())
+        m2.eval()
+        store2 = AdapterStore(m2, rank=RANK, slots=4)
+        for aid in ("ten-a", "ten-b"):
+            store2.register(aid, str(d / f"{aid}.pdmodel"))
+        eng2 = ServingEngine(m2, ServingConfig(
+            page_size=16, num_pages=64, decode_batch=4, prefill_chunk=16,
+            max_seq_len=64), adapter_store=store2)
+        _drain(eng2, eng2.submit(np.arange(3, 9, dtype=np.int32),
+                                 max_new_tokens=2, adapter="ten-a"))
+        rng = np.random.RandomState(4)
+        n, n_new = 8, 24
+        prompts = [rng.randint(1, 128, 6).astype(np.int32) for _ in range(n)]
+        adapters = [("ten-a", "ten-b")[i % 2] for i in range(n)]
+        want = [_drain(eng, eng.submit(p, max_new_tokens=n_new, adapter=a))
+                for p, a in zip(prompts, adapters)]
+        reps = [InProcessReplica(eng, replica_id=0),
+                InProcessReplica(eng2, replica_id=1)]
+        router = Router(reps, RouterConfig(probe_interval_s=0.05,
+                                           gap_timeout_s=2.0))
+        results = [None] * n
+
+        def client(i):
+            results[i] = router.generate(
+                {"prompt_ids": [int(t) for t in prompts[i]],
+                 "max_new_tokens": n_new, "adapter": adapters[i],
+                 "tenant": adapters[i], "session": f"rc{i}"})
+
+        def killer():
+            deadline = time.time() + 5.0
+            while time.time() < deadline and not eng2.scheduler.running:
+                time.sleep(0.002)
+            reps[1].kill()
+
+        threads = [threading.Thread(target=client, args=(i,))
+                   for i in range(n)] + [threading.Thread(target=killer)]
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60.0)
+            assert reps[1].dead_cause is not None
+            for i, res in enumerate(results):
+                assert res is not None, f"request {i} got no terminal"
+                toks, term = res
+                assert term.get("done") is True, (i, term)
+                assert toks == want[i], i
+            assert eng.decode_retraces_after_warmup == 0
+        finally:
+            router.close()
+            for rep in reps:
+                rep.close()
+
+
 class TestSatellites:
     def test_grouped_matmul_block_rows_provenance(self):
         """Satellite: an indivisible caller-supplied block_rows names its
@@ -455,9 +524,11 @@ class TestSatellites:
     def test_serve_delta_backends_agree(self):
         """The TPU path (pallas grouped matmul, interpret here) and the
         CPU path (xla backend at block_rows=1 — a per-row w[gid] gather)
-        must produce the IDENTICAL delta for any unsorted slot mix,
-        trash rows included: `backend="auto"` switching platforms can
-        never change a stream."""
+        produce the same delta for any unsorted slot mix. They are two
+        programs that add the same float32 products in another order, so
+        the deltas agree to float32 rounding (measured: 227 of 384
+        elements differ, at most 3.8e-6 absolute, 5.1e-5 relative), not
+        bit for bit; the trash rows are EXACTLY zero on both."""
         import jax.numpy as jnp
 
         from paddle_tpu.lora.seam import ServeBinding, serve_delta
@@ -472,9 +543,10 @@ class TestSatellites:
             np.asarray(serve_delta(v, a_pool, b_pool, ServeBinding(
                 {}, slots, G, block_rows=8, backend=be)))
             for be in ("pallas", "auto")]
-        np.testing.assert_array_equal(outs[0], outs[1])
+        np.testing.assert_allclose(outs[0], outs[1], rtol=1e-4, atol=1e-5)
         # trash rows (gid == G) contribute an exactly-zero delta
-        assert np.all(outs[0][3] == 0) and np.all(outs[0][6] == 0)
+        for out in outs:
+            assert np.all(out[3] == 0) and np.all(out[6] == 0)
         assert np.any(outs[0][0] != 0)
 
     def test_lora_metrics_exported(self, served):

@@ -5,6 +5,7 @@ router -> replica -> scheduler/engine asserted on a two-replica in-process
 run), honest step telemetry (bit-equal losses with collection on,
 cost_analysis FLOPs), LogWriter durability, the structured event journal,
 and the /metrics HTTP endpoint + zero-retrace guard on a real engine."""
+import contextlib
 import http.client
 import json
 import os
@@ -526,6 +527,28 @@ class TestKernelNames:
         assert calls == names == 14       # PR 27: kda_fwd, kda_bwd
 
 
+@contextlib.contextmanager
+def _temporary_persistent_cache(path):
+    """JAX's persistent cache at `path`, storing every program however
+    small or quick, for the length of the block."""
+    import jax
+    from jax._src import compilation_cache
+
+    keys = {"jax_compilation_cache_dir": str(path),
+            "jax_persistent_cache_min_compile_time_secs": 0.0,
+            "jax_persistent_cache_min_entry_size_bytes": 0}
+    before = {k: getattr(jax.config, k) for k in keys}
+    try:
+        for k, v in keys.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+        yield
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+
+
 class TestCompileLog:
     def test_hit_and_miss_land_on_the_right_program(self, tmp_path):
         """With a temporary persistent cache: the first compile of a
@@ -533,7 +556,6 @@ class TestCompileLog:
         each under its own fun_name with its phases' seconds."""
         import jax
         import jax.numpy as jnp
-        from jax._src import compilation_cache
 
         from paddle_tpu.core import compile_cache as cc
 
@@ -547,26 +569,15 @@ class TestCompileLog:
         def log_beta(x):
             return x - 3
 
-        keys = {"jax_compilation_cache_dir": str(tmp_path),
-                "jax_persistent_cache_min_compile_time_secs": 0.0,
-                "jax_persistent_cache_min_entry_size_bytes": 0}
-        before = {k: getattr(jax.config, k) for k in keys}
         cc.start_compile_log()
         cc.start_compile_log()                   # registers once
         n0 = len(cc.compile_log())
         t_before = time.perf_counter()
-        try:
-            for k, v in keys.items():
-                jax.config.update(k, v)
-            compilation_cache.reset_cache()
+        with _temporary_persistent_cache(tmp_path):
             x = jnp.ones(3, jnp.float32)
             jax.jit(alpha())(x)
             jax.jit(log_beta)(x)
             jax.jit(alpha())(x)
-        finally:
-            for k, v in before.items():
-                jax.config.update(k, v)
-            compilation_cache.reset_cache()
         mine = [e for e in cc.compile_log()[n0:] if "log_" in e["fun_name"]]
         assert [(e["fun_name"], e["cache"]) for e in mine] == [
             ("jit(log_alpha)", "miss"), ("jit(log_beta)", "miss"),
@@ -580,6 +591,37 @@ class TestCompileLog:
             e["trace_s"] + e["lower_s"] + e["compile_s"]
             for e in mine if e["fun_name"] == "jit(log_alpha)"))
         assert cc.compile_totals()["traces"] >= 3
+
+    def test_registry_series_are_the_persistent_caches_log(self, tmp_path):
+        """`compile_cache_hits_total` / `_misses_total` are published from
+        the compile log of the cache that is on (JAX's persistent one), at
+        scrape time: after a miss-then-hit run they equal the log's own
+        hits and misses."""
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu.core import compile_cache as cc
+
+        def gamma():
+            def log_gamma(x):
+                return x * 5 - 2
+            return log_gamma
+
+        cc.start_compile_log()
+
+        def series():
+            snap = obs_metrics.registry().snapshot()
+            return tuple(snap[n]["samples"][0]["value"] for n in (
+                "compile_cache_hits_total", "compile_cache_misses_total"))
+
+        h0, m0 = series()
+        with _temporary_persistent_cache(tmp_path):
+            x = jnp.ones(3, jnp.float32)
+            jax.jit(gamma())(x)
+            jax.jit(gamma())(x)
+        tot = cc.compile_totals()
+        assert series() == (tot["hits"], tot["misses"])
+        assert series() == (h0 + 1, m0 + 1)
 
 
 # ---------------------------------------------------------------------------

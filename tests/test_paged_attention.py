@@ -171,7 +171,7 @@ class TestVisitCounter:
         assert got.tolist() == want
 
     def test_ragged_cost_below_dense(self, paged_interpret):
-        """The serving bench's utilization counter: visited fraction ==
+        """The utilization counter: visited fraction ==
         sum(ceil(len/ps)) / (B * pages_per_seq), well under the dense 1.0
         for a mixed-length batch."""
         lens = [5, 60, 12, 0, 25, 3, 40, 9]

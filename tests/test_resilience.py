@@ -374,15 +374,23 @@ class TestCompiledStepDetection:
         return CompiledTrainStep(net, lambda o, l: crit(o, l), opt,
                                  anomaly_detector=det)
 
-    def test_healthy_trajectory_bit_identical_with_detection(self):
+    def test_healthy_trajectory_equal_with_detection(self):
+        """Detection adds outputs to the step, so `on` and `off` are two
+        different programs and XLA:CPU fuses them otherwise: the losses
+        agree to float32 rounding (measured: steps 3 and 4 one ulp apart),
+        not bit for bit. Bit-equality is asserted where both sides run the
+        SAME program (the rollback replays of TestRunResilient and
+        TestFitChaosMatrix)."""
         det = AnomalyDetector(policy="rollback", min_history=4)
         s_on = self._step(det)
         s_off = self._step(False)
         x, y = _mlp_data(0)
-        on = [float(s_on(x, y)) for _ in range(4)]
-        off = [float(s_off(x, y)) for _ in range(4)]
+        on = np.asarray([float(s_on(x, y)) for _ in range(4)], np.float32)
+        off = np.asarray([float(s_off(x, y)) for _ in range(4)], np.float32)
         s_on.drain()
-        assert on == off
+        ulps = np.abs(on.view(np.int32).astype(np.int64)
+                      - off.view(np.int32).astype(np.int64))
+        assert ulps.max() <= 4, (on, off)
         assert det.incidents == [] and len(det.history) == 4
 
     def test_nan_batch_skips_update_and_detects_same_step(self):
@@ -420,8 +428,7 @@ class TestCompiledStepDetection:
 @pytest.mark.slow
 class TestRunResilient:
     """Full supervisor recovery loops (compile-heavy: full tier; the quick
-    tier keeps the registry/detector/step units, and the bench `resilience`
-    arm drives the same recovery end-to-end)."""
+    tier keeps the registry/detector/step units)."""
 
     N = 24
 

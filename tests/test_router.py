@@ -79,7 +79,6 @@ class FakeEngine:
         for req in list(self.scheduler.running):
             tok = self.token(req.prompt, len(req.generated))
             req.generated.append(tok)
-            req.token_times.append(time.perf_counter())
             if req.stream_cb is not None:
                 req.stream_cb(req, tok)
             if ((req.eos_id is not None and tok == req.eos_id)

@@ -780,7 +780,7 @@ class TestSpeculativeDecoding:
     def test_prefix_skip_prefill_and_stats(self, shared):
         """A second same-prompt admission adopts the registered pages:
         prefill runs zero tail chunks, the hit rate reflects it, and
-        stats() carries the PR-12 fields the router/bench consume."""
+        stats() carries the PR-12 fields the router consumes."""
         m, cfg, _ = shared
         eng = _engine(m, spec_k=0, prefix_sharing=True)
         rng = np.random.RandomState(17)

@@ -1,11 +1,9 @@
 """Tier-1 tuning plane: the shared block-size resolver (precedence +
 provenance), the JSON tuning cache (round-trip, stale-schema rejection
 mirroring the paddle_tpu-npz1 convention), the CPU-interpret autotuner
-end-to-end (search -> persist -> load -> dispatch), the persistent AOT
-program cache (key safety: geometry/flags/jax-version changes MUST miss;
-corrupted entries fall back to a fresh compile with one warning;
-round-trips are bit-equal), and the grep guard that keeps all five Pallas
-kernels resolving through ONE helper."""
+end-to-end (search -> persist -> load -> dispatch), and the grep guards
+that keep all five Pallas kernels resolving through ONE helper and the
+package on ONE compile cache (JAX's persistent one)."""
 import json
 import os
 import warnings
@@ -16,16 +14,15 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-import paddle_tpu as paddle
 from paddle_tpu.core.flags import _REGISTRY, flag, set_flags
-from paddle_tpu.tuning import (KERNELS, ProgramCache, TuningCache,
-                               cache_key, last_resolution, program_counters,
-                               resolve_blocks, trial_blocks, tuning_counters)
+from paddle_tpu.tuning import (KERNELS, TuningCache, cache_key,
+                               last_resolution, resolve_blocks, trial_blocks,
+                               tuning_counters)
 from paddle_tpu.tuning.blocks import _last
 
-TUNE_FLAGS = ("autotune", "tuning_cache_dir", "program_cache_dir",
-              "flash_block_q", "flash_block_k", "flash_bwd_block_q",
-              "flash_bwd_block_k", "moe_block_rows", "rmsnorm_block_rows",
+TUNE_FLAGS = ("autotune", "tuning_cache_dir", "flash_block_q",
+              "flash_block_k", "flash_bwd_block_q", "flash_bwd_block_k",
+              "moe_block_rows", "rmsnorm_block_rows",
               "fused_ce_chunk_tokens", "fused_ce_chunk_vocab",
               "serving_page_size")
 
@@ -293,167 +290,12 @@ class TestAutotuneEndToEnd:
         _resolve_rmsnorm()
         ensure_metrics_collector()
         snap = obs_metrics.registry().snapshot()
-        for name in ("compile_cache_hits_total", "compile_cache_misses_total",
-                     "autotune_trials_total", "block_resolutions_total",
-                     "program_load_ms"):
+        for name in ("autotune_trials_total", "tuning_cache_rejects_total",
+                     "block_resolutions_total"):
             assert name in snap, name
         provs = {s["labels"].get("provenance")
                  for s in snap["block_resolutions_total"]["samples"]}
         assert {"flag", "tuned", "default", "trial"} <= provs
-
-
-def _lower_fn(n=8):
-    def f(x):
-        return (x * 2.0 + 1.0).sum()
-
-    return jax.jit(f).lower(jnp.ones((n, 4), jnp.float32))
-
-
-class TestProgramCacheKeys:
-    def test_key_sensitivity(self, tmp_path):
-        """Geometry, flags fingerprint, jax version, platform tag and the
-        caller tag each MUST move the key — drift can only miss, never
-        load a stale executable."""
-        pc = ProgramCache(str(tmp_path))
-        low = _lower_fn(8)
-        base = pc.key_for(low, "t")
-        assert pc.key_for(low, "t") == base  # deterministic
-        assert pc.key_for(_lower_fn(16), "t") != base          # geometry
-        assert pc.key_for(low, "t2") != base                   # tag
-        assert pc.key_for(low, "t", extra="x") != base         # extra
-        assert pc.key_for(low, "t", _jax_version="9.9.9") != base
-        assert pc.key_for(low, "t", _flags_fp="{}") != base
-
-    def test_cache_control_flags_do_not_move_the_key(self, tmp_path):
-        """FLAGS_autotune/tuning_cache_dir/program_cache_dir select where
-        to cache, not what compiles: a warm load-mode process must hit the
-        programs a search-mode process persisted."""
-        pc = ProgramCache(str(tmp_path))
-        low = _lower_fn(8)
-        set_flags({"autotune": "search", "tuning_cache_dir": "/x",
-                   "program_cache_dir": str(tmp_path)})
-        k1 = pc.key_for(low, "t")
-        set_flags({"autotune": "load", "tuning_cache_dir": "/y",
-                   "program_cache_dir": ""})
-        assert pc.key_for(low, "t") == k1
-        set_flags({"flash_block_q": 256})  # a REAL flag still moves it
-        assert pc.key_for(low, "t") != k1
-
-
-class TestProgramCacheRoundTrip:
-    def test_miss_store_hit_bit_equal(self, tmp_path):
-        pc = ProgramCache(str(tmp_path))
-        low = _lower_fn(8)
-        x = jnp.arange(32, dtype=jnp.float32).reshape(8, 4)
-        ex1, s1, ms1 = pc.load_or_compile(low, "rt")
-        assert s1 == "miss" and ms1 > 0
-        # a second instance over the same dir = a cold process
-        ex2, s2, ms2 = ProgramCache(str(tmp_path)).load_or_compile(low, "rt")
-        assert s2 == "hit"
-        assert float(ex1(x)) == float(ex2(x))  # bit-equal
-        assert program_counters()["last_load_ms"] == ms2
-
-    def test_corrupt_entry_falls_back_with_one_warning(self, tmp_path):
-        pc = ProgramCache(str(tmp_path))
-        low = _lower_fn(8)
-        key = pc.key_for(low, "c")
-        pc.load_or_compile(low, "c")
-        path = os.path.join(str(tmp_path), f"{key}.prog")
-        blob = open(path, "rb").read()
-        with open(path, "wb") as f:
-            f.write(blob[:len(blob) // 2])  # truncate the payload
-        before = program_counters()["corrupt"]
-        with pytest.warns(UserWarning, match="unusable program-cache"):
-            ex, status, _ = pc.load_or_compile(low, "c")
-        assert status == "miss"  # recompiled, never crashed
-        assert program_counters()["corrupt"] == before + 1
-        x = jnp.ones((8, 4), jnp.float32)
-        assert float(ex(x)) == 96.0  # (1*2+1) summed over 8x4
-        # the recompile re-stored a good entry; and the warning fired ONCE
-        with warnings.catch_warnings():
-            warnings.simplefilter("error")
-            _, status, _ = pc.load_or_compile(low, "c")
-        assert status == "hit"
-
-    def test_alien_header_rejected(self, tmp_path):
-        pc = ProgramCache(str(tmp_path))
-        low = _lower_fn(8)
-        key = pc.key_for(low, "a")
-        os.makedirs(str(tmp_path), exist_ok=True)
-        with open(os.path.join(str(tmp_path), f"{key}.prog"), "wb") as f:
-            f.write(b'{"format": "paddle_tpu-prog0", "payload_bytes": 0}\n')
-        before = program_counters()["corrupt"]
-        with pytest.warns(UserWarning):
-            assert pc.load(key, low) is None
-        assert program_counters()["corrupt"] == before + 1
-
-
-class TestTrainStepAot:
-    def test_cold_miss_then_warm_hit_loss_bit_equal(self, tmp_path):
-        """CompiledTrainStep through FLAGS_program_cache_dir: the second
-        instance (a cold process stand-in) must LOAD and produce the
-        bit-identical loss."""
-        from paddle_tpu.models.llama import (LlamaForCausalLM,
-                                             LlamaPretrainingCriterion,
-                                             llama_tiny_config)
-        from paddle_tpu.parallel import CompiledTrainStep
-
-        set_flags({"program_cache_dir": str(tmp_path)})
-        rng = np.random.RandomState(0)
-        cfg = llama_tiny_config(num_hidden_layers=1)
-        ids = rng.randint(0, cfg.vocab_size, (2, 16)).astype(np.int64)
-        crit = LlamaPretrainingCriterion(cfg)
-
-        def make():
-            paddle.seed(0)
-            m = LlamaForCausalLM(cfg)
-            opt = paddle.optimizer.AdamW(learning_rate=1e-3,
-                                         parameters=m.parameters())
-            return CompiledTrainStep(m, lambda o, l: crit(o, l),
-                                     optimizer=opt)
-
-        s1 = make()
-        loss1 = float(s1(ids, ids))
-        assert s1.program_cache["status"] == "miss"
-        s2 = make()
-        loss2 = float(s2(ids, ids))
-        assert s2.program_cache["status"] == "hit"
-        assert loss1 == loss2
-        assert s2.program_cache["ms"] < s1.program_cache["ms"]
-
-
-@pytest.mark.slow
-class TestEngineProgramCache:
-    def test_stats_surface_and_warm_load(self, tmp_path):
-        """ServingEngine /stats carries the per-program cache outcomes;
-        a second engine over the same dir loads every program and streams
-        the identical tokens."""
-        from paddle_tpu.models.llama import (LlamaForCausalLM,
-                                             llama_tiny_config)
-        from paddle_tpu.serving import ServingConfig, ServingEngine
-
-        set_flags({"program_cache_dir": str(tmp_path)})
-        paddle.seed(0)
-        m = LlamaForCausalLM(llama_tiny_config())
-        m.eval()
-
-        def run():
-            eng = ServingEngine(m, ServingConfig(
-                page_size=4, num_pages=64, decode_batch=4,
-                prefill_chunk=8, max_seq_len=64))
-            outs = eng.generate([np.arange(1, 6, dtype=np.int32)],
-                                max_new_tokens=4)
-            eng.mark_warmup()
-            return [int(t) for t in outs[0]], eng.stats()["program_cache"]
-
-        toks1, st1 = run()
-        assert st1["enabled"] and st1["dir"] == str(tmp_path)
-        assert st1["programs"] and all(
-            v["status"] == "miss" for v in st1["programs"].values())
-        assert set(st1["at_warmup"]) == set(st1["programs"])
-        toks2, st2 = run()
-        assert toks2 == toks1
-        assert all(v["status"] == "hit" for v in st2["programs"].values())
 
 
 KERNEL_FILES = {
@@ -506,6 +348,24 @@ class TestSharedResolverGuard:
                 if "partial override ignored" in open(path).read():
                     offenders.append(os.path.relpath(path, root))
         assert offenders == [os.path.join("tuning", "blocks.py")], offenders
+
+    def test_the_package_names_no_second_compile_cache(self):
+        """PR 30 deleted the serialized-executable cache beside JAX's
+        persistent one (`core/compile_cache.py`): no file of the package
+        may name it again without a cell that shows it to win."""
+        import paddle_tpu
+
+        root = os.path.dirname(os.path.abspath(paddle_tpu.__file__))
+        offenders = []
+        for dirpath, _, files in os.walk(root):
+            for fname in files:
+                if not fname.endswith(".py"):
+                    continue
+                path = os.path.join(dirpath, fname)
+                src = open(path).read()
+                if "program_cache" in src or "AotProgram" in src:
+                    offenders.append(os.path.relpath(path, root))
+        assert offenders == [], offenders
 
     def test_kernel_registry_covers_the_contract(self):
         assert set(KERNELS) == {"flash_fwd", "flash_bwd", "grouped_matmul",

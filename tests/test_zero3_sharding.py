@@ -161,14 +161,18 @@ class TestZero3Parity:
         assert step._zero3_scan_info is None
 
 
-def _compiled_text(step, ids):
+def _compiled(step, ids):
     step._build()
     placed, _ = step._spec_cache.place([ids._value] * 3)
     lowered = step._jitted.lower(
         step._param_vals, step._opt_states, tuple(placed),
         jax.random.key(0), jnp.asarray(1e-3, jnp.float32),
         jnp.asarray(1, jnp.int32))
-    return lowered.compile().as_text()
+    return lowered.compile()
+
+
+def _compiled_text(step, ids):
+    return _compiled(step, ids).as_text()
 
 
 def _all_gather_result_shapes(txt):
@@ -222,6 +226,32 @@ class TestHLOGuard:
         assert any(dims[0] == self.L and int(np.prod(dims)) in stack_elems
                    for dims in shapes), \
             "gather-at-start baseline shows no full-stack all-gather"
+
+
+    def test_gather_ahead_keeps_two_layers_live_not_the_stack(self):
+        """XLA's memory analysis of the two compiled steps (a static count,
+        no clock): gather-at-start holds the whole gathered stack,
+        gather-ahead two layers of it, so the peak gap accounts for the
+        (L - 2) layers gather-ahead never materialises (at least half of
+        their bytes: the compiler may overlap other temporaries)."""
+        L = 8
+        peak, layer_bytes = {}, None
+        for mode in ("start", "ahead"):
+            build_mesh({"sharding": ZD})
+            # weights outweigh the activations of an 8 x 16 batch
+            cfg, m = _model(L, hidden_size=256, intermediate_size=512)
+            step = _step(m, scan_layers=True, zero_axis="sharding",
+                         zero_stage=3, zero3_gather=mode)
+            ids, _ = _data(cfg)
+            ma = _compiled(step, ids).memory_analysis()
+            peak[mode] = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                          + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+            n_outer = len(step._outer_params)
+            layer_bytes = sum(int(np.prod(v.shape[1:])) * v.dtype.itemsize
+                              for v in step._param_vals[n_outer:])
+            set_mesh(None)
+        assert peak["start"] - peak["ahead"] >= 0.5 * (L - 2) * layer_bytes, (
+            peak, layer_bytes)
 
 
 class TestStateDictRoundTrip:

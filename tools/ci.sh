@@ -12,13 +12,11 @@
 #   1. import hygiene: importing paddle_tpu must NOT initialize the XLA
 #      backend (jax.distributed would break)
 #   1c. tuning plane: block-size resolver precedence/provenance, the JSON
-#      tuning cache, and the persistent AOT program cache (key safety,
-#      corrupt-entry fallback, warm-load bit-equality)
+#      tuning cache and the autotuner end to end
 #   2. unit suite on the virtual 8-device CPU mesh
 #   3. driver multichip gate: 8-device dryrun of the full sharded train step
-#   4. bench smoke (CPU config) + regression check against the recorded
-#      baseline (tools/bench_regression.py), incl. the warm-vs-cold
-#      TUNE_JSON gates
+# Speed is not CI's to judge: the driver measures every PR on the chip
+# (BENCHMARK.json, benchmark/run.py, PERF_LEDGER.jsonl).
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -38,9 +36,9 @@ echo "== [1b] observability plane (not slow) =="
 python -m pytest tests/test_observability.py -q -m "not slow"
 
 echo "== [1c] tuning plane (not slow) =="
-# the autotuner + AOT program cache feed every compile the later stages
-# time: resolver precedence, cache-key safety and corrupt-entry fallback
-# are verified before any stage that could silently eat a stale program
+# the block resolver feeds every kernel the later stages compile: its
+# precedence, the tuning cache's stale-schema rejection and the autotuner
+# are verified first
 python -m pytest tests/test_tuning.py -q -m "not slow"
 
 if [ "$TIER" = "quick" ]; then
@@ -57,9 +55,6 @@ python -m pytest tests/ -q --ignore=tests/test_observability.py --ignore=tests/t
 
 echo "== [3] multichip gate =="
 python -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
-
-echo "== [4] bench regression =="
-python tools/bench_regression.py
 
 if [ "$TIER" = "nightly" ]; then
   echo "== [5] loss-curve parity (200 steps, fp32 + bf16, vs torch) =="
