@@ -1,6 +1,6 @@
 """One chip's share of an expert-parallel layer: gated (SwiGLU) experts, a
-shared expert every token passes through, and a router as wide as the whole
-layer.
+shared expert every token passes through (or none: `num_shared=0`), and a
+router as wide as the whole layer.
 
 `HeldExpertsMoE` is told which experts it holds (`held_experts`, a range).
 It scores and chooses over ALL `num_expert` experts as the whole layer does,
@@ -56,12 +56,13 @@ def held_rows(pairs: int, held: int, num_expert: int, block_rows: int = 0,
     return min(_round_up(max(int(capacity_factor * share), bm), bm), pairs), bm
 
 
-def _held_moe(xv, logits, bias, wg, wu, wd, sg_w, su_w, sd_w, *, k, first,
-              routing, rows, block_rows, backend):
+def _held_moe(xv, logits, bias, wg, wu, wd, *shared_w, k, first, routing,
+              rows, block_rows, backend):
     """The layer on plain arrays: xv [N, d], logits [N, E] float32, the
     held experts' weights [G, ...] (experts first .. first + G - 1) and the
-    shared expert's. Returns (out [N, d], [pairs routed here, largest and
-    mean load of a held expert, pairs left out], load of ALL experts [E])."""
+    shared expert's three (`shared_w`; none for a layer without one).
+    Returns (out [N, d], [pairs routed here, largest and mean load of a held
+    expert, pairs left out], load of ALL experts [E])."""
     n, d = xv.shape
     E, G = logits.shape[1], wg.shape[0]
     topv, topi, _ = _route(logits.astype(jnp.float32), None, k=k,
@@ -80,20 +81,26 @@ def _held_moe(xv, logits, bias, wg, wu, wd, sg_w, su_w, sd_w, *, k, first,
     act = (jax.nn.silu(mm(buf, wg)) * mm(buf, wu)).astype(xv.dtype)
     y = jnp.take(mm(act, wd), dest, axis=0, mode="fill", fill_value=0.0)
     routed = jnp.zeros((n, d), jnp.float32).at[tok].add(y * wgt[:, None])
-    shared = (jax.nn.silu(xv @ sg_w) * (xv @ su_w)) @ sd_w
+    if shared_w:
+        sg_w, su_w, sd_w = shared_w
+        shared = (jax.nn.silu(xv @ sg_w) * (xv @ su_w)) @ sd_w
     load = counts.astype(jnp.float32)
     n_here = jnp.sum(load)
     stats = jnp.stack([n_here, jnp.max(load), jnp.mean(load),
                        jnp.maximum(n_here - rows, 0.0)])
     load_all = jnp.zeros((E,), jnp.float32).at[flat].add(1.0)
-    return (routed + shared.astype(jnp.float32)).astype(xv.dtype), stats, load_all
+    if shared_w:
+        routed = routed + shared.astype(jnp.float32)
+    return routed.astype(xv.dtype), stats, load_all
 
 
 class HeldExpertsMoE(Layer):
     """The experts `held_experts = (first, stop)` of a layer of `num_expert`
     gated experts of width `d_hidden`, its sigmoid router (all `num_expert`
     wide, `top_k` a token, its correction bias moved by `bias_update_rate`)
-    and its shared expert of width `d_hidden * num_shared`. After a forward
+    and its shared expert of width `d_hidden * num_shared` (`num_shared=0`:
+    no shared expert, and no leaves for one). `renorm_eps` is added to the sum
+    the chosen scores are divided by. After a forward
     `step_stats` holds [pairs routed here, largest load, mean load of a held
     expert, pairs left out] and `gate.next_bias` the bias after this step.
 
@@ -103,8 +110,8 @@ class HeldExpertsMoE(Layer):
 
     def __init__(self, d_model, num_expert, d_hidden, top_k, *,
                  held_experts=None, routed_scale=1.0, renormalize=True,
-                 num_shared=1, bias_update_rate=0.0, block_rows=0,
-                 backend=None, recompute=False):
+                 renorm_eps=0.0, num_shared=1, bias_update_rate=0.0,
+                 block_rows=0, backend=None, recompute=False):
         super().__init__()
         first, stop = held_experts or (0, num_expert)
         if not 0 <= first < stop <= num_expert:
@@ -117,7 +124,7 @@ class HeldExpertsMoE(Layer):
         held = stop - first
         self.gate = SigmoidGate(d_model, num_expert, topk=top_k,
                                 routed_scale=routed_scale,
-                                renormalize=renormalize,
+                                renormalize=renormalize, renorm_eps=renorm_eps,
                                 bias_update_rate=bias_update_rate)
         init = I.XavierNormal()
         mk = lambda *shape: self.create_parameter(      # noqa: E731
@@ -125,8 +132,10 @@ class HeldExpertsMoE(Layer):
         self.w_gate, self.w_up = mk(held, d_model, d_hidden), mk(held, d_model, d_hidden)
         self.w_down = mk(held, d_hidden, d_model)
         hs = d_hidden * num_shared
-        self.shared_gate, self.shared_up = mk(d_model, hs), mk(d_model, hs)
-        self.shared_down = mk(hs, d_model)
+        self.shared = bool(hs)
+        if self.shared:
+            self.shared_gate, self.shared_up = mk(d_model, hs), mk(d_model, hs)
+            self.shared_down = mk(hs, d_model)
         self.l_aux = None           # balanced by the router's bias, no loss
         self.tokens_dropped = None
         self.step_stats = None
@@ -153,9 +162,9 @@ class HeldExpertsMoE(Layer):
             fn = jax.checkpoint(fn)
         out, stats, load = apply_op(
             fn, x2, self.gate(x2), self.gate.e_score_correction_bias,
-            self.w_gate, self.w_up, self.w_down, self.shared_gate,
-            self.shared_up, self.shared_down, name="held_experts_moe",
-            n_outputs=3)
+            self.w_gate, self.w_up, self.w_down,
+            *((self.shared_gate, self.shared_up, self.shared_down)
+              if self.shared else ()), name="held_experts_moe", n_outputs=3)
         self.step_stats = stats
         self.tokens_dropped = stats[3]
         self.gate.next_bias = self.gate.balanced(load)

@@ -123,8 +123,9 @@ class SwitchGate(NaiveGate):
 class SigmoidGate(NaiveGate):
     """Sigmoid router with a correction bias (DeepSeek-V3 / Kimi family):
     scores `s = sigmoid(x W)` in float32, the k experts with the largest
-    `s + b` are chosen, and a chosen expert weighs `scale * s_e / sum of the
-    chosen s` (`renormalize`), else `scale * s_e`. `b` balances load
+    `s + b` are chosen, and a chosen expert weighs `scale * s_e / (sum of the
+    chosen s + renorm_eps)` (`renormalize`; the epsilon is 0 in the Kimi
+    family and 1e-6 in LFM2), else `scale * s_e`. `b` balances load
     without an auxiliary loss. It is a parameter no gradient reaches
     (`stop_gradient`, float32 whatever the model is cast to); the balancing
     rule moves it: after a step, up by `bias_update_rate` for an expert
@@ -135,10 +136,12 @@ class SigmoidGate(NaiveGate):
     group that is the identity."""
 
     def __init__(self, d_model, num_expert, world_size=1, topk=8,
-                 routed_scale=1.0, renormalize=True, bias_update_rate=0.0):
+                 routed_scale=1.0, renormalize=True, bias_update_rate=0.0,
+                 renorm_eps=0.0):
         super().__init__(d_model, num_expert, world_size, topk)
         self.routed_scale = float(routed_scale)
         self.renormalize = bool(renormalize)
+        self.renorm_eps = float(renorm_eps)
         self.bias_update_rate = float(bias_update_rate)
         self.e_score_correction_bias = self.create_parameter(
             [num_expert], None, dtype="float32",
@@ -165,7 +168,8 @@ class SigmoidGate(NaiveGate):
 
     def routing_config(self, training: bool) -> tuple:
         return (("kind", "sigmoid"), ("routed_scale", self.routed_scale),
-                ("renormalize", self.renormalize))
+                ("renormalize", self.renormalize),
+                ("renorm_eps", self.renorm_eps))
 
 
 class ExpertFFN(Layer):
@@ -210,7 +214,10 @@ def _route(logits, rng, *, k, routing, bias=None):
         _, topi = jax.lax.top_k(scores if bias is None else scores + bias, k)
         topv = jnp.take_along_axis(scores, topi, axis=-1)
         if cfg.get("renormalize", True):
-            topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+            total = jnp.sum(topv, axis=-1, keepdims=True)
+            eps = cfg.get("renorm_eps", 0.0)
+            # no `+ 0.0` where there is no epsilon: the program stays the same
+            topv = topv / (total + eps if eps else total)
         return topv * cfg.get("routed_scale", 1.0), topi, scores
     if kind == "switch" and cfg.get("switch_eps", 0.0) > 0.0:
         eps = cfg["switch_eps"]

@@ -16,3 +16,6 @@ from paddle_tpu.models.kimi_linear import (  # noqa: F401
     KimiLinearConfig, KimiLinearForCausalLM, KimiLinearModel,
     kimi_linear_tiny_config,
 )
+from paddle_tpu.models.lfm2_moe import (  # noqa: F401
+    Lfm2MoeConfig, Lfm2MoeForCausalLM, Lfm2MoeModel, lfm2_moe_tiny_config,
+)

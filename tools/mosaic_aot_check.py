@@ -3,7 +3,8 @@
 
     python tools/mosaic_aot_check.py            # the dense train/serve path
     python tools/mosaic_aot_check.py --extra    # + the kernels off that path,
-                                                #   Kimi-Linear's among them
+                                                #   Kimi-Linear's and LFM2-MoE's
+                                                #   among them
 
 Each case is lowered and fully compiled for a TPU — block-shape checks,
 Mosaic's own lowering, the scoped-VMEM limit — and reported as `ok` or
@@ -126,6 +127,7 @@ def _cases(extra: bool):
             flash, [((2, s, h, d), bf)] * 3 + [((2, s), i32)]),
     }
     off_path.update(_kimi_linear_cases())
+    off_path.update(_lfm2_moe_cases())
     return dense, off_path
 
 
@@ -185,6 +187,44 @@ def _kimi_linear_cases():
             [((rows * s, hid), bf), ((rows * s, 256), f32), ((256,), f32),
              ((8, hid, inter), bf), ((8, hid, inter), bf), ((8, inter, hid), bf),
              ((hid, inter), bf), ((hid, inter), bf), ((inter, hid), bf)]),
+    }
+
+
+def _lfm2_moe_cases():
+    """The kernels of `train_lfm2moe_seq8k` at its shapes: 3 rows x 8192,
+    32 query heads on 8 key heads of width 64 (half the lanes), and an expert
+    layer WITHOUT a shared expert: 8 of 64 held, [2048, 1536], four a token."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.incubate.distributed.models.moe.held_experts import (
+        _held_moe, held_rows)
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_bshd
+
+    bf, f32 = jnp.bfloat16, jnp.float32
+    rows, s, hid, inter = 3, 8192, 2048, 1536
+
+    def gqa_grad(q, k, v):
+        return jax.grad(lambda *a: flash_attention_bshd(
+            *a, causal=True, interpret=False).astype(f32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    laid_out, bm = held_rows(rows * s * 4, 8, 64)
+    routing = (("kind", "sigmoid"), ("renormalize", True), ("renorm_eps", 1e-6))
+
+    def experts_grad(x, logits, bias, *w):
+        return jax.grad(lambda a, *b: _held_moe(
+            a, logits, bias, *b, k=4, first=0, routing=routing, rows=laid_out,
+            block_rows=bm, backend="pallas")[0].astype(f32).sum(),
+            argnums=tuple(range(4)))(x, *w)
+
+    return {
+        "flash fwd+bwd 3 x 8192, 32q / 8kv x 64": (
+            gqa_grad, [((rows, s, 32, 64), bf)] + [((rows, s, 8, 64), bf)] * 2),
+        "held experts layer fwd+bwd 24576 tokens x 8 of 64, no shared expert": (
+            experts_grad,
+            [((rows * s, hid), bf), ((rows * s, 64), f32), ((64,), f32),
+             ((8, hid, inter), bf), ((8, hid, inter), bf), ((8, inter, hid), bf)]),
     }
 
 
