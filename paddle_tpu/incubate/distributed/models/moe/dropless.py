@@ -47,30 +47,52 @@ from paddle_tpu.ops.pallas.grouped_matmul import (
     grouped_matmul, pick_block_rows,
 )
 
-__all__ = ["_dropless_moe", "_expert_choice_moe", "ragged_layout"]
+__all__ = ["_dropless_moe", "_expert_choice_moe", "ragged_layout", "pair_rows"]
 
 
 def _round_up(v, m):
     return ((v + m - 1) // m) * m
 
 
-def _order_by_group(gids_all, counts_full):
-    """What a stable `argsort(gids_all)` gives. With few groups (a chip's
-    share of the experts and the trash) by counting: each copy's place is
-    its group's start plus how many of its group came before it, and the
-    order is that permutation inverted by one scatter. A stable sort of
-    131,072 keys takes 36 s to compile for a v5e, this takes 1 (XLA
-    analysis, PR 27)."""
-    (Nk,), groups = gids_all.shape, counts_full.shape[0]
+def _place_by_group(gids_all, groups):
+    """(each copy's position in a stable sort by group id, copies of each
+    group [groups]). With few groups (a chip's share of the experts and the
+    trash) without sorting or scattering: a copy's place is its group's start
+    plus how many of its group came before it. A stable sort of 131,072 keys
+    takes 36 s to compile for a v5e, this takes 1 (XLA analysis, PR 27)."""
+    (Nk,) = gids_all.shape
     if groups > 16:
-        return jnp.argsort(gids_all)                              # stable
+        counts = jnp.zeros((groups,), jnp.int32).at[gids_all].add(1)
+        return jnp.zeros((Nk,), jnp.int32).at[jnp.argsort(gids_all)].set(
+            jnp.arange(Nk, dtype=jnp.int32)), counts
     onehot = (gids_all[:, None] == jnp.arange(groups, dtype=jnp.int32)[None, :]
               ).astype(jnp.int32)                                 # [Nk, groups]
     before = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=1)
-    start = jnp.cumsum(counts_full) - counts_full
-    place = jnp.take(start, gids_all) + before
-    return jnp.zeros((Nk,), jnp.int32).at[place].set(
-        jnp.arange(Nk, dtype=jnp.int32))
+    counts = jnp.sum(onehot, axis=0)
+    return jnp.take(jnp.cumsum(counts) - counts, gids_all) + before, counts
+
+
+def _order_by_group(gids_all, counts_full):
+    """What a stable `argsort(gids_all)` gives: with few groups
+    `_place_by_group`'s permutation, inverted by one scatter."""
+    (Nk,), groups = gids_all.shape, counts_full.shape[0]
+    if groups > 16:
+        return jnp.argsort(gids_all)                              # stable
+    return jnp.zeros((Nk,), jnp.int32).at[
+        _place_by_group(gids_all, groups)[0]].set(jnp.arange(Nk, dtype=jnp.int32))
+
+
+def _bucket_buffer(counts, bm, rows):
+    """The bucket buffer of `counts [E]` copies an expert, `rows` of them
+    kept: (aoff [E+1], each bucket's start aligned to bm; M, its rows,
+    STATIC; gbuf [M], each row's expert, E past the buckets)."""
+    E = counts.shape[0]
+    aoff = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                            jnp.cumsum(_round_up(counts, bm))])   # [E+1]
+    M = _round_up(rows, bm) + E * bm
+    gbuf = jnp.searchsorted(aoff[1:], jnp.arange(M, dtype=jnp.int32),
+                            side="right").astype(jnp.int32)
+    return aoff, M, gbuf
 
 
 def ragged_layout(gids_all, E, bm, rows=None):
@@ -107,16 +129,29 @@ def ragged_layout(gids_all, E, bm, rows=None):
     raw_start = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                                  jnp.cumsum(counts_full)[:-1]])   # [E+1]
     rank = jnp.arange(rows, dtype=jnp.int32) - jnp.take(raw_start, sorted_g)
-    aligned = _round_up(counts, bm)
-    aoff = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                            jnp.cumsum(aligned)])                 # [E+1]
-    M = _round_up(rows, bm) + E * bm                              # static
+    aoff, _, gbuf = _bucket_buffer(counts, bm, rows)
     dest = jnp.where(sorted_g < E,
                      jnp.take(aoff, jnp.minimum(sorted_g, E - 1)) + rank,
                      aoff[E] + rank)
-    gbuf = jnp.searchsorted(aoff[1:], jnp.arange(M, dtype=jnp.int32),
-                            side="right").astype(jnp.int32)
     return order, rank, dest, gbuf, counts
+
+
+def pair_rows(gids_all, E, bm, rows):
+    """`ragged_layout(rows=)` told by copy and not by sorted position, for a
+    caller that moves its rows by index arrays (`ops.pallas.moe_rows`):
+    (pair_row [Nk], the buffer row of each copy, M for a copy that is trash
+    or past the `rows` kept; gbuf [M]; counts [E]; the rows up to which the
+    buckets reach, a multiple of bm). The same rows as `ragged_layout`'s
+    `dest`; with few groups no sort, no permutation and no scatter."""
+    (Nk,) = gids_all.shape
+    rows = min(rows, Nk)
+    place, counts_full = _place_by_group(gids_all, E + 1)
+    start = jnp.cumsum(counts_full) - counts_full
+    aoff, M, gbuf = _bucket_buffer(counts_full[:E], bm, rows)
+    kept = (gids_all < E) & (place < rows)
+    row = jnp.take(aoff, jnp.minimum(gids_all, E - 1)) + place - jnp.take(start, gids_all)
+    return (jnp.where(kept, row, M).astype(jnp.int32), gbuf, counts_full[:E],
+            jnp.minimum(aoff[E], M))
 
 
 def _act(h, act):

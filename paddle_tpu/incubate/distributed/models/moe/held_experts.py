@@ -10,17 +10,19 @@ the experts other chips hold: on one chip there is no exchange. The shares
 of all chips, with the shared expert counted once, add up to the whole
 layer (tests/test_kimi_linear.py::test_shares_add_up).
 
-The pairs go through the dropless dispatcher's own layout
-(`dropless.ragged_layout`: sorted by expert into block-aligned buckets) and
-`ops.pallas.grouped_matmul(aligned=True)`, whose kernels skip the row blocks
-no pair fell into: device time follows the pairs routed here. Every shape is
-static. A chip that holds ALL the experts lays out every pair and drops
-none. A chip that holds a share lays out `CAPACITY_FACTOR` times the share a
-balanced router sends it (`tokens x top_k x held / num_expert` rows) and
-COUNTS the pairs past that (`step_stats[3]`, `moe.dropped`): the router's
-correction bias is what keeps the count at zero, moved after every step by
-the balancing rule (`SigmoidGate.next_bias`), which `CompiledTrainStep`
-applies outside the gradient.
+The layout is a few integer arrays built ONCE a layer and step, outside
+what `recompute=True` runs again (`_layout`: `dropless.pair_rows` gives each
+pair its row of the dispatcher's block-aligned buckets). Two row movers, each
+the other's transpose, fill the buffer and sum the products back
+(`ops.pallas.moe_rows`): their device time follows the pairs routed here, not
+the rows laid out, as `grouped_matmul(aligned=True)` between them skips the row
+blocks no pair fell into. Every shape is static. A chip that holds ALL the
+experts lays out every pair and drops none. A chip that holds a share lays out
+`CAPACITY_FACTOR` times the share a balanced router sends it (`tokens x top_k
+x held / num_expert` rows) and COUNTS the pairs past that (`step_stats[3]`,
+`moe.dropped`): the router's correction bias is what keeps the count at zero,
+moved after every step by the balancing rule (`SigmoidGate.next_bias`), which
+`CompiledTrainStep` applies outside the gradient.
 """
 from __future__ import annotations
 
@@ -31,12 +33,14 @@ import jax.numpy as jnp
 
 from paddle_tpu.core.tensor import apply_op
 from paddle_tpu.incubate.distributed.models.moe.dropless import (
-    _round_up, ragged_layout)
+    _round_up, pair_rows)
 from paddle_tpu.incubate.distributed.models.moe.moe_layer import (
     SigmoidGate, _route)
 from paddle_tpu.nn import initializer as I
 from paddle_tpu.nn.layer.layers import Layer
 from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul, pick_block_rows
+from paddle_tpu.ops.pallas.moe_rows import (
+    rows_backend, rows_combine, rows_gather, rows_layout)
 
 __all__ = ["HeldExpertsMoE", "held_rows"]
 
@@ -56,42 +60,69 @@ def held_rows(pairs: int, held: int, num_expert: int, block_rows: int = 0,
     return min(_round_up(max(int(capacity_factor * share), bm), bm), pairs), bm
 
 
-def _held_moe(xv, logits, bias, wg, wu, wd, *shared_w, k, first, routing,
-              rows, block_rows, backend):
-    """The layer on plain arrays: xv [N, d], logits [N, E] float32, the
-    held experts' weights [G, ...] (experts first .. first + G - 1) and the
-    shared expert's three (`shared_w`; none for a layer without one).
-    Returns (out [N, d], [pairs routed here, largest and mean load of a held
-    expert, pairs left out], load of ALL experts [E])."""
-    n, d = xv.shape
-    E, G = logits.shape[1], wg.shape[0]
+def _rows_layout(gids, held, k, block_rows, rows):
+    """The layout of pairs with group ids `gids [N * k]` (`held`: not here):
+    (the movers' `RowsLayout`; `gbuf [M]`, each buffer row's expert; pairs of
+    each held expert). The rows are `ragged_layout(rows=)`'s."""
+    pair_row, gbuf, counts, reach = pair_rows(gids, held, block_rows, rows)
+    row_pair = jnp.full(gbuf.shape, gids.shape[0], jnp.int32).at[pair_row].set(
+        jnp.arange(gids.shape[0], dtype=jnp.int32), mode="drop")
+    return (rows_layout(row_pair, pair_row.reshape(-1, k), reach // block_rows,
+                        min(rows, gids.shape[0])), gbuf, counts)
+
+
+def _layout(logits, bias, *, k, first, held, routing, rows, block_rows):
+    """Route and lay out, once a layer and step: logits [N, E] float32 ->
+    (weights [N, k] float32 of each token's chosen experts; the layout and
+    `gbuf` of `_rows_layout`; [pairs routed here, largest and mean load of a
+    held expert, pairs left out]; load of ALL experts [E])."""
     topv, topi, _ = _route(logits.astype(jnp.float32), None, k=k,
                            routing=routing, bias=bias)
     flat = topi.reshape(-1)
     local = flat - first
-    gids = jnp.where((local >= 0) & (local < G), local, G).astype(jnp.int32)
-    order, _, dest, gbuf, counts = ragged_layout(gids, G, block_rows, rows=rows)
-    here = jnp.take(gids, order) < G                  # routed pairs sort first
-    tok = (order // k).astype(jnp.int32)
-    wgt = jnp.take(topv.reshape(-1), order) * here
-    buf = jnp.zeros((gbuf.shape[0], d), xv.dtype).at[dest].set(
-        jnp.take(xv, tok, axis=0), mode="drop")
-    mm = functools.partial(grouped_matmul, gids=gbuf, block_rows=block_rows,
-                           backend=backend, aligned=True)
-    act = (jax.nn.silu(mm(buf, wg)) * mm(buf, wu)).astype(xv.dtype)
-    y = jnp.take(mm(act, wd), dest, axis=0, mode="fill", fill_value=0.0)
-    routed = jnp.zeros((n, d), jnp.float32).at[tok].add(y * wgt[:, None])
-    if shared_w:
-        sg_w, su_w, sd_w = shared_w
-        shared = (jax.nn.silu(xv @ sg_w) * (xv @ su_w)) @ sd_w
+    gids = jnp.where((local >= 0) & (local < held), local, held).astype(jnp.int32)
+    layout, gbuf, counts = _rows_layout(gids, held, k, block_rows, rows)
     load = counts.astype(jnp.float32)
     n_here = jnp.sum(load)
     stats = jnp.stack([n_here, jnp.max(load), jnp.mean(load),
                        jnp.maximum(n_here - rows, 0.0)])
-    load_all = jnp.zeros((E,), jnp.float32).at[flat].add(1.0)
+    # pairs of every expert, counted by comparison: a scatter-add of one a
+    # pair took a millisecond (my chip run, PR 34)
+    load_all = jnp.sum(flat[:, None] == jnp.arange(logits.shape[1], dtype=flat.dtype),
+                       axis=0, dtype=jnp.float32)
+    return topv, layout, gbuf, stats, load_all
+
+
+def _experts(xv, topv, layout, gbuf, wg, wu, wd, *shared_w, block_rows, backend):
+    """The held experts over a layout, and the shared expert (`shared_w`;
+    none for a layer without one): what `recompute=True` runs again."""
+    buf = rows_gather(xv, layout, block_rows=block_rows, backend=backend)
+    mm = functools.partial(grouped_matmul, gids=gbuf, block_rows=block_rows,
+                           backend=backend, aligned=True)
+    act = (jax.nn.silu(mm(buf, wg)) * mm(buf, wu)).astype(xv.dtype)
+    routed = rows_combine(mm(act, wd), topv, layout, block_rows=block_rows,
+                          backend=backend)
     if shared_w:
+        sg_w, su_w, sd_w = shared_w
+        shared = (jax.nn.silu(xv @ sg_w) * (xv @ su_w)) @ sd_w
         routed = routed + shared.astype(jnp.float32)
-    return routed.astype(xv.dtype), stats, load_all
+    return routed.astype(xv.dtype)
+
+
+def _held_moe(xv, logits, bias, wg, wu, wd, *shared_w, k, first, routing,
+              rows, block_rows, backend, recompute=False):
+    """The layer on plain arrays: xv [N, d], logits [N, E] float32, the
+    held experts' weights [G, ...] (experts first .. first + G - 1) and the
+    shared expert's three. The layout is built here, outside what
+    `recompute` runs twice. Returns (out [N, d], [pairs routed here, largest
+    and mean load of a held expert, pairs left out], load of ALL experts [E])."""
+    topv, layout, gbuf, stats, load_all = _layout(
+        logits, bias, k=k, first=first, held=wg.shape[0], routing=routing,
+        rows=rows, block_rows=block_rows)
+    experts = functools.partial(_experts, block_rows=block_rows, backend=backend)
+    if recompute:
+        experts = jax.checkpoint(experts)
+    return experts(xv, topv, layout, gbuf, wg, wu, wd, *shared_w), stats, load_all
 
 
 class HeldExpertsMoE(Layer):
@@ -149,17 +180,18 @@ class HeldExpertsMoE(Layer):
         pairs = x2.shape[0] * self.top_k
         rows, bm = held_rows(pairs, stop - first, self.num_expert,
                              self.block_rows)
+        buffer_rows = _round_up(rows, bm) + (stop - first) * bm
         # static a compiled program, as `last_resolution("kda")` is
         note_derived(Resolution("held_experts", {"rows": rows, "block_rows": bm},
                                 "caller" if self.block_rows else "default",
                                 "CAPACITY_FACTOR"),
-                     pairs=pairs, buffer_rows=_round_up(rows, bm) + (stop - first) * bm)
+                     pairs=pairs, buffer_rows=buffer_rows,
+                     rows_backend=rows_backend(self.backend, *x2.shape, self.top_k,
+                                               x2.dtype, buffer_rows, rows))
         fn = functools.partial(
             _held_moe, k=self.top_k, first=first,
             routing=self.gate.routing_config(self.training), rows=rows,
-            block_rows=bm, backend=self.backend)
-        if self.recompute:
-            fn = jax.checkpoint(fn)
+            block_rows=bm, backend=self.backend, recompute=self.recompute)
         out, stats, load = apply_op(
             fn, x2, self.gate(x2), self.gate.e_score_correction_bias,
             self.w_gate, self.w_up, self.w_down,

@@ -440,10 +440,10 @@ def _kernel_cases():
     import jax
     import jax.numpy as jnp
 
-    fa, ce, pa, gm, rk, kda = (
+    fa, ce, pa, gm, rk, kda, mr = (
         importlib.import_module("paddle_tpu.ops.pallas." + m)
         for m in ("flash_attention", "fused_ce", "paged_attention",
-                  "grouped_matmul", "rmsnorm_kernel", "kda"))
+                  "grouped_matmul", "rmsnorm_kernel", "kda", "moe_rows"))
     f32, i32 = jnp.float32, jnp.int32
 
     def flash(q):
@@ -458,6 +458,23 @@ def _kernel_cases():
     def paged_count(lens):
         with pa.force_interpret():
             return pa.page_visit_counts(lens, 16, 4)
+
+    # 32 tokens x 4 pairs, the first 16 pairs in the first 16 of 32 buffer rows
+    pairs = jnp.arange(128, dtype=i32)
+    layout = mr.rows_layout(jnp.where(jnp.arange(32) < 16, jnp.arange(32), 128).astype(i32),
+                            jnp.where(pairs < 16, pairs, 32).reshape(32, 4), 1, 16)
+
+    def movers(x, y, w):
+        with fa.force_interpret():
+            return jax.grad(lambda x, y, w: (
+                mr.rows_gather(x, layout, block_rows=16).sum()
+                + mr.rows_combine(y, w, layout, block_rows=16).sum()),
+                argnums=(0, 1, 2))(x, y, w)
+
+    def mover_counts():
+        with fa.force_interpret():
+            return (mr.rows_gather_visit_counts(layout, 16),
+                    mr.rows_combine_visit_counts(layout))
 
     lab = jnp.zeros((32,), i32)
     gids = jnp.repeat(jnp.arange(2, dtype=i32), 16)
@@ -498,6 +515,14 @@ def _kernel_cases():
                 q, q, q, g, b, interpret=True).sum())(q),
             (jnp.ones((1, 64, 2, 128), f32), -jnp.ones((1, 64, 2, 128), f32),
              jnp.ones((1, 64, 2), f32) * 0.5), ["kda_fwd", "kda_fwd", "kda_bwd"]),
+        "moe_rows": (
+            movers, (jnp.ones((32, 128), f32), jnp.ones((32, 128), f32),
+                     jnp.ones((32, 4), f32)),
+            ["moe_rows_gather", "moe_rows_combine", "moe_rows_gather",
+             "moe_rows_combine"]),
+        "moe_rows_block_count": (
+            mover_counts, (),
+            ["moe_rows_gather_block_count", "moe_rows_combine_block_count"]),
     }
 
 
@@ -505,7 +530,7 @@ class TestKernelNames:
     @pytest.mark.parametrize("case", [
         "flash_attention", "flash_block_count", "fused_ce", "paged_decode",
         "paged_block_count", "rmsnorm", "grouped_matmul",
-        "grouped_matmul_block_count", "kda"])
+        "grouped_matmul_block_count", "kda", "moe_rows", "moe_rows_block_count"])
     def test_every_pallas_call_is_named(self, case):
         fn, args, want = _kernel_cases()[case]
         found = _pallas_names(fn, *args)
@@ -524,7 +549,7 @@ class TestKernelNames:
                 src = open(os.path.join(root, f)).read()
                 calls += len(re.findall(r"pl\.pallas_call\(", src))
                 names += len(re.findall(r"\*\*_compat\.kernel_name\(", src))
-        assert calls == names == 14       # PR 27: kda_fwd, kda_bwd
+        assert calls == names == 17       # PR 34: the two row movers, their counter
 
 
 @contextlib.contextmanager

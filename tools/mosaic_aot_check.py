@@ -166,10 +166,11 @@ def _kimi_linear_cases():
 
     def experts_grad(x, logits, bias, *w):
         # the whole layer as the step runs it: route over 256, lay out the
-        # pairs of the 8 held experts, three grouped products, the shared expert
+        # pairs of the 8 held experts, the two row movers around three
+        # grouped products, the shared expert
         return jax.grad(lambda a, *b: _held_moe(
             a, logits, bias, *b, k=8, first=0, routing=routing, rows=laid_out,
-            block_rows=bm, backend="pallas")[0].astype(f32).sum(),
+            block_rows=bm, backend="pallas", recompute=True)[0].astype(f32).sum(),
             argnums=tuple(range(7)))(x, *w)
 
     head = [((rows, s, h, 128), bf)] * 3
@@ -215,7 +216,7 @@ def _lfm2_moe_cases():
     def experts_grad(x, logits, bias, *w):
         return jax.grad(lambda a, *b: _held_moe(
             a, logits, bias, *b, k=4, first=0, routing=routing, rows=laid_out,
-            block_rows=bm, backend="pallas")[0].astype(f32).sum(),
+            block_rows=bm, backend="pallas", recompute=True)[0].astype(f32).sum(),
             argnums=tuple(range(4)))(x, *w)
 
     return {
