@@ -18,6 +18,8 @@ import sys
 import threading
 import time
 
+from benchmark import reduce
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
@@ -145,7 +147,14 @@ class CompileMeter:
 class Profile:
     """The profiler over one slice of the measured window: from 40% of it,
     for `trace_s` seconds at most. `poll(now)` is called from the loop that
-    drives the window; the xplane is reduced once the window has closed."""
+    drives the window; the xplane is reduced once the window has closed.
+
+    The slice is what lies inside the `bench.slice` span, opened once the
+    profiler runs and closed when `poll` decides to stop, BEFORE the profiler
+    is stopped: its two ends are on the trace's own clock, and `reduce.py`
+    clips every device event to them. The host's clock keeps `t_begin` and
+    `host_window_s` for the readers that compare the slice with host-clock
+    records of the program; nothing divides by them."""
 
     def __init__(self, on: bool, t0: float, seconds: float, trace_s: float):
         self.on = on
@@ -153,7 +162,7 @@ class Profile:
         self.end = self.begin + min(trace_s, 0.5 * seconds)
         self.dir = os.path.join(ROOT, ".bench_trace")
         self.state = "before" if on else "done"
-        self.window_s = None
+        self.host_window_s = None
         self.t_begin = None
 
     def poll(self, now: float):
@@ -165,12 +174,15 @@ class Profile:
             opts.python_tracer_level = 0
             opts.host_tracer_level = 2
             jax.profiler.start_trace(self.dir, profiler_options=opts)
-            self._t = self.t_begin = time.perf_counter()
+            self._slice = annotate(reduce.SLICE_SPAN)
+            self._slice.__enter__()
+            self.t_begin = time.perf_counter()
             self.state = "running"
         elif self.state == "running" and now >= self.end:
-            # collecting a trace takes seconds: off the thread that offers load
-            self.window_s = time.perf_counter() - self._t
+            self._slice.__exit__(None, None, None)
+            self.host_window_s = time.perf_counter() - self.t_begin
             self.state = "stopping"
+            # collecting a trace takes seconds: off the thread that offers load
             self._stopper = threading.Thread(target=jax.profiler.stop_trace,
                                              name="bench.stop_trace")
             self._stopper.start()
@@ -186,10 +198,8 @@ class Profile:
 
     def reduce(self, n_devices: int):
         """The reduced trace (see reduce.py), or None without one."""
-        if self.window_s is None:
+        if self.host_window_s is None:
             return None
-        from benchmark import reduce
-
         try:
             return reduce.reduce_dir(self.dir, n_devices)
         finally:
@@ -256,16 +266,18 @@ def interpret_kernels(on: bool):
         yield
 
 
-def dump_trace(path: str, reduced: dict, keep_s: float = 0.7):
+def dump_trace(path: str, reduced: dict, keep_s: float | None = 0.7):
     """For looking at a trace by hand and for recording the small trace the
-    tests keep: plane and line names, and the events of the first `keep_s`
-    seconds after the first device operation."""
+    tests keep: plane and line names, and the events that start in the first
+    `keep_s` seconds after the first device operation; with `keep_s` None
+    (`BENCH_DUMP_TRACE_S=all`) every event, the `bench.slice` span with them:
+    a whole slice as `reduce_events` takes it."""
     import gzip
 
     events = reduced["events"]
     starts = [e[1] for d in events["devices"].values() for e in d["ops"]]
     lo = min(starts) if starts else 0.0
-    hi = lo + keep_s * 1e9
+    lo, hi = (lo, lo + keep_s * 1e9) if keep_s is not None else (float("-inf"), float("inf"))
 
     def cut(evs):
         return [e for e in evs if lo <= e[1] < hi]

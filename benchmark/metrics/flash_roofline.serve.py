@@ -17,7 +17,8 @@ def read(run):
     if not spent:
         return None
     cfg, peak = run["cell"]["model"], roofline.peaks(run["device"]["kind"])
-    lo, hi = run["trace_t0"], run["trace_t0"] + run["trace_window_s"]
+    # the program's records are on the host's clock, and so is this interval
+    lo, hi = run["trace_t0"], run["trace_t0"] + run["trace_host_window_s"]
     least = sum(roofline.least_seconds(*roofline.flash_fwd(cfg, 1, n), peak)[0]
                 for t, n in run["admitted"] if lo <= t <= hi)
     if not least:

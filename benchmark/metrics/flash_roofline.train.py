@@ -13,7 +13,7 @@ def read(run):
     cfg = run["cell"]["model"]
     shards = (run["cell"].get("mesh") or {}).get("mp", 1)
     spent = reduce.pallas_seconds(trace, lacks=reduce.dims(cfg["hidden_size"], cfg["vocab_size"] // shards))
-    steps = reduce.main_module_runs(trace)
+    steps = reduce.steps_measured(trace)
     if not spent or not steps:
         return None
     cell, peak = run["cell"], roofline.peaks(run["device"]["kind"])

@@ -20,7 +20,8 @@ def read(run):
     if not spent:
         return None
     cfg, peak = run["cell"]["model"], roofline.peaks(run["device"]["kind"])
-    lo, hi = run["trace_t0"], run["trace_t0"] + run["trace_window_s"]
+    # the program's records are on the host's clock, and so is this interval
+    lo, hi = run["trace_t0"], run["trace_t0"] + run["trace_host_window_s"]
     context = batch = 0
     for rec in run["records"]:
         n0 = len(rec["prompt"])

@@ -33,19 +33,21 @@ def kernel_seconds(reduced: dict, *names: str) -> float:
 
 
 def host_seconds(reduced: dict, *names: str) -> float | None:
-    """Seconds of the host spans called any of `names` in the traced window,
-    or None where the program wrote no such span."""
-    durs = [d for n, _, d in reduced["events"]["host"] if n in names]
-    return sum(durs) / 1e9 if durs else None
+    """Seconds of the host spans called any of `names` inside the traced
+    slice, or None where the program wrote no such span."""
+    lo, hi = reduced["interval_ns"]
+    durs = [min(s + d, hi) - max(s, lo) for n, s, d in reduced["events"]["host"] if n in names]
+    return sum(d for d in durs if d > 0) / 1e9 if durs else None
 
 
 def host_ms_per_step(run: dict, *names: str) -> float | None:
     """Milliseconds a step of the host spans called `names`, over the steps
-    the device ran in the traced window; None without such spans."""
+    the device ran in the traced slice (`reduce.steps_measured`); None
+    without such spans or without a whole step in the slice."""
     trace = run.get("trace")
     if not trace:
         return None
-    spent, steps = host_seconds(trace, *names), reduce.main_module_runs(trace)
+    spent, steps = host_seconds(trace, *names), reduce.steps_measured(trace)
     if spent is None or not steps:
         return None
     return 1e3 * spent / steps
@@ -53,12 +55,13 @@ def host_ms_per_step(run: dict, *names: str) -> float | None:
 
 def roofline_share(run: dict, least_per_step: float, *names: str) -> float | None:
     """100 x (least seconds for the steps traced) / (device seconds of the
-    kernels called `names`); None where nothing is so called."""
+    kernels called `names`), steps and seconds both of what ran inside the
+    slice; None where nothing is so called or the slice holds no whole step."""
     trace = run.get("trace")
     if not trace:
         return None
     spent = kernel_seconds(trace, *names)
-    steps = reduce.main_module_runs(trace)
+    steps = reduce.steps_measured(trace)
     if not spent or not steps:
         return None
     return 100.0 * steps * least_per_step / spent

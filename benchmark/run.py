@@ -35,7 +35,7 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearsal", action="store_true")
     args = ap.parse_args(argv)
 
-    from benchmark import harness
+    from benchmark import harness, reduce
 
     manifest = harness.load_manifest()
     if args.workload not in [w["name"] for w in manifest["workloads"]]:
@@ -60,17 +60,24 @@ def main(argv=None) -> int:
               "failed": out["failed"], "metrics": None, "device": dev}
     if args.trace:
         reduced = out["profile"].reduce(cell["chips"])
-        run = dict(out["run"], trace=reduced, trace_window_s=out["profile"].window_s,
-                   trace_t0=out["profile"].t_begin, rehearsal=args.rehearsal)
+        # the slice on the trace's own clock; its start and length on the host's
+        # for the readers that hold it against the program's host-clock records
+        run = dict(out["run"], trace=reduced, trace_window_s=reduced and reduced["window_s"],
+                   trace_t0=out["profile"].t_begin,
+                   trace_host_window_s=out["profile"].host_window_s, rehearsal=args.rehearsal)
         values = harness.read_per_layer([m["name"] for m in metrics], run)
         if reduced is not None:
             dev["busy_s"] = reduced["busy_s"]
-            dev["window_s"] = out["profile"].window_s
+            dev["window_s"] = reduced["window_s"]
+            harness.log(f"slice on the trace's clock {reduced['window_s']:.9f}s, busy "
+                        f"{reduced['busy_s']:.9f}s, {reduce.main_module_runs(reduced):.4f} steps; "
+                        f"on the host's clock {out['profile'].host_window_s:.9f}s")
             result["breakdown"] = {"device_ops": reduced["device_ops"],
                                    "idle_gaps": reduced["idle_gaps"]}
             dump = os.environ.get("BENCH_DUMP_TRACE")
             if dump:
-                harness.dump_trace(dump, reduced)
+                keep = os.environ.get("BENCH_DUMP_TRACE_S", "0.7")
+                harness.dump_trace(dump, reduced, None if keep == "all" else float(keep))
     else:
         values = out["end_to_end"]
     result["metrics"] = harness.with_units(values, metrics)

@@ -24,6 +24,7 @@ MANIFEST = harness.load_manifest()
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
 # every cell file, in the manifest or kept for a later PR (PERF.md, Open questions)
 CELL_FILES = sorted(f[:-5] for f in os.listdir(os.path.join(harness.HERE, "workloads")))
+REHEARSED = set(harness.load_json("rehearsal.json")["cell"])   # kinds with tiny sizes
 KIND_CELLS: dict = {}                     # the first one-chip cell of each kind
 for _c in CELLS + CELL_FILES:
     if harness.load_cell(_c)["chips"] == 1:
@@ -35,11 +36,15 @@ for _c in CELLS + CELL_FILES:
 @pytest.mark.parametrize("name", CELL_FILES)
 def test_every_cell_file_loads(name):
     cell = harness.load_cell(name)
-    assert cell["kind"] in ("train", "serve") and cell["chips"] in (1, 4)
+    assert cell["kind"] in ("train", "train_arch", "serve") and cell["chips"] in (1, 4)
     assert os.path.exists(os.path.join(harness.HERE, "kinds", f"{cell['kind']}.py"))
-    assert cell["mix"]["family"] == {"train": "token_stream", "serve": "requests"}[cell["kind"]]
+    assert cell["mix"]["family"] == {"train": "token_stream", "train_arch": "token_stream",
+                                     "serve": "requests"}[cell["kind"]]
     assert cell["model"]["hidden_size"] and "compiles_in_window" in cell["limits"]
-    assert harness.load_cell(name, rehearsal=True)["model"]["hidden_size"] == 64
+    if cell["kind"] in REHEARSED:         # an architecture's kind has its own CPU tests
+        assert harness.load_cell(name, rehearsal=True)["model"]["hidden_size"] == 64
+    else:
+        assert os.path.isdir(os.path.join(harness.HERE, "arch", cell["model"]["arch"]))
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -117,8 +122,8 @@ def test_widths_are_the_published_ones():
 
 def test_a_reader_that_finds_nothing_returns_nothing():
     run = {"cell": harness.load_cell(KIND_CELLS["train"]), "device": {"kind": "TPU v5 lite"},
-           "trace": None, "spans": [], "queue_waits": [], "records": []}
-    out = harness.read_per_layer(["flash_roofline.train", "fused_ce_roofline",
+           "trace": None, "spans": [], "queue_waits": [], "records": [], "tokens_per_step": 12288}
+    out = harness.read_per_layer(["flash_roofline.train", "ce_stats_roofline",
                                   "device_idle_pct.train", "engine_decode_step_ms"], run)
     assert out == {}
 
@@ -207,7 +212,11 @@ def test_reduce_arithmetic_on_hand_made_events():
     assert r["exposed_collective_s"] == pytest.approx(2.0)   # [4,6]
     assert reduce.pallas_seconds(r, has=reduce.dims(8, 4096, 16, 128)) == pytest.approx(1.0)
     assert reduce.pallas_seconds(r, lacks=reduce.dims(8, 4096, 16, 128)) == 0
-    assert reduce.main_module_runs(r) == 2
+    # no `bench.slice` span: the slice is the events' extent, [0, 10], both
+    # runs of the program touch an end, and 8 s of it over the longer is no count
+    assert r["window_s"] == pytest.approx(10.0)
+    assert reduce.main_module_runs(r) == pytest.approx(8 / 6)
+    assert reduce.steps_measured(r) is None
     assert r["idle_gaps"][0] == ["train.read_loss", pytest.approx(2.0)]
     assert r["device_ops"][0][0] == "fusion.1"
 
@@ -284,10 +293,11 @@ class _Meter:
 _METER = _Meter()
 
 
-@pytest.mark.parametrize("kind", sorted({harness.load_cell(c)["kind"] for c in CELLS}))
+@pytest.mark.parametrize("kind", sorted({harness.load_cell(c)["kind"] for c in CELLS} & REHEARSED))
 def test_rehearsal_last_line_has_the_contracts_keys(kind):
-    """run.py end to end, for each kind that has a cell in the manifest (a
-    kind without one is driven in-process by the control tests below)."""
+    """run.py end to end, for each kind that has a cell in the manifest and
+    tiny sizes in `rehearsal.json` (a kind without a cell is driven in-process
+    by the control tests below; `train_arch` by `test_train_arch.py`)."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     for trace in ("0", "1"):
         p = subprocess.run([sys.executable, os.path.join(harness.HERE, "run.py"), "--workload",
@@ -304,6 +314,8 @@ def test_rehearsal_last_line_has_the_contracts_keys(kind):
             assert "setup_s" in line["metrics"] and len(line["metrics"]) >= 2
         else:
             assert {"busy_s", "window_s"} <= set(line["device"]) and "breakdown" in line
+            # the CPU has no device plane: nothing busy, and the slice's span is there
+            assert 0 == line["device"]["busy_s"] < line["device"]["window_s"] < 2
             assert "compile_s" in line["metrics"]
         assert p.stderr.strip().splitlines()[-1].startswith("check ")
 
