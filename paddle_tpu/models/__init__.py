@@ -19,3 +19,7 @@ from paddle_tpu.models.kimi_linear import (  # noqa: F401
 from paddle_tpu.models.lfm2_moe import (  # noqa: F401
     Lfm2MoeConfig, Lfm2MoeForCausalLM, Lfm2MoeModel, lfm2_moe_tiny_config,
 )
+from paddle_tpu.models.deepseek_v3 import (  # noqa: F401
+    DeepseekV3Config, DeepseekV3ForCausalLM, DeepseekV3Model,
+    deepseek_v3_tiny_config,
+)

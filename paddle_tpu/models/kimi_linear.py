@@ -5,7 +5,8 @@ feed-forward part. The mixer is Kimi Delta Attention (KDA: gated delta-rule
 linear attention, `ops/pallas/kda.py`) in three layers of four and
 multi-head latent attention WITHOUT positions (NoPE MLA: keys and values
 expanded from one 512-wide latent, query/key width 192 beside value width
-128, through the flash kernels) in the fourth. The feed-forward part is a
+128, through the flash kernels) in the fourth (`LatentAttention`, which
+`deepseek_v3.py` shares and there rotates). The feed-forward part is a
 dense SwiGLU MLP in the first `first_k_dense_replace` layers and sigmoid-
 routed SwiGLU experts with a shared expert after them
 (`incubate.distributed.models.moe.HeldExpertsMoE`; `num_experts` of the
@@ -27,7 +28,7 @@ import jax.numpy as jnp
 
 import paddle_tpu.nn as nn
 import paddle_tpu.nn.functional as F
-from paddle_tpu.core.tensor import apply_op
+from paddle_tpu.core.tensor import Tensor, apply_op
 from paddle_tpu.incubate.distributed.models.moe import HeldExpertsMoE
 from paddle_tpu.nn import initializer as I
 
@@ -52,8 +53,10 @@ class KimiLinearConfig:
     num_attention_heads: int = 32
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
-    qk_rope_head_dim: int = 64          # NoPE: carried, never rotated
+    qk_rope_head_dim: int = 64          # carried; rotated unless mla_use_nope
     v_head_dim: int = 128
+    mla_use_nope: bool = True           # the published models never rotate
+    rope_theta: float = 10000.0
     linear_attn_config: dict = field(default_factory=_default_linear_attn)
     low_rank_gate_dim: int = 0          # 0: the linear-attention head_dim
     first_k_dense_replace: int = 1
@@ -197,16 +200,51 @@ class KimiDeltaAttention(_Block):
                         name="kda_attention")
 
 
-class LatentAttention(_Block):
-    """NoPE multi-head latent attention: x + W_o softmax(q k^T / sqrt(192)) v
-    with k = [k_nope | k_r], k_r one 64-wide key all heads share."""
+def _kernel_outputs(prim, *_, **__) -> bool:
+    """A recomputation policy: keep what a Pallas kernel wrote (flash's output
+    and softmax statistics), make everything else again."""
+    return prim.name == "pallas_call"
 
-    def __init__(self, config: KimiLinearConfig):
+
+def rotate_pairs(x, theta):
+    """x [B, T, ..., 2n]: the pair (2i, 2i + 1) of position t turned by
+    t * theta^(-2i / 2n), in float32, the turned pairs laid out [even | odd].
+    The order of the channels is a permutation that a query and its key
+    share: their product is the interleaved layout's."""
+    n = x.shape[-1] // 2
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], n, 2)
+    x0, x1 = xf[..., 0], xf[..., 1]
+    t = jnp.arange(x.shape[1], dtype=jnp.float32).reshape(1, -1, *(1,) * (x.ndim - 3), 1)
+    angle = t * theta ** (-jnp.arange(n, dtype=jnp.float32) / n)
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    return jnp.concatenate([x0 * cos - x1 * sin, x1 * cos + x0 * sin], -1).astype(x.dtype)
+
+
+class LatentAttention(_Block):
+    """Multi-head latent attention: x + W_o softmax(q k^T / sqrt(192)) v with
+    k = [k_nope | k_r], k_r one 64-wide key all heads share. `rope_theta`
+    turns the 64 channels of q and k_r beside the latent's by position
+    (`rotate_pairs`); None carries them as they are (Kimi-Linear under
+    `mla_use_nope`). The model that stacks the layer reads both from its own
+    configuration; `config` gives the family's published widths
+    (`KimiLinearConfig`, `DeepseekV3Config`).
+
+    `keep_qkv` says what a layer keeps for its backward pass beside its input
+    (under `recompute`): flash's q, k and v as well as its output and softmax
+    statistics (True), or the output and statistics alone, q, k and v built
+    again from the input (False: the concatenations, the broadcast and
+    flash's head-major copies a second time, for heads x 512 values a token
+    less kept). The model that stacks the layers chooses: only it knows how
+    many of them share the chip (PERF.md section 6, PR 37, has both costs)."""
+
+    def __init__(self, config, rope_theta: float | None, keep_qkv: bool = True):
         super().__init__()
+        self.keep_qkv = keep_qkv
         h, self.heads = config.hidden_size, config.num_attention_heads
         self.nope, self.rope = config.qk_nope_head_dim, config.qk_rope_head_dim
         self.vd, self.rank = config.v_head_dim, config.kv_lora_rank
         self.eps, self.recompute = config.rms_norm_eps, config.recompute
+        self.theta = None if rope_theta is None else float(rope_theta)
         self.input_norm = self._vec(h)
         self.wq = self._mat(h, self.heads * (self.nope + self.rope))
         self.w_kva = self._mat(h, self.rank + self.rope)
@@ -215,8 +253,10 @@ class LatentAttention(_Block):
         self.wo = self._mat(self.heads * self.vd, h)
 
     def forward(self, x):
-        heads, nope, rope, vd, rank, eps = (self.heads, self.nope, self.rope,
-                                            self.vd, self.rank, self.eps)
+        from paddle_tpu.tuning.blocks import Resolution, last_resolution, note_derived
+
+        heads, nope, rope, vd, rank, eps, theta = (
+            self.heads, self.nope, self.rope, self.vd, self.rank, self.eps, self.theta)
 
         def qkv(x, norm, wq, wkva, kvnorm, wkvb):
             b, t, _ = x.shape
@@ -225,13 +265,36 @@ class LatentAttention(_Block):
             kva = y @ wkva
             kv = (rms_norm(kva[..., :rank], kvnorm, eps) @ wkvb).reshape(
                 b, t, heads, nope + vd)
-            k_r = jnp.broadcast_to(kva[:, :, None, rank:], (b, t, heads, rope))
+            k_r = kva[:, :, None, rank:]
+            if theta is not None:
+                with jax.named_scope("mla_rope"):
+                    q = jnp.concatenate([q[..., :nope], rotate_pairs(q[..., nope:], theta)], -1)
+                    k_r = rotate_pairs(k_r, theta)
+            k_r = jnp.broadcast_to(k_r, (b, t, heads, rope))
             return q, jnp.concatenate([kv[..., :nope], k_r], -1), kv[..., nope:]
 
-        q, k, v = apply_op(self._fn(qkv), x, self.input_norm, self.wq, self.w_kva,
-                           self.kv_norm, self.w_kvb, name="mla_qkv", n_outputs=3)
-        o = F.scaled_dot_product_attention(q, k, v, is_causal=True,
-                                           training=self.training)
+        def attend(*a):
+            q, k, v = (Tensor(z) for z in qkv(*a))
+            return F.scaled_dot_product_attention(q, k, v, is_causal=True,
+                                                  training=self.training)._value
+
+        before = last_resolution("flash_fwd")
+        args = (x, self.input_norm, self.wq, self.w_kva, self.kv_norm, self.w_kvb)
+        if self.keep_qkv:
+            q, k, v = apply_op(self._fn(qkv), *args, name="mla_qkv", n_outputs=3)
+            o = F.scaled_dot_product_attention(q, k, v, is_causal=True,
+                                               training=self.training)
+        else:
+            o = apply_op(self._fn(attend, _kernel_outputs), *args, name="mla_attention")
+        flash = last_resolution("flash_fwd")
+        # static a compiled program, as `last_resolution("kda")` is
+        note_derived(Resolution("latent_attention",
+                                {"heads": heads, "qk_nope": nope, "qk_rope": rope,
+                                 "v": vd, "latent": rank},
+                                "config", "rope_theta"),
+                     rotated_channels=rope if theta is not None else 0,
+                     rope_theta=theta,
+                     flash_blocks=dict(flash.values) if flash is not before else None)
         return apply_op(lambda x, o, wo: x + o.reshape(*x.shape[:2], -1) @ wo,
                         x, o, self.wo, name="mla_out")
 
@@ -281,7 +344,8 @@ class KimiLinearLayer(nn.Layer):
     def __init__(self, config: KimiLinearConfig, number: int):
         super().__init__()
         kda = number in config.linear_attn_config["kda_layers"]
-        self.mixer = KimiDeltaAttention(config) if kda else LatentAttention(config)
+        self.mixer = KimiDeltaAttention(config) if kda else LatentAttention(
+            config, None if config.mla_use_nope else config.rope_theta)
         dense = number <= config.first_k_dense_replace
         self.mlp = DenseMLP(config) if dense else ExpertMLP(config)
 

@@ -128,7 +128,20 @@ def _cases(extra: bool):
     }
     off_path.update(_kimi_linear_cases())
     off_path.update(_lfm2_moe_cases())
+    off_path.update(_moonlight_cases())
     return dense, off_path
+
+
+def _flash_grad(q, k, v):
+    """Causal flash forward and backward by q, k and v, [B, S, H, D]."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_bshd
+
+    return jax.grad(lambda *a: flash_attention_bshd(
+        *a, causal=True, interpret=False).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))(q, k, v)
 
 
 def _kimi_linear_cases():
@@ -140,7 +153,6 @@ def _kimi_linear_cases():
 
     from paddle_tpu.incubate.distributed.models.moe.held_experts import (
         _held_moe, held_rows)
-    from paddle_tpu.ops.pallas.flash_attention import flash_attention_bshd
     from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
     from paddle_tpu.ops.pallas.kda import kda_chunked
 
@@ -150,11 +162,6 @@ def _kimi_linear_cases():
     def kda_grad(q, k, v, g, beta):
         return jax.grad(lambda *a: kda_chunked(*a, interpret=False).astype(f32).sum(),
                         argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
-
-    def mla_grad(q, k, v):
-        return jax.grad(lambda *a: flash_attention_bshd(
-            *a, causal=True, interpret=False).astype(f32).sum(),
-            argnums=(0, 1, 2))(q, k, v)
 
     def gmm_grad(x, w, g):
         return jax.grad(lambda a, b: grouped_matmul(
@@ -178,7 +185,7 @@ def _kimi_linear_cases():
         "KDA scan fwd+bwd 2 x 8192 x 32 heads x 128": (
             kda_grad, head + [((rows, s, h, 128), f32), ((rows, s, h), f32)]),
         "flash fwd+bwd seq 8192, q/k 192, v 128": (
-            mla_grad, [((rows, s, h, 192), bf)] * 2 + [((rows, s, h, 128), bf)]),
+            _flash_grad, [((rows, s, h, 192), bf)] * 2 + [((rows, s, h, 128), bf)]),
         "grouped matmul fwd+dx+dw 5120 x 2304 -> 8 x [2304,1024]": (
             gmm_grad, [((5120, hid), bf), ((8, hid, inter), bf), ((5120,), i32)]),
         "grouped matmul fwd+dx+dw 5120 x 1024 -> 8 x [1024,2304]": (
@@ -200,15 +207,9 @@ def _lfm2_moe_cases():
 
     from paddle_tpu.incubate.distributed.models.moe.held_experts import (
         _held_moe, held_rows)
-    from paddle_tpu.ops.pallas.flash_attention import flash_attention_bshd
 
     bf, f32 = jnp.bfloat16, jnp.float32
     rows, s, hid, inter = 3, 8192, 2048, 1536
-
-    def gqa_grad(q, k, v):
-        return jax.grad(lambda *a: flash_attention_bshd(
-            *a, causal=True, interpret=False).astype(f32).sum(),
-            argnums=(0, 1, 2))(q, k, v)
 
     laid_out, bm = held_rows(rows * s * 4, 8, 64)
     routing = (("kind", "sigmoid"), ("renormalize", True), ("renorm_eps", 1e-6))
@@ -221,11 +222,64 @@ def _lfm2_moe_cases():
 
     return {
         "flash fwd+bwd 3 x 8192, 32q / 8kv x 64": (
-            gqa_grad, [((rows, s, 32, 64), bf)] + [((rows, s, 8, 64), bf)] * 2),
+            _flash_grad, [((rows, s, 32, 64), bf)] + [((rows, s, 8, 64), bf)] * 2),
         "held experts layer fwd+bwd 24576 tokens x 8 of 64, no shared expert": (
             experts_grad,
             [((rows * s, hid), bf), ((rows * s, 64), f32), ((64,), f32),
              ((8, hid, inter), bf), ((8, hid, inter), bf), ((8, inter, hid), bf)]),
+    }
+
+
+def _moonlight_cases():
+    """The kernels of `train_moonlight_seq8k` at its shapes: 3 rows x 8192,
+    16 heads of query/key 192 beside value 128, the grouped products at
+    [2048, 1408] over the rows laid out for 18,432 pairs, and the whole expert
+    layer at SIX experts a token (the two row movers at a `k` that is no power
+    of two) with the two shared experts as one SwiGLU of 2816."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.incubate.distributed.models.moe.held_experts import (
+        _held_moe, held_rows)
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+    from paddle_tpu.ops.pallas.moe_rows import rows_backend
+
+    bf, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
+    rows, s, heads, hid, inter, k = 3, 8192, 16, 2048, 1408, 6
+
+    laid_out, bm = held_rows(rows * s * k, 8, 64)
+    buf = laid_out + 8 * bm
+    # `xla` at these sizes: the movers' index arrays (3 x 73,728 pairs) pass
+    # the scalar memory they may take, so the layout is XLA's gathers and
+    # scatters and the layer compiles to 8 kernel calls, not 11 (PERF.md)
+    movers = rows_backend("pallas", rows * s, hid, k, bf, buf, laid_out)
+
+    def gmm_grad(x, w, g):
+        return jax.grad(lambda a, b: grouped_matmul(
+            a, b, g, block_rows=bm, backend="pallas", aligned=True).sum(),
+            argnums=(0, 1))(x, w)
+
+    routing = (("kind", "sigmoid"), ("routed_scale", 2.446), ("renormalize", True),
+               ("renorm_eps", 1e-20))
+
+    def experts_grad(x, logits, bias, *w):
+        return jax.grad(lambda a, *b: _held_moe(
+            a, logits, bias, *b, k=k, first=0, routing=routing, rows=laid_out,
+            block_rows=bm, backend="pallas", recompute=True)[0].astype(f32).sum(),
+            argnums=tuple(range(7)))(x, *w)
+
+    return {
+        "flash fwd+bwd 3 x 8192 x 16 heads, q/k 192, v 128": (
+            _flash_grad, [((rows, s, heads, 192), bf)] * 2 + [((rows, s, heads, 128), bf)]),
+        f"grouped matmul fwd+dx+dw {buf} x 2048 -> 8 x [2048,1408]": (
+            gmm_grad, [((buf, hid), bf), ((8, hid, inter), bf), ((buf,), i32)]),
+        f"grouped matmul fwd+dx+dw {buf} x 1408 -> 8 x [1408,2048]": (
+            gmm_grad, [((buf, inter), bf), ((8, inter, hid), bf), ((buf,), i32)]),
+        f"held experts layer fwd+bwd 24576 tokens x 6 a token, 8 of 64, two shared (row movers: {movers})": (
+            experts_grad,
+            [((rows * s, hid), bf), ((rows * s, 64), f32), ((64,), f32),
+             ((8, hid, inter), bf), ((8, hid, inter), bf), ((8, inter, hid), bf),
+             ((hid, 2 * inter), bf), ((hid, 2 * inter), bf), ((2 * inter, hid), bf)]),
     }
 
 
