@@ -38,7 +38,7 @@ from paddle_tpu.incubate.distributed.models.moe.moe_layer import (
     SigmoidGate, _route)
 from paddle_tpu.nn import initializer as I
 from paddle_tpu.nn.layer.layers import Layer
-from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul, pick_block_rows
+from paddle_tpu.ops.pallas.grouped_matmul import col_tiles, grouped_matmul, pick_block_rows
 from paddle_tpu.ops.pallas.moe_rows import (
     rows_backend, rows_combine, rows_gather, rows_layout)
 
@@ -181,13 +181,16 @@ class HeldExpertsMoE(Layer):
         rows, bm = held_rows(pairs, stop - first, self.num_expert,
                              self.block_rows)
         buffer_rows = _round_up(rows, bm) + (stop - first) * bm
+        d, h = self.w_gate.shape[1:]
         # static a compiled program, as `last_resolution("kda")` is
         note_derived(Resolution("held_experts", {"rows": rows, "block_rows": bm},
                                 "caller" if self.block_rows else "default",
                                 "CAPACITY_FACTOR"),
                      pairs=pairs, buffer_rows=buffer_rows,
                      rows_backend=rows_backend(self.backend, *x2.shape, self.top_k,
-                                               x2.dtype, buffer_rows, rows))
+                                               x2.dtype, buffer_rows, rows),
+                     col_tiles={"gate_up": col_tiles(bm, d, h, x2.dtype, self.w_gate.dtype),
+                                "down": col_tiles(bm, h, d, x2.dtype, self.w_down.dtype)})
         fn = functools.partial(
             _held_moe, k=self.top_k, first=first,
             routing=self.gate.routing_config(self.training), rows=rows,

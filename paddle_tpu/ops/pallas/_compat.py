@@ -1,12 +1,13 @@
 """Shared Pallas-kernel helpers: the one platform probe, the x64 trace
-override, and the shard_map wrap Mosaic kernels need under a GSPMD mesh."""
+override, the VMEM a kernel may ask for, and the shard_map wrap Mosaic
+kernels need under a GSPMD mesh."""
 from __future__ import annotations
 
 import contextlib
 
 import jax
 
-__all__ = ["on_tpu", "x64_off", "kernel_trace_ctx", "kernel_name",
+__all__ = ["on_tpu", "lanes", "vmem_budget", "x64_off", "kernel_trace_ctx", "kernel_name",
            "DATA_AXES", "mesh_axes_dividing", "gspmd_mesh"]
 
 # the mesh axes a batch dim is sharded over (io.device_feed.default_batch_spec)
@@ -19,6 +20,29 @@ def on_tpu() -> bool:
     that fails to start raises here: turning that into "not a TPU" would
     silently select interpret mode on a machine that has a chip."""
     return jax.devices()[0].platform == "tpu"
+
+
+def lanes(n: int) -> int:
+    """n rounded up to whole 128-lane rows: what a trailing dim of n takes
+    in VMEM."""
+    return -(-n // 128) * 128
+
+
+_V5E_VMEM = 128 * 2**20     # one v5e TensorCore's, the chip every cell runs on
+
+
+def vmem_budget() -> int:
+    """What a kernel may ask Mosaic for (`vmem_limit_bytes`) beside the
+    compiler's own 16 MiB scope: half of one TensorCore's VMEM as
+    `pltpu.get_tpu_info` gives it for the chip, 64 MiB on a v5e. Where the
+    process holds no TPU (interpret mode, or `tools/aot_step.py` lowering for
+    a v5e with `on_tpu` forced) it is a v5e's half: this reads the device,
+    not the path `on_tpu` picks."""
+    if jax.devices()[0].platform != "tpu":
+        return _V5E_VMEM // 2
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.get_tpu_info().vmem_capacity_bytes // 2
 
 
 def x64_off():

@@ -287,7 +287,7 @@ def _flash_fwd(q, k, v, seg, causal: bool, scale: float, group: int,
     # two buffers). Past the compiler's own 16 MiB scope (8192 x 192 + 128:
     # 12 MiB, refused) the call asks for what it needs; below it, as at
     # 4096 x 128, nothing is passed and the call compiles as it always has
-    lanes = lambda n: -(-n // 128) * 128            # noqa: E731
+    lanes = _compat.lanes
     resident = 2 * s * (lanes(d) + lanes(dv)) * q.dtype.itemsize
     params = {}
     if resident > 8 * 2**20 and not interpret:

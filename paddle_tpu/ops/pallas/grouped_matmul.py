@@ -18,8 +18,10 @@ more segment vocabulary):
     The output tile [bm, bn] accumulates over the trailing span. With the
     dispatcher's block-aligned layout span is 1 and the weights of a group
     stay in VMEM while its row blocks pass. The weight is tiled over its
-    output columns ([K, bn], bn from `_col_tile`): a whole [2304, 1024]
-    expert in float32 does not fit the chip's 16 MB scope.
+    output columns ([K, bn], bn from `_col_tile`) only where one tile's
+    working set does not fit the VMEM budget: every column tile streams all
+    the rows again, so the rule takes the FEWEST tiles that fit, the last
+    one partial where the width is no multiple of the tile.
   * dx — the forward kernel over `w` transposed (same skip structure).
   * dw — the same grid; dw[g]'s [K, bn] tile is the resident output while
     the row blocks of g pass (groups come sorted, so they follow one
@@ -61,7 +63,7 @@ from paddle_tpu.ops.pallas.flash_attention import (
 )
 
 __all__ = ["grouped_matmul", "grouped_matmul_visit_counts",
-           "expected_visit_counts", "pick_block_rows"]
+           "expected_visit_counts", "pick_block_rows", "col_tiles"]
 
 
 def _heuristic_block_rows(n_rows: int, num_groups: int) -> int:
@@ -108,22 +110,77 @@ def _resolve_backend(backend: str | None) -> str:
 # nothing to do names the block its neighbour had and moves no data. `span`
 # is how many groups a row block may hold: 1 under the dispatcher's
 # block-aligned layout (one launch a row block and column tile, no row
-# mask), the number of groups for any sorted layout. The weights are tiled
-# over their output columns (a [2304, 1024] expert whole, cast to float32,
-# was 19-36 MB of the 16 MB scope); operands go to the MXU in their own type
-# and accumulate in float32.
+# mask), the number of groups for any sorted layout. Operands go to the MXU
+# in their own type and accumulate in float32.
+#
+# The column tile: every tile streams all the laid-out rows again, so the
+# kernel takes the fewest tiles whose working set fits the chip's
+# `_compat.vmem_budget()`, the whole width where one does (64 MiB on a v5e:
+# one tile at all six shapes of the three expert cells, the fastest count at
+# each in the chip's table, PERF.md, PR 38). The tile need not divide the
+# width: the last one may be partial, its columns past n never written back,
+# and K (the contraction) is never tiled, so no output element changes (a
+# width of 11 x 128 has no wide divisor: PERF.md, PR 38).
 
-_TILE_ELEMS = 1_250_000        # K x bn of one weight / dw tile
+_VMEM_SCOPE = 16 * 2**20     # what Mosaic gives a kernel that asks for nothing
 
 
-def _col_tile(k: int, n: int) -> int:
-    """Columns of a weight tile: the widest divisor of n that is a multiple
-    of 128 lanes and keeps the [k, bn] tile under _TILE_ELEMS; n whole when
-    it is small or has no such divisor."""
-    if k * n <= _TILE_ELEMS or n % 128:
-        return n
-    fits = [b for b in range(128, n + 1, 128) if n % b == 0 and k * b <= _TILE_ELEMS]
-    return max(fits) if fits else 128
+def _working_set(bm: int, k: int, bn: int, x_bytes: int, tile_bytes: int,
+                 blk_bytes: int, product_rows: int) -> int:
+    """VMEM bytes of a grid step: the pipeline's two buffers of the [bm, k]
+    rows, of the [k, bn] weight (forward) or float32 dw tile, of the [bm, bn]
+    output (forward) or dy block and of the ids, and the step's float32
+    product, [bm, bn] forward and [k, bn] for dw (`product_rows`)."""
+    lanes = _compat.lanes
+    return (product_rows * lanes(bn) * 4
+            + 2 * (bm * lanes(k) * x_bytes + k * lanes(bn) * tile_bytes
+                   + bm * lanes(bn) * blk_bytes + bm * 128 * 4))
+
+
+def _fwd_sizes(block_rows: int, x_dtype, w_dtype, out_dtype=jnp.float32) -> tuple:
+    """`_working_set`'s bytes of the forward (and of dx, dy over w
+    transposed): rows, weight tile, output block, a [block_rows, bn] product."""
+    return (jnp.dtype(x_dtype).itemsize, jnp.dtype(w_dtype).itemsize,
+            jnp.dtype(out_dtype).itemsize, block_rows)
+
+
+def _dw_sizes(k: int, x_dtype, dy_dtype) -> tuple:
+    """`_working_set`'s bytes of dw: rows, the float32 dw tile, the dy block,
+    a [k, bn] product."""
+    return (jnp.dtype(x_dtype).itemsize, 4, jnp.dtype(dy_dtype).itemsize, k)
+
+
+def _col_tile(bm: int, k: int, n: int, *sizes: int) -> int:
+    """Columns of a weight / dw tile: n whole when its working set fits the
+    chip's `vmem_budget`, else a multiple of 128 that cuts n into the fewest
+    tiles that fit (the last one may be partial); 128 when none does."""
+    budget = _compat.vmem_budget()
+    for tiles in range(1, pl.cdiv(n, 128) + 1):
+        bn = n if tiles == 1 else _compat.lanes(pl.cdiv(n, tiles))
+        if _working_set(bm, k, bn, *sizes) <= budget:
+            return bn
+    return min(n, 128)
+
+
+def _vmem_params(working_set: int, interpret: bool) -> dict:
+    """Past the compiler's own scope a call asks for its working set and a
+    MiB of room (every expert cell's shape compiled so on a v5e, PR 38);
+    below it, nothing."""
+    if interpret or working_set + 2**20 <= _VMEM_SCOPE:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=working_set + 2**20)}
+
+
+def col_tiles(block_rows: int, k: int, n: int, x_dtype, w_dtype) -> dict:
+    """Column tiles of x [M, k] @ w [G, k, n] and of its two backward
+    products as the kernels cut them: the forward, dx (dy in x's dtype over
+    w transposed) and dw."""
+    fwd = _fwd_sizes(block_rows, x_dtype, w_dtype)
+    dw = _dw_sizes(k, x_dtype, x_dtype)
+    return {"fwd": pl.cdiv(n, _col_tile(block_rows, k, n, *fwd)),
+            "dx": pl.cdiv(k, _col_tile(block_rows, n, k, *fwd)),
+            "dw": pl.cdiv(n, _col_tile(block_rows, k, n, *dw))}
 
 
 def _group_of(gmin_ref, gmax_ref, i, s, num_groups):
@@ -200,12 +257,13 @@ def _gmm_fwd_pallas(x, w, gids, block_rows, interpret, span=0,
     m, k = x.shape
     num_groups, _, n = w.shape
     span = span or num_groups
-    bn = _col_tile(k, n)
+    sizes = _fwd_sizes(block_rows, x.dtype, w.dtype, out_dtype)
+    bn = _col_tile(block_rows, k, n, *sizes)
     gmin, gmax = _block_ranges(gids, block_rows)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(n // bn, m // block_rows, span),
+        grid=(pl.cdiv(n, bn), m // block_rows, span),
         in_specs=[
             pl.BlockSpec((block_rows, 1), lambda j, i, s, *_: (i, 0)),
             pl.BlockSpec((block_rows, k), lambda j, i, s, *_: (i, 0)),
@@ -219,6 +277,7 @@ def _gmm_fwd_pallas(x, w, gids, block_rows, interpret, span=0,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
             interpret=interpret,
+            **_vmem_params(_working_set(block_rows, k, bn, *sizes), interpret),
             **_compat.kernel_name("grouped_matmul"),
         )(gmin, gmax, gids.reshape(m, 1), x, w)
 
@@ -227,12 +286,13 @@ def _gmm_dw_pallas(x, dy, gids, num_groups, block_rows, interpret, span=0):
     m, k = x.shape
     n = dy.shape[1]
     span = span or num_groups
-    bn = _col_tile(k, n)
+    sizes = _dw_sizes(k, x.dtype, dy.dtype)
+    bn = _col_tile(block_rows, k, n, *sizes)
     gmin, gmax = _block_ranges(gids, block_rows)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(n // bn, m // block_rows, span),
+        grid=(pl.cdiv(n, bn), m // block_rows, span),
         in_specs=[
             pl.BlockSpec((block_rows, 1), lambda j, i, s, *_: (i, 0)),
             pl.BlockSpec((block_rows, k), lambda j, i, s, *_: (i, 0)),
@@ -246,6 +306,7 @@ def _gmm_dw_pallas(x, dy, gids, num_groups, block_rows, interpret, span=0):
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((num_groups, k, n), jnp.float32),
             interpret=interpret,
+            **_vmem_params(_working_set(block_rows, k, bn, *sizes), interpret),
             **_compat.kernel_name("grouped_matmul_dw"),
         )(gmin, gmax, gids.reshape(m, 1), x, dy)
     # a group no row block holds is never visited: its tile was never written

@@ -58,7 +58,6 @@ __all__ = ["RowsLayout", "rows_layout", "rows_gather", "rows_combine",
 
 GROUP = 8             # rows of a 32-bit HBM tile
 _SLOTS = 128          # most pairs of a combine step
-_VMEM = 64 * 2**20    # both kernels stage two blocks' tiles: 8-19 MB of it
 _SMEM = 768 * 2**10   # of the core's 1 MB, for the prefetched index arrays
 
 
@@ -270,7 +269,9 @@ def _live_map(i, live_ref, *_):
 def _params(interpret):
     if interpret:
         return {}
-    return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=_VMEM)}
+    # both kernels stage two blocks' tiles: 8-19 MB of the budget
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=_compat.vmem_budget())}
 
 
 def _stage(slots, src):

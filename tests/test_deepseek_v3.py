@@ -422,6 +422,26 @@ def test_pairs_past_the_rows_laid_out_are_counted_at_six_a_token():
     assert stats[0] == pairs and stats[3] == pairs - rows == float(layer.tokens_dropped._value)
 
 
+def test_the_resolution_names_each_products_column_tiles():
+    """`last_resolution("held_experts").derived["col_tiles"]`: the column tiles
+    the forward, dx and dw of the gate / up and the down products cut into,
+    as the kernels cut them (one each at these widths)."""
+    from paddle_tpu.ops.pallas.grouped_matmul import col_tiles
+    from paddle_tpu.tuning.blocks import last_resolution
+
+    cfg = tiny_cfg(router_experts=64, n_routed_experts=8)
+    d = DW.dims(cfg)
+    layer = _program_layer(cfg, _moe_leaves(cfg), 0, np.zeros(d["experts"]))
+    x = np.random.RandomState(4).randn(16, d["h"]).astype(np.float32)
+    with force_interpret():
+        layer(paddle.to_tensor(x))
+    tiles = last_resolution("held_experts").derived["col_tiles"]
+    f32 = jnp.float32
+    assert tiles == {"gate_up": col_tiles(8, d["h"], d["expert"], f32, f32),
+                     "down": col_tiles(8, d["expert"], d["h"], f32, f32)}
+    assert tiles["gate_up"] == {"fwd": 1, "dx": 1, "dw": 1}
+
+
 def test_held_rows_of_the_cell():
     """6 of 64 a token, 8 held: 18,432 pairs land here a step and layer with
     a balanced router (2,304 a held expert) and four times that is laid out."""

@@ -7,6 +7,8 @@ predicate) and the XLA block-gather fallback — in fp32 (<=1e-5) and bf16
 (<=1e-3), forward and dx/dw. The visit-count kernel must agree with the
 predicate evaluated independently in numpy.
 """
+import importlib
+
 import numpy as np
 import pytest
 
@@ -15,9 +17,11 @@ import jax.numpy as jnp
 
 from paddle_tpu.ops.pallas.flash_attention import force_interpret
 from paddle_tpu.ops.pallas.grouped_matmul import (
-    expected_visit_counts, grouped_matmul, grouped_matmul_visit_counts,
-    pick_block_rows,
+    col_tiles, expected_visit_counts, grouped_matmul,
+    grouped_matmul_visit_counts, pick_block_rows,
 )
+
+gm = importlib.import_module("paddle_tpu.ops.pallas.grouped_matmul")
 
 
 def _dense_ref(x, w, gids):
@@ -158,3 +162,82 @@ class TestVisitCounts:
         assert pick_block_rows(128 * 64, 8) == 128
         assert pick_block_rows(8 * 40, 8) == 32
         assert pick_block_rows(64, 8) == 8
+
+
+class TestColumnTiles:
+    """The column tile is cut by what VMEM holds, not by what divides the
+    width: 11 x 128 and 13 x 128 have no 128-multiple divisor but 128 and
+    themselves, so the rule before PR 38 gave them 128-column tiles."""
+
+    @pytest.mark.parametrize("n", [11 * 128, 13 * 128])
+    @pytest.mark.parametrize("cut", ["partial", "whole"])
+    def test_partial_or_whole_tile_matches_dense(self, n, cut, monkeypatch):
+        rs = np.random.RandomState(7)
+        bm, G, k = 8, 3, 16
+        fwd = gm._fwd_sizes(bm, jnp.float32, jnp.float32)
+        dws = gm._dw_sizes(k, jnp.float32, jnp.float32)
+        if cut == "partial":    # the budget of a 512-column tile at this k
+            budget = max(gm._working_set(bm, k, 512, *fwd), gm._working_set(bm, k, 512, *dws))
+            monkeypatch.setattr(gm._compat, "vmem_budget", lambda: budget)
+        bn = gm._col_tile(bm, k, n, *fwd)
+        assert bn == gm._col_tile(bm, k, n, *dws)      # dw cut alike
+        assert (bn == n) == (cut == "whole") and (cut == "whole" or n % bn)
+        gids = _aligned_gids(rs, 6, bm, G, trash_blocks=2)
+        x = jnp.asarray(rs.randn(gids.size, k), jnp.float32)
+        w = jnp.asarray(rs.randn(G, k, n), jnp.float32)
+        g = jnp.asarray(gids)
+
+        def loss(fn):
+            return lambda xv, wv: jnp.sum(jnp.sin(fn(xv, wv, g)))
+
+        gmm = lambda xv, wv, g: grouped_matmul(        # noqa: E731
+            xv, wv, g, block_rows=bm, backend="pallas", aligned=True)
+        with force_interpret():
+            y = gmm(x, w, g)
+            dx, dw = jax.grad(loss(gmm), (0, 1))(x, w)
+        dxr, dwr = jax.grad(loss(_dense_ref), (0, 1))(x, w)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(_dense_ref(x, w, g)),
+                                   rtol=1e-5, atol=1e-5)
+        # dx sums n products of size ~10 in another order than the reference
+        np.testing.assert_allclose(np.asarray(dx), np.asarray(dxr), rtol=1e-5, atol=5e-4)
+        np.testing.assert_allclose(np.asarray(dw), np.asarray(dwr), rtol=1e-5, atol=1e-4)
+        np.testing.assert_array_equal(np.asarray(y)[gids == G], 0.0)
+        np.testing.assert_array_equal(np.asarray(dx)[gids == G], 0.0)
+
+    @pytest.mark.parametrize("bm,k,n,w_dtype", [
+        (128, 2048, 1408, jnp.bfloat16), (128, 1408, 2048, jnp.bfloat16),
+        (128, 7168, 2048, jnp.bfloat16), (128, 7168, 1408, jnp.float32),
+        (8, 16, 1408, jnp.float32), (128, 4096, 24, jnp.bfloat16),
+        (32, 20480, 11 * 128, jnp.bfloat16),
+    ])
+    def test_rule_takes_the_fewest_tiles_that_fit(self, bm, k, n, w_dtype):
+        budget = gm._compat.vmem_budget()
+        for sizes in (gm._fwd_sizes(bm, jnp.bfloat16, w_dtype),
+                      gm._dw_sizes(k, jnp.bfloat16, jnp.bfloat16)):
+            bn = gm._col_tile(bm, k, n, *sizes)
+            tiles = -(-n // bn)
+            assert bn == n or bn % 128 == 0
+            assert (tiles - 1) * bn < n <= tiles * bn          # they cover n
+            ws = lambda b: gm._working_set(bm, k, b, *sizes)  # noqa: E731
+            assert ws(bn) <= budget or bn == 128
+            if tiles > 1:       # one tile fewer does not fit
+                fewer = n if tiles == 2 else gm._compat.lanes(-(-n // (tiles - 1)))
+                assert ws(fewer) > budget
+
+    def test_tiles_follow_the_operands_dtypes(self):
+        """A v5e's budget is half its 128 MiB, and where no TPU answers it is
+        a v5e's. float32 weights double the forward's weight tile: a
+        DeepSeek-V3-wide product whose bfloat16 tile fits whole takes two."""
+        assert gm._compat.vmem_budget() == 64 * 2**20
+        bf = col_tiles(128, 7168, 2048, jnp.bfloat16, jnp.bfloat16)
+        f32 = col_tiles(128, 7168, 2048, jnp.bfloat16, jnp.float32)
+        assert bf["fwd"] == 1 and f32["fwd"] == 2 and bf["dw"] == f32["dw"]
+
+    @pytest.mark.parametrize("cell,d,h", [
+        ("moonlight", 2048, 1408), ("lfm2", 2048, 1536), ("kimi", 2304, 1024)])
+    def test_expert_cells_take_one_tile(self, cell, d, h):
+        """The counts PERF.md records for the three expert cells (PR 38):
+        one tile for every product, forward, dx and dw, at block rows 128."""
+        one = {"fwd": 1, "dx": 1, "dw": 1}
+        assert col_tiles(128, d, h, jnp.bfloat16, jnp.bfloat16) == one
+        assert col_tiles(128, h, d, jnp.bfloat16, jnp.bfloat16) == one

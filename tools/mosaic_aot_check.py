@@ -241,7 +241,7 @@ def _moonlight_cases():
 
     from paddle_tpu.incubate.distributed.models.moe.held_experts import (
         _held_moe, held_rows)
-    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+    from paddle_tpu.ops.pallas.grouped_matmul import col_tiles, grouped_matmul
     from paddle_tpu.ops.pallas.moe_rows import rows_backend
 
     bf, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
@@ -249,6 +249,8 @@ def _moonlight_cases():
 
     laid_out, bm = held_rows(rows * s * k, 8, 64)
     buf = laid_out + 8 * bm
+    # the column tiles of the forward, dx and dw as the kernels cut them
+    tiles = lambda a, b: "/".join(map(str, col_tiles(bm, a, b, bf, bf).values()))  # noqa: E731
     # `xla` at these sizes: the movers' index arrays (3 x 73,728 pairs) pass
     # the scalar memory they may take, so the layout is XLA's gathers and
     # scatters and the layer compiles to 8 kernel calls, not 11 (PERF.md)
@@ -271,9 +273,9 @@ def _moonlight_cases():
     return {
         "flash fwd+bwd 3 x 8192 x 16 heads, q/k 192, v 128": (
             _flash_grad, [((rows, s, heads, 192), bf)] * 2 + [((rows, s, heads, 128), bf)]),
-        f"grouped matmul fwd+dx+dw {buf} x 2048 -> 8 x [2048,1408]": (
+        f"grouped matmul fwd+dx+dw {buf} x 2048 -> 8 x [2048,1408] (column tiles {tiles(hid, inter)})": (
             gmm_grad, [((buf, hid), bf), ((8, hid, inter), bf), ((buf,), i32)]),
-        f"grouped matmul fwd+dx+dw {buf} x 1408 -> 8 x [1408,2048]": (
+        f"grouped matmul fwd+dx+dw {buf} x 1408 -> 8 x [1408,2048] (column tiles {tiles(inter, hid)})": (
             gmm_grad, [((buf, inter), bf), ((8, inter, hid), bf), ((buf,), i32)]),
         f"held experts layer fwd+bwd 24576 tokens x 6 a token, 8 of 64, two shared (row movers: {movers})": (
             experts_grad,
