@@ -1,0 +1,9 @@
+"""Device milliseconds a step of the KDA layers (gated delta-rule linear
+attention) with their pre-norm and residual add: the `kda` part of the step
+program (`benchmark/scopes.py`). Layer: model. Moves
+train_tokens_per_s_per_chip."""
+from benchmark import scopes
+
+
+def read(run):
+    return scopes.part_ms_per_step(run, "kda")

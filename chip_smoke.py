@@ -158,7 +158,7 @@ def train_batch(sz: Sizes, rows: int):
 
 
 def step_program_text(step) -> str:
-    return step._jitted.lower(*step._abstract_args).as_text()
+    return step._lowered.as_text()
 
 
 def has_kernel(text: str, name: str) -> bool:
@@ -486,13 +486,12 @@ def phase_mesh(sz: Sizes, on_tpu: bool, state: dict) -> dict:
         check(abs(losses[0] - want) <= TOL_BF16["atol"]
               + TOL_BF16["rtol"] * abs(want),
               f"first loss on the mesh {losses[0]} vs one chip {want}")
-        lowered = step._jitted.lower(*step._abstract_args)
-        text = lowered.as_text()
+        text = step_program_text(step)
         if on_tpu:
             for kernel in TRAIN_KERNELS:
                 check(has_kernel(text, kernel),
                       f"{kernel} not in the mesh program")
-        hlo = lowered.compile().as_text()
+        hlo = step._executable.as_text()
         collectives = {op: hlo.count(f" {op}(") + hlo.count(f" {op}-start(")
                        for op in ("all-reduce", "all-gather",
                                   "reduce-scatter")}

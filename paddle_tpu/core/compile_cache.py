@@ -92,6 +92,12 @@ def _on_scalar(event, value, **kw):
     if event not in _PHASE:
         return
     p = _pending()
+    if event == _TRACE and p["depth"] == 0:
+        # an outermost trace opens the next program: what a trace left that
+        # lowered nothing (a lookup in JAX's trace cache, which reports a
+        # trace too: a step lowered before its first call) is no part of it
+        _tls.pending = None
+        p = _pending()
     if p["t0"] is None:
         p["t0"] = time.perf_counter()
     # a jitted function called while another is traced is traced inside it:

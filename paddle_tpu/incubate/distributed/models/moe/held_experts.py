@@ -38,6 +38,7 @@ from paddle_tpu.incubate.distributed.models.moe.moe_layer import (
     SigmoidGate, _route)
 from paddle_tpu.nn import initializer as I
 from paddle_tpu.nn.layer.layers import Layer
+from paddle_tpu.observability import scopes
 from paddle_tpu.ops.pallas.grouped_matmul import col_tiles, grouped_matmul, pick_block_rows
 from paddle_tpu.ops.pallas.moe_rows import (
     rows_backend, rows_combine, rows_gather, rows_layout)
@@ -76,36 +77,44 @@ def _layout(logits, bias, *, k, first, held, routing, rows, block_rows):
     (weights [N, k] float32 of each token's chosen experts; the layout and
     `gbuf` of `_rows_layout`; [pairs routed here, largest and mean load of a
     held expert, pairs left out]; load of ALL experts [E])."""
-    topv, topi, _ = _route(logits.astype(jnp.float32), None, k=k,
-                           routing=routing, bias=bias)
-    flat = topi.reshape(-1)
-    local = flat - first
-    gids = jnp.where((local >= 0) & (local < held), local, held).astype(jnp.int32)
-    layout, gbuf, counts = _rows_layout(gids, held, k, block_rows, rows)
-    load = counts.astype(jnp.float32)
-    n_here = jnp.sum(load)
-    stats = jnp.stack([n_here, jnp.max(load), jnp.mean(load),
-                       jnp.maximum(n_here - rows, 0.0)])
-    # pairs of every expert, counted by comparison: a scatter-add of one a
-    # pair took a millisecond (my chip run, PR 34)
-    load_all = jnp.sum(flat[:, None] == jnp.arange(logits.shape[1], dtype=flat.dtype),
-                       axis=0, dtype=jnp.float32)
+    with scopes.scope("moe_router"):
+        topv, topi, _ = _route(logits.astype(jnp.float32), None, k=k,
+                               routing=routing, bias=bias)
+        flat = topi.reshape(-1)
+    with scopes.scope("moe_layout"):
+        local = flat - first
+        gids = jnp.where((local >= 0) & (local < held), local, held).astype(jnp.int32)
+        layout, gbuf, counts = _rows_layout(gids, held, k, block_rows, rows)
+        load = counts.astype(jnp.float32)
+        n_here = jnp.sum(load)
+        stats = jnp.stack([n_here, jnp.max(load), jnp.mean(load),
+                           jnp.maximum(n_here - rows, 0.0)])
+    with scopes.scope("moe_router"):
+        # pairs of every expert, counted by comparison: a scatter-add of one
+        # a pair took a millisecond (measured on one v5e chip)
+        load_all = jnp.sum(flat[:, None] == jnp.arange(logits.shape[1], dtype=flat.dtype),
+                           axis=0, dtype=jnp.float32)
     return topv, layout, gbuf, stats, load_all
 
 
 def _experts(xv, topv, layout, gbuf, wg, wu, wd, *shared_w, block_rows, backend):
     """The held experts over a layout, and the shared expert (`shared_w`;
     none for a layer without one): what `recompute=True` runs again."""
-    buf = rows_gather(xv, layout, block_rows=block_rows, backend=backend)
-    mm = functools.partial(grouped_matmul, gids=gbuf, block_rows=block_rows,
-                           backend=backend, aligned=True)
-    act = (jax.nn.silu(mm(buf, wg)) * mm(buf, wu)).astype(xv.dtype)
-    routed = rows_combine(mm(act, wd), topv, layout, block_rows=block_rows,
-                          backend=backend)
+    with scopes.scope("moe_layout"):
+        buf = rows_gather(xv, layout, block_rows=block_rows, backend=backend)
+    with scopes.scope("moe_experts"):
+        mm = functools.partial(grouped_matmul, gids=gbuf, block_rows=block_rows,
+                               backend=backend, aligned=True)
+        act = (jax.nn.silu(mm(buf, wg)) * mm(buf, wu)).astype(xv.dtype)
+        out = mm(act, wd)
+    with scopes.scope("moe_layout"):
+        routed = rows_combine(out, topv, layout, block_rows=block_rows,
+                              backend=backend)
     if shared_w:
         sg_w, su_w, sd_w = shared_w
-        shared = (jax.nn.silu(xv @ sg_w) * (xv @ su_w)) @ sd_w
-        routed = routed + shared.astype(jnp.float32)
+        with scopes.scope("moe_shared"):
+            shared = (jax.nn.silu(xv @ sg_w) * (xv @ su_w)) @ sd_w
+            routed = routed + shared.astype(jnp.float32)
     return routed.astype(xv.dtype)
 
 
@@ -195,12 +204,15 @@ class HeldExpertsMoE(Layer):
             _held_moe, k=self.top_k, first=first,
             routing=self.gate.routing_config(self.training), rows=rows,
             block_rows=bm, backend=self.backend, recompute=self.recompute)
+        with scopes.scope("moe_router"):
+            logits = self.gate(x2)
         out, stats, load = apply_op(
-            fn, x2, self.gate(x2), self.gate.e_score_correction_bias,
+            fn, x2, logits, self.gate.e_score_correction_bias,
             self.w_gate, self.w_up, self.w_down,
             *((self.shared_gate, self.shared_up, self.shared_down)
               if self.shared else ()), name="held_experts_moe", n_outputs=3)
         self.step_stats = stats
         self.tokens_dropped = stats[3]
-        self.gate.next_bias = self.gate.balanced(load)
+        with scopes.scope("moe_router"):
+            self.gate.next_bias = self.gate.balanced(load)
         return out.reshape(shape)

@@ -34,14 +34,13 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-import jax
-
 import paddle_tpu.nn as nn
 import paddle_tpu.nn.functional as F
 from paddle_tpu.core.tensor import apply_op
 from paddle_tpu.incubate.distributed.models.moe import HeldExpertsMoE
 from paddle_tpu.models.kimi_linear import (DenseMLP, LatentAttention, _Block,
                                            rms_norm)
+from paddle_tpu.observability import scopes
 
 __all__ = ["DeepseekV3Config", "DeepseekV3ForCausalLM", "DeepseekV3Model",
            "deepseek_v3_tiny_config"]
@@ -131,9 +130,9 @@ class DeepseekV3Layer(nn.Layer):
         self.mlp = DenseMLP(config) if ffn == "dense" else ExpertMLP(config)
 
     def forward(self, x):
-        with jax.named_scope("attn"):
+        with scopes.scope("attn"):
             x = self.mixer(x)
-        with jax.named_scope("mlp"):
+        with scopes.scope("mlp"):
             return self.mlp(x)
 
 
@@ -153,11 +152,11 @@ class DeepseekV3Model(nn.Layer):
         self.norm = nn.RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
 
     def forward(self, input_ids):
-        with jax.named_scope("embed"):
+        with scopes.scope("embed"):
             x = self.embed_tokens(input_ids)
         for layer in self.layers:
             x = layer(x)
-        with jax.named_scope("final_norm"):
+        with scopes.scope("head"):
             return self.norm(x)
 
 
@@ -175,10 +174,9 @@ class DeepseekV3ForCausalLM(nn.Layer):
         from paddle_tpu.core.flags import flag
 
         hidden = self.model(input_ids)
-        if labels is None:
-            with jax.named_scope("head"):
+        with scopes.scope("head"):
+            if labels is None:
                 return self.lm_head(hidden)
-        with jax.named_scope("head_ce"):
             if flag("use_fused_head_loss"):
                 return F.fused_linear_cross_entropy(
                     hidden, self.lm_head.weight, labels, reduction="mean")

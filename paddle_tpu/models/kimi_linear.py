@@ -31,6 +31,7 @@ import paddle_tpu.nn.functional as F
 from paddle_tpu.core.tensor import Tensor, apply_op
 from paddle_tpu.incubate.distributed.models.moe import HeldExpertsMoE
 from paddle_tpu.nn import initializer as I
+from paddle_tpu.observability import scopes
 
 __all__ = ["KimiLinearConfig", "KimiLinearForCausalLM", "KimiLinearModel",
            "kimi_linear_tiny_config"]
@@ -346,13 +347,14 @@ class KimiLinearLayer(nn.Layer):
         kda = number in config.linear_attn_config["kda_layers"]
         self.mixer = KimiDeltaAttention(config) if kda else LatentAttention(
             config, None if config.mla_use_nope else config.rope_theta)
+        self.scope = "kda" if kda else "attn"
         dense = number <= config.first_k_dense_replace
         self.mlp = DenseMLP(config) if dense else ExpertMLP(config)
 
     def forward(self, x):
-        with jax.named_scope("attn"):
+        with scopes.scope(self.scope):
             x = self.mixer(x)
-        with jax.named_scope("mlp"):
+        with scopes.scope("mlp"):
             return self.mlp(x)
 
 
@@ -366,11 +368,11 @@ class KimiLinearModel(nn.Layer):
         self.norm = nn.RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
 
     def forward(self, input_ids):
-        with jax.named_scope("embed"):
+        with scopes.scope("embed"):
             x = self.embed_tokens(input_ids)
         for layer in self.layers:
             x = layer(x)
-        with jax.named_scope("final_norm"):
+        with scopes.scope("head"):
             return self.norm(x)
 
 
@@ -386,10 +388,9 @@ class KimiLinearForCausalLM(nn.Layer):
         from paddle_tpu.core.flags import flag
 
         hidden = self.model(input_ids)
-        if labels is None:
-            with jax.named_scope("head"):
+        with scopes.scope("head"):
+            if labels is None:
                 return self.lm_head(hidden)
-        with jax.named_scope("head_ce"):
             if flag("use_fused_head_loss"):
                 return F.fused_linear_cross_entropy(
                     hidden, self.lm_head.weight, labels, reduction="mean")

@@ -44,6 +44,7 @@ from paddle_tpu.incubate.distributed.models.moe import HeldExpertsMoE
 from paddle_tpu.models.kimi_linear import _Block, causal_conv, rms_norm
 from paddle_tpu.models.llama import _rope_tables
 from paddle_tpu.nn import initializer as I
+from paddle_tpu.observability import scopes
 
 __all__ = ["Lfm2MoeConfig", "Lfm2MoeForCausalLM", "Lfm2MoeModel",
            "lfm2_moe_tiny_config"]
@@ -226,9 +227,9 @@ class Lfm2MoeLayer(nn.Layer):
         self.mlp = DenseFFN(config) if ffn == "dense" else ExpertFFN(config)
 
     def forward(self, x):
-        with jax.named_scope(self.scope):
+        with scopes.scope(self.scope):
             x = self.mixer(x)
-        with jax.named_scope("mlp"):
+        with scopes.scope("mlp"):
             return self.mlp(x)
 
 
@@ -242,11 +243,11 @@ class Lfm2MoeModel(nn.Layer):
         self.embedding_norm = nn.RMSNorm(config.hidden_size, epsilon=config.norm_eps)
 
     def forward(self, input_ids):
-        with jax.named_scope("embed"):
+        with scopes.scope("embed"):
             x = self.embed_tokens(input_ids)
         for layer in self.layers:
             x = layer(x)
-        with jax.named_scope("final_norm"):
+        with scopes.scope("head"):
             return self.embedding_norm(x)
 
 
@@ -262,12 +263,11 @@ class Lfm2MoeForCausalLM(nn.Layer):
         from paddle_tpu.core.flags import flag
 
         hidden = self.model(input_ids)
-        # the ONE leaf a second time, as [hidden, vocab]
-        head = apply_op(jnp.transpose, self.model.embed_tokens.weight, name="tied_head")
-        if labels is None:
-            with jax.named_scope("head"):
+        with scopes.scope("head"):
+            # the ONE leaf a second time, as [hidden, vocab]
+            head = apply_op(jnp.transpose, self.model.embed_tokens.weight, name="tied_head")
+            if labels is None:
                 return apply_op(jnp.matmul, hidden, head, name="head")
-        with jax.named_scope("head_ce"):
             if flag("use_fused_head_loss"):
                 return F.fused_linear_cross_entropy(hidden, head, labels,
                                                     reduction="mean")

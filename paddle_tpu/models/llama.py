@@ -28,6 +28,7 @@ from paddle_tpu.distributed.fleet.meta_parallel import (
     ColumnParallelLinear, ParallelCrossEntropy, RowParallelLinear,
     VocabParallelEmbedding,
 )
+from paddle_tpu.observability import scopes
 
 __all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM", "LlamaDecoderLayer",
            "LlamaPretrainingCriterion", "llama_tiny_config", "llama_7b_config"]
@@ -403,19 +404,19 @@ class LlamaDecoderLayer(nn.Layer):
                 position_ids=None):
         # named scopes change HLO metadata only: a device trace can then
         # say which part of a block an operation belongs to
-        with jax.named_scope("attn"):
+        with scopes.scope("attn"):
             x = _tag_residual(x + self.self_attn(self.input_layernorm(x),
                                                  attn_mask, rope=rope,
                                                  segment_ids=segment_ids,
                                                  position_ids=position_ids))
-        with jax.named_scope("mlp"):
+        with scopes.scope("mlp"):
             x = _tag_residual(x + self.mlp(self.post_attention_layernorm(x)))
         return x
 
     def forward_decode(self, x, *, rope, cache, layer_idx, page_table,
                        context_lens, position_ids, ctx_pad=None,
                        write_mask=None, verify=False, segment_ids=None):
-        with jax.named_scope("attn"):
+        with scopes.scope("attn"):
             attn_out, cache = self.self_attn.forward_decode(
                 self.input_layernorm(x), rope=rope, cache=cache,
                 layer_idx=layer_idx, page_table=page_table,
@@ -423,7 +424,7 @@ class LlamaDecoderLayer(nn.Layer):
                 ctx_pad=ctx_pad, write_mask=write_mask, verify=verify,
                 segment_ids=segment_ids)
             x = x + attn_out
-        with jax.named_scope("mlp"):
+        with scopes.scope("mlp"):
             x = x + self.mlp(self.post_attention_layernorm(x))
         return x, cache
 
@@ -457,10 +458,10 @@ class LlamaModel(nn.Layer):
 
     def forward(self, input_ids, attn_mask=None, segment_ids=None,
                 position_ids=None):
-        with jax.named_scope("embed"):
+        with scopes.scope("embed"):
             x = self.embed_tokens(input_ids)
         x = self._run_layers(x, attn_mask, segment_ids, position_ids)
-        with jax.named_scope("final_norm"):
+        with scopes.scope("head"):
             return self.norm(x)
 
     def decode_forward(self, input_ids, cache, page_table, context_lens,
@@ -480,7 +481,7 @@ class LlamaModel(nn.Layer):
         write_mask = _raw(write_mask)
         segment_ids = (_raw(segment_ids).astype(jnp.int32)
                        if segment_ids is not None else None)
-        with jax.named_scope("embed"):
+        with scopes.scope("embed"):
             x = self.embed_tokens(input_ids)
         rope = (self.rope_cos._value, self.rope_sin._value)
         for i, layer in enumerate(self.layers):
@@ -490,7 +491,7 @@ class LlamaModel(nn.Layer):
                 position_ids=position_ids, ctx_pad=ctx_pad,
                 write_mask=write_mask, verify=verify,
                 segment_ids=segment_ids)
-        with jax.named_scope("final_norm"):
+        with scopes.scope("head"):
             return self.norm(x), cache
 
     def _run_layers(self, x, attn_mask, segment_ids=None, position_ids=None):
@@ -607,7 +608,7 @@ class LlamaForCausalLM(nn.Layer):
         if labels is not None:
             from paddle_tpu.core.flags import flag
 
-            with head_scope(), jax.named_scope("head_ce"):
+            with head_scope(), scopes.scope("head"):
                 # head_scope: under fp8_policy='matmuls' the head matmul
                 # stays bf16; 'matmuls+head' quantizes it too (the fused-CE
                 # kernel keeps its softmax statistics fp32 either way)
@@ -618,7 +619,7 @@ class LlamaForCausalLM(nn.Layer):
                     return self.criterion.forward_fused(hidden, self.lm_head,
                                                         labels)
                 return self.criterion(self.lm_head(hidden), labels)
-        with head_scope(), jax.named_scope("head"):
+        with head_scope(), scopes.scope("head"):
             return self.lm_head(hidden)
 
     def decode_forward(self, input_ids, cache, page_table, context_lens,
@@ -630,7 +631,7 @@ class LlamaForCausalLM(nn.Layer):
             input_ids, cache, page_table, context_lens, position_ids,
             ctx_pad=ctx_pad, write_mask=write_mask, verify=verify,
             segment_ids=segment_ids)
-        with jax.named_scope("head"):
+        with scopes.scope("head"):
             return self.lm_head(hidden), cache
 
     # ---- pipeline-parallel factory ----------------------------------------

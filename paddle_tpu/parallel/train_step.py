@@ -25,6 +25,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax._src import config as jax_config
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from paddle_tpu.autograd import tape as _tape
@@ -32,6 +33,7 @@ from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.distributed.fleet import rng as fleet_rng
 from paddle_tpu.distributed.mesh import get_mesh
 from paddle_tpu.distributed.resilience import faults
+from paddle_tpu.observability import scopes
 from paddle_tpu.observability import tracing as obs_tracing
 
 __all__ = ["CompiledTrainStep", "functional_call", "init_opt_states",
@@ -64,22 +66,6 @@ def _nan_poison(vals):
             out[i] = v * jnp.asarray(float("nan"), v.dtype)
             return tuple(out), True
     return vals, False
-
-
-def _abstractify(x):
-    """ShapeDtypeStruct mirror of one step argument leaf (sharding kept
-    when the array is COMMITTED to it) — concrete arrays are donated per
-    step, so the abstract mirror is what `CompiledTrainStep.cost_analysis()`
-    lowers against. An uncommitted leaf (the PRNG key, lr and step scalars)
-    goes wherever the program runs: pinning it to its current device would
-    clash with mesh-sharded parameters at lowering."""
-    if hasattr(x, "shape") and hasattr(x, "dtype"):
-        sh = x.sharding if getattr(x, "committed", False) else None
-        try:
-            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh)
-        except TypeError:
-            return jax.ShapeDtypeStruct(x.shape, x.dtype)
-    return x
 
 
 def _innermost_opt(opt):
@@ -196,14 +182,13 @@ def apply_optimizer_update(optimizer, params, grads, states, lr, step_i):
     the nn.clip semantics on raw arrays), then optimizer._update per array.
     The single implementation behind PipelinedTrainStep and
     ZBH1PipelinedStep — schedule runtimes must not drift apart here."""
-    grads = [g.astype(p.dtype) if g.dtype != p.dtype else g
-             for p, g in zip(params, grads)]
-    clip = getattr(optimizer, "_grad_clip", None)
-    if clip is not None:
-        with jax.named_scope("clip"):
-            grads = _clip_grads(clip, grads)
     new_p, new_s = [], []
-    with jax.named_scope("optimizer"):
+    with scopes.scope("optimizer"):
+        grads = [g.astype(p.dtype) if g.dtype != p.dtype else g
+                 for p, g in zip(params, grads)]
+        clip = getattr(optimizer, "_grad_clip", None)
+        if clip is not None:
+            grads = _clip_grads(clip, grads)
         for pv, gv, st in zip(params, grads, states):
             np_, ns_ = optimizer._update(pv, gv, st, lr, step_i)
             new_p.append(np_)
@@ -527,7 +512,8 @@ class CompiledTrainStep:
         self._pending_metrics: list = []
         self._last_metrics: dict | None = None
         self._prev_metric_wall: float | None = None
-        self._abstract_args = None       # captured on the first dispatch
+        self._lowered = None             # the build's lowering and
+        self._executable = None          # compiled step (`_compile`)
         self._cost_analysis_cache = None
         self._layer_capable = bool(getattr(model, "layer_remat_capable", False))
         if scan_layers is None:
@@ -611,9 +597,6 @@ class CompiledTrainStep:
                                  if metrics_every is None else metrics_every)
         self._async_count = 0
         self._window = DispatchWindow(dispatch_window)
-        # host seconds of __call__ by part, cumulative (host_counters())
-        self._host_s = {"place": 0.0, "build": 0.0, "dispatch": 0.0,
-                        "run_ahead_wait": 0.0}
         self._builds = 0
         self._calls = 0
 
@@ -843,7 +826,8 @@ class CompiledTrainStep:
                                               batch[:-1],
                                               params=self._outer_params)
                         label = Tensor(batch[-1])
-                loss = self.loss_fn(out, label)
+                with scopes.scope("head"):
+                    loss = self.loss_fn(out, label)
             return loss._value
         finally:
             fleet_rng._tls.active_key_fn = prev
@@ -896,7 +880,8 @@ class CompiledTrainStep:
             for i, v in zip(trainable_idx, train_vals):
                 full[i] = v
             # scopes name HLO metadata only (docs/observability.md): the
-            # backward is `transpose(jvp(loss))` by JAX's own naming
+            # backward is `transpose(jvp(loss))` by JAX's own naming; `loss`
+            # is on no list of parts (`observability.scopes`)
             with jax.named_scope("loss"):
                 loss = run_loss(full, fp8_s)
             moe_vec = moe_stats() if self._moe_layers else None
@@ -916,6 +901,21 @@ class CompiledTrainStep:
         (_, (loss, moe_vec, next_biases)), (grads, new_fp8) = jax.value_and_grad(
             loss_all, argnums=(0, 1), has_aux=True)(train_vals, fp8_in)
 
+        # everything after the gradients is the update and its bookkeeping
+        # (loss scaling, health, telemetry): one part of the step
+        with scopes.scope("optimizer"):
+            return self._update(param_vals, opt_states, grads, loss, moe_vec,
+                                next_biases, fp8_in, new_fp8, lr, step_i,
+                                scaler_scale, trainable_idx)
+
+    def _update(self, param_vals, opt_states, grads, loss, moe_vec,
+                next_biases, fp8_in, new_fp8, lr, step_i, scaler_scale,
+                trainable_idx):
+        """The step after its gradients: unscaling, the health flag, the
+        telemetry vector, the optimizer's update and the gates' biases; the
+        step's outputs."""
+        fp8_on = self.fp8_policy != "none"
+        scaling = self._scaler is not None
         found_inf = None
         if scaling:
             inv = (1.0 / scaler_scale).astype(jnp.float32)
@@ -998,8 +998,7 @@ class CompiledTrainStep:
 
             for j, i in enumerate(trainable_idx):
                 st = streamed_state(i)
-                with jax.named_scope("optimizer"):
-                    np_, ns_ = one_update(j, i, st)
+                np_, ns_ = one_update(j, i, st)
                 if found_inf is not None:
                     # inf/nan grads (or an unhealthy anomaly-detected step)
                     # skip the WHOLE update: params and moments keep their
@@ -1088,15 +1087,12 @@ class CompiledTrainStep:
                 "a dict batch must carry a 'labels' entry (it feeds both "
                 f"the model and loss_fn); got keys {sorted(batch[0])}")
         # xprof's step view; the spans inside are the host's share of a step
-        # (docs/observability.md), each summed into host_counters()
+        # (docs/observability.md)
         with jax.profiler.StepTraceAnnotation("train.call",
                                               step_num=self._step_i + 1):
             return self._call(batch, named)
 
     def _call(self, batch, named):
-        clock = time.perf_counter
-        host = self._host_s
-        t0 = clock()
         with obs_tracing.span("train.place"):
             if named:
                 keys = sorted(batch[0])
@@ -1106,8 +1102,6 @@ class CompiledTrainStep:
             else:
                 vals, moved = self._spec_cache.place(batch)
             self.h2d_transfers += moved
-        t1 = clock()
-        host["place"] += t1 - t0
         building = self._jitted is None
         if building:
             with obs_tracing.span("train.build", part="program"):
@@ -1117,14 +1111,10 @@ class CompiledTrainStep:
             self._builds += 1
         with obs_tracing.span("train.dispatch", step=self._step_i + 1):
             loss = self._dispatch_step(vals, building)
-        t0, t1 = t1, clock()
-        # the first call is the program's construction, trace and compile
-        host["build" if building else "dispatch"] += t1 - t0
         # bounded run-ahead: block on the loss of step N-window before
         # returning, so at most `window` compiled steps are queued on-device
         with obs_tracing.span("train.run_ahead_wait"):
             self._window.admit(loss)
-        host["run_ahead_wait"] += clock() - t1
         self._calls += 1
         if self.optimizer is not None:
             _innermost_opt(self.optimizer)._step_count = self._step_i
@@ -1133,8 +1123,8 @@ class CompiledTrainStep:
     def _dispatch_step(self, vals, building: bool):
         """Everything between the placed batch and the enqueued step: the
         step's key and learning rate, the call of the compiled program (on
-        the first call its trace and compile: `train.build`), and taking
-        over its outputs."""
+        the first call its trace and compile: `train.build`, `_compile`), and
+        taking over its outputs."""
         self._step_i += 1
         self._key, sub = jax.random.split(self._key)
         lr = jnp.asarray(
@@ -1160,17 +1150,9 @@ class CompiledTrainStep:
         else:
             args = (self._param_vals, self._opt_states, vals, sub, lr,
                     jnp.asarray(self._step_i, jnp.int32))
-        if self._abstract_args is None:
-            # abstract (shape, dtype, sharding) mirror of the step's
-            # arguments — what cost_analysis() lowers against later
-            # (the concrete arrays are about to be donated)
-            self._abstract_args = jax.tree_util.tree_map(
-                _abstractify, args)
         if building:
-            with obs_tracing.span("train.build", part="compile"):
-                outs = self._jitted(*args)
-        else:
-            outs = self._jitted(*args)
+            self._compile(args)
+        outs = self._jitted(*args)
         step_metrics = None
         if self._telemetry:
             step_metrics = outs[-1]
@@ -1202,6 +1184,26 @@ class CompiledTrainStep:
                 (self._step_i, step_metrics, time.perf_counter()))
             self.settle_metrics(block=False)
         return loss
+
+    def _compile(self, args):
+        """Trace, lower and compile the step for `args` before the call that
+        donates them: the call then runs this executable (JAX's caches hand
+        it over; the step is traced and compiled once). The lowering
+        (`_lowered`: the StableHLO `chip_smoke.py` reads) and the executable
+        (`cost_analysis()`) are kept; the executable's text maps each
+        instruction to the named part of the model it belongs to
+        (`observability.scopes.last_table()`, a table of strings: nothing of
+        the step, its arrays or the executable).
+
+        The persistent compile cache's key takes this program's metadata in:
+        without it a step whose only change is where a scope lies is served
+        with the old `op_name`s, and the table names the old parts."""
+        with obs_tracing.span("train.build", part="compile"):
+            self._lowered = self._jitted.lower(*args)
+            with jax_config.compilation_cache_include_metadata_in_key(True):
+                self._executable = self._lowered.compile()
+        with obs_tracing.span("train.build", part="scopes"):
+            scopes.publish(self._executable.as_text())
 
     def step_async(self, *batch):
         """Dispatch one step and return a LossFuture — the deferred-read
@@ -1277,13 +1279,11 @@ class CompiledTrainStep:
 
     def host_counters(self) -> dict:
         """Cumulative host-side account of `__call__`, telemetry on or off:
-        seconds spent placing batches (`train.place`), dispatching
-        (`train.dispatch`: key, learning rate, the call, taking over the
-        outputs), waiting on the run-ahead window (`train.run_ahead_wait`)
-        and building (`train.build`: the first call's program construction,
-        trace and compile), with the number of calls (`steps`) and builds,
-        and what JAX's compile log (`core.compile_cache`) holds for this
-        class's program, process-wide. With telemetry on and a model that
+        the number of calls (`steps`) and builds, and what JAX's compile log
+        (`core.compile_cache`) holds for this class's program, process-wide;
+        the host's seconds by part are the spans `train.place`,
+        `train.dispatch`, `train.run_ahead_wait` and `train.build`
+        (docs/observability.md). With telemetry on and a model that
         holds a share of its experts, `moe` sums over the SETTLED steps
         (read with the loss, never by a sync of their own) the token-expert
         pairs routed here (`moe.routed_slots`), the pairs past the rows
@@ -1293,7 +1293,6 @@ class CompiledTrainStep:
         from paddle_tpu.core.compile_cache import compile_totals
 
         out = {"steps": self._calls, "builds": self._builds,
-               **{f"{k}_s": v for k, v in self._host_s.items()},
                "compile": compile_totals("jit(_step_fn)")}
         if self._telemetry and self._moe_load:
             self.settle_metrics(block=False)
@@ -1303,17 +1302,16 @@ class CompiledTrainStep:
     def cost_analysis(self) -> dict:
         """XLA's own cost model for ONE compiled step (flops, bytes
         accessed, ...) — the honest FLOP count MFU derives from, replacing
-        hand-counted formulas. Lowers + compiles a second AOT executable
-        from the captured abstract arguments (one-off, cached; call OFF
-        the hot path). Needs at least one executed step."""
+        hand-counted formulas. Read from the executable the build compiled
+        (`_compile`; cached; call OFF the hot path). Needs at least one
+        executed step."""
         if self._cost_analysis_cache is not None:
             return self._cost_analysis_cache
-        if self._jitted is None or self._abstract_args is None:
+        if self._executable is None:
             raise RuntimeError(
                 "cost_analysis() needs at least one executed step (the "
-                "abstract argument signature is captured at first dispatch)")
-        compiled = self._jitted.lower(*self._abstract_args).compile()
-        ca = compiled.cost_analysis()
+                "step's executable is compiled by the first call)")
+        ca = self._executable.cost_analysis()
         if isinstance(ca, (list, tuple)):
             ca = ca[0] if ca else {}
         self._cost_analysis_cache = dict(ca)

@@ -339,7 +339,7 @@ def test_launcher_refuses_an_unverified_split_before_starting(
 # ---------------------------------------------------------------------------
 
 
-def _tiny_step(mesh=None):
+def _tiny_step(mesh=None, loss_fn=None):
     from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
     from paddle_tpu.parallel import CompiledTrainStep
 
@@ -347,8 +347,8 @@ def _tiny_step(mesh=None):
     model = LlamaForCausalLM(llama_tiny_config(num_hidden_layers=1))
     opt = paddle.optimizer.AdamW(learning_rate=1e-3,
                                  parameters=model.parameters())
-    step = CompiledTrainStep(model, lambda out, lab: out, optimizer=opt,
-                             mesh=mesh)
+    step = CompiledTrainStep(model, loss_fn or (lambda out, lab: out),
+                             optimizer=opt, mesh=mesh)
     ids = paddle.to_tensor(np.random.RandomState(0).randint(
         0, 256, (2, 16)).astype(np.int32))
     return step, ids
@@ -356,24 +356,35 @@ def _tiny_step(mesh=None):
 
 def test_train_step_is_traced_once_without_a_mesh():
     """Uncommitted first-call inputs vs committed outputs used to give step 2
-    a second signature: one more trace + compile of the whole program."""
+    a second signature: one more trace + compile of the whole program. The
+    build lowers the step before its first call; the call finds that trace
+    in JAX's trace cache, and JAX reports the lookup as a trace event of its
+    own: two events, one run of the step's Python body."""
     import jax.monitoring
 
-    traced = []
+    traced, compiled, bodies = [], [], []
 
     def listener(event, secs, **kw):
         if (event.endswith("jaxpr_trace_duration")
                 and kw.get("fun_name") == "_step_fn"):
             traced.append(event)
+        elif (event.endswith("backend_compile_duration")
+              and kw.get("fun_name") == "jit(_step_fn)"):
+            compiled.append(event)
+
+    def loss_fn(out, lab):
+        bodies.append(1)          # once a Python trace of the step's body
+        return out
 
     jax.monitoring.register_event_duration_secs_listener(listener)
     try:
-        step, ids = _tiny_step()
+        step, ids = _tiny_step(loss_fn=loss_fn)
         for _ in range(3):
             float(step(ids, ids, ids))
     finally:
         jax.monitoring.unregister_event_duration_listener(listener)
-    assert len(traced) == 1
+    assert len(bodies) == 1 and len(traced) == 2 and len(compiled) == 1
+    assert step._jitted._cache_size() == 1
 
 
 def test_cost_analysis_lowers_under_a_mesh(mesh_dp2_mp2):

@@ -374,9 +374,12 @@ class TestProfilerClock:
         st_prof, _ = _tiny_step(False, seed=3)
         plain = [float(st_plain(ids, ids, ids)) for _ in range(3)]
         jax.profiler.start_trace(str(tmp_path))
+        obs_tracing.start_tracing()
         try:
             prof = [float(st_prof(ids, ids, ids)) for _ in range(3)]
         finally:
+            window = obs_tracing.stop_tracing()
+            obs_tracing.reset()
             jax.profiler.stop_trace()
         assert plain == prof
         assert st_plain._jitted._cache_size() == 1
@@ -385,7 +388,8 @@ class TestProfilerClock:
         for name in ("train.place", "train.dispatch",
                      "train.run_ahead_wait", "train.call"):
             assert len(evs[name]) == 3, (name, sorted(evs))
-        assert len(evs["train.build"]) == 2      # the program, its compile
+        # the program, its compile, the table of its parts
+        assert len(evs["train.build"]) == 3
         assert [e[2]["step"] for e in evs["train.dispatch"]] == [1, 2, 3]
         assert [e[2]["step_num"] for e in evs["train.call"]] == [1, 2, 3]
         # every part lies inside its step's train.call
@@ -396,8 +400,15 @@ class TestProfilerClock:
                 assert cs <= s and s + d <= cs + cd
         c = st_prof.host_counters()
         assert c["steps"] == 3 and c["builds"] == 1
-        assert c["build_s"] > c["dispatch_s"] > 0 and c["place_s"] > 0
-        assert c["run_ahead_wait_s"] >= 0
+        # the host's seconds by part are the spans' own, read in the window:
+        # the first dispatch holds the build's compile
+        durs = {}
+        for e in window:
+            durs.setdefault(e["name"], []).append(e["dur"])
+        first, *later = durs["train.dispatch"]
+        assert first > max(later) > 0 and min(durs["train.build"]) > 0
+        assert min(durs["train.place"]) > 0
+        assert min(durs["train.run_ahead_wait"]) >= 0
         # the compile log knows the step's program by JAX's name for it
         assert c["compile"]["programs"] >= 2 and c["compile"]["secs"] > 0
 
@@ -406,9 +417,8 @@ class TestProfilerClock:
         every scope the docs promise is in the lowered program."""
         st, ids = _tiny_step(False, seed=4)
         st(ids, ids, ids)
-        text = st._jitted.lower(*st._abstract_args).as_text(debug_info=True)
-        for scope in ("loss", "optimizer", "embed", "attn", "mlp",
-                      "final_norm", "head_ce"):
+        text = st._lowered.as_text(debug_info=True)
+        for scope in ("loss", "optimizer", "embed", "attn", "mlp", "head"):
             assert f"/{scope}/" in text or f"({scope})" in text, scope
         assert "transpose(jvp(loss))" in text
 
@@ -616,6 +626,36 @@ class TestCompileLog:
             e["trace_s"] + e["lower_s"] + e["compile_s"]
             for e in mine if e["fun_name"] == "jit(log_alpha)"))
         assert cc.compile_totals()["traces"] >= 3
+
+    def test_a_trace_cache_lookup_opens_no_program(self):
+        """A call that finds its trace in JAX's trace cache (a function
+        lowered before its first call) reports a trace and compiles nothing:
+        the next program's entry starts at its own trace, with its own
+        seconds."""
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu.core import compile_cache as cc
+
+        def log_gamma(x):
+            return x * 3
+
+        def log_delta(x):
+            return x + 5
+
+        cc.start_compile_log()
+        x = jnp.ones(3, jnp.float32)
+        f = jax.jit(log_gamma)
+        f.lower(x).compile()
+        time.sleep(0.2)
+        f(x)                               # the lookup: a trace event only
+        time.sleep(0.2)
+        t_before = time.perf_counter()
+        jax.jit(log_delta)(x)
+        (e,) = [e for e in cc.compile_log() if e["fun_name"] == "jit(log_delta)"]
+        assert t_before < e["t0"] < time.perf_counter()
+        assert e["trace_s"] + e["lower_s"] + e["compile_s"] < (
+            time.perf_counter() - t_before)
 
     def test_registry_series_are_the_persistent_caches_log(self, tmp_path):
         """`compile_cache_hits_total` / `_misses_total` are published from
@@ -859,6 +899,205 @@ class TestStepTelemetry:
 
 # ---------------------------------------------------------------------------
 # trace-id propagation on a two-replica in-process run
+# ---------------------------------------------------------------------------
+# the table of named parts a built step publishes (observability.scopes)
+# ---------------------------------------------------------------------------
+
+def _experts_step(build_table=True):
+    """A tiny latent-attention model with held and shared experts (kernels
+    interpreted), one step built and run."""
+    from paddle_tpu.models.deepseek_v3 import (DeepseekV3ForCausalLM,
+                                               deepseek_v3_tiny_config)
+    from paddle_tpu.ops.pallas.flash_attention import force_interpret
+    from paddle_tpu.parallel import CompiledTrainStep
+
+    with force_interpret():
+        paddle.seed(1)
+        model = DeepseekV3ForCausalLM(
+            deepseek_v3_tiny_config(router_bias_update_rate=0.01))
+        model.train()
+        opt = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                     parameters=model.parameters(),
+                                     multi_precision=True)
+        st = CompiledTrainStep(model, lambda out, lab: out, optimizer=opt,
+                               collect_metrics=True)
+        if not build_table:
+            st._compile = lambda args: None
+        b = np.random.RandomState(5).randint(0, 128, (2, 129)).astype(np.int32)
+        ids, lab = paddle.to_tensor(b[:, :-1]), paddle.to_tensor(b[:, 1:])
+        losses = [float(st(ids, lab, lab)) for _ in range(2)]
+    return st, losses
+
+
+@pytest.fixture(scope="module")
+def scope_tables():
+    """A dense and a held-experts step, each with the table it published
+    and the number of `jit(_step_fn)` compiles its build logged."""
+    from paddle_tpu.core.compile_cache import compile_log, start_compile_log
+    from paddle_tpu.observability import scopes
+
+    start_compile_log()
+    out = {}
+
+    def step_compiles():
+        return sum(e["fun_name"] == "jit(_step_fn)" for e in compile_log())
+
+    before = step_compiles()
+    dense, ids = _tiny_step(False, seed=6)
+    dense_losses = [float(dense(ids, ids, ids)) for _ in range(2)]
+    out["dense"] = (dense, scopes.last_table(), step_compiles() - before,
+                    dense_losses)
+    before = step_compiles()
+    experts, losses = _experts_step()
+    out["experts"] = (experts, scopes.last_table(), step_compiles() - before,
+                      losses)
+    return out
+
+
+class TestScopeTable:
+    def test_each_step_publishes_its_table(self, scope_tables):
+        for kind, (st, table, _, _) in scope_tables.items():
+            assert table["module"] == "jit__step_fn", kind
+            assert table["ops"], kind
+            # every instruction the executable runs at the top level is in it
+            text = st._executable.as_text()
+            entry = [ln for ln in text.splitlines() if ln.startswith("ENTRY")]
+            assert len(entry) == 1
+
+    def test_every_named_instruction_is_a_listed_part(self, scope_tables):
+        from paddle_tpu.observability import scopes
+
+        for kind, (st, table, _, _) in scope_tables.items():
+            _, entry, comps = scopes._computations(st._executable.as_text())
+            named = 0
+            for comp in comps.values():
+                for name, _, rest in comp:
+                    if name not in table["ops"]:
+                        continue
+                    _, op_name, _, _ = scopes._details(rest)
+                    if op_name is not None and "/" in op_name:
+                        named += 1
+                        assert table["ops"][name] in scopes.NAMES, (
+                            kind, name, op_name)
+            assert named > 50, kind
+            assert set(table["ops"].values()) <= set(scopes.NAMES) | {
+                scopes.UNSCOPED}
+
+    def test_experts_and_optimizer_have_operations(self, scope_tables):
+        parts = set(scope_tables["experts"][1]["ops"].values())
+        for part in ("moe_router", "moe_layout", "moe_experts",
+                     "moe_shared", "optimizer", "attn", "mlp", "embed",
+                     "head"):
+            assert part in parts, part
+        dense = set(scope_tables["dense"][1]["ops"].values())
+        assert {"attn", "mlp", "embed", "head", "optimizer"} <= dense
+        assert not any(p.startswith("moe_") for p in dense)
+
+    def test_the_table_compiles_nothing_more(self, scope_tables):
+        for kind, (st, _, compiles, _) in scope_tables.items():
+            assert compiles == 1, kind
+            assert st._jitted._cache_size() == 1, kind
+            assert st.host_counters()["builds"] == 1
+
+    def test_loss_bit_equal_without_the_table(self, scope_tables):
+        from paddle_tpu.observability import scopes
+
+        kept = scopes.last_table()
+        st, losses = _experts_step(build_table=False)
+        assert st._executable is None
+        assert scopes.last_table() is kept        # nothing published
+        assert losses == scope_tables["experts"][3]
+        dense, ids = _tiny_step(False, seed=6)
+        dense._compile = lambda args: None
+        assert ([float(dense(ids, ids, ids)) for _ in range(2)]
+                == scope_tables["dense"][3])
+
+    def test_a_moved_scope_is_compiled_again(self, tmp_path, monkeypatch):
+        """The persistent cache's key leaves HLO metadata out unless told
+        otherwise: the step's compile takes it in, so a step whose only
+        change is where a scope lies compiles again, and its table names
+        the parts where they are now, not where the cached program had
+        them."""
+        from paddle_tpu.core.compile_cache import compile_log, start_compile_log
+        from paddle_tpu.observability import scopes
+
+        start_compile_log()
+
+        def build():
+            n0 = len(compile_log())
+            st, ids = _tiny_step(False, seed=7)
+            st(ids, ids, ids)
+            (e,) = [e for e in compile_log()[n0:]
+                    if e["fun_name"] == "jit(_step_fn)"]
+            return e["cache"], set(scopes.last_table()["ops"].values())
+
+        with _temporary_persistent_cache(tmp_path):
+            first, parts = build()
+            named = scopes.scope
+            monkeypatch.setattr(scopes, "scope", lambda name: named(
+                "attn" if name == "mlp" else name))
+            again, moved = build()
+        assert (first, again) == ("miss", "miss")
+        assert "mlp" in parts and "mlp" not in moved
+
+    def test_innermost_listed_name_wins(self):
+        from paddle_tpu.observability import scopes
+
+        assert scopes.part_of(
+            "jit(_step_fn)/transpose(jvp(loss))/mlp/moe_experts/dot") == \
+            "moe_experts"
+        assert scopes.part_of("jit(_step_fn)/jvp(loss)/attn/mla_rope/mul") \
+            == "attn"
+        assert scopes.part_of(
+            "jit(_step_fn)/transpose(jvp(kda))/checkpoint/x;"
+            "jit(_step_fn)/head/y") == "kda"
+        assert scopes.part_of("jit(_step_fn)/head_ce/add") == scopes.UNSCOPED
+        with pytest.raises(ValueError):
+            scopes.scope("final_norm")
+
+    def test_xla_made_instructions_take_a_neighbours_part(self):
+        from paddle_tpu.observability import scopes
+
+        text = """HloModule jit__step_fn, is_scheduled=true
+
+%fused_computation (param_0: f32[4]) -> f32[4] {
+  %param_0 = f32[4]{0} parameter(0)
+  ROOT %multiply.1 = f32[4]{0} multiply(%param_0, %param_0), metadata={op_name="jit(_step_fn)/jvp(loss)/mlp/mul"}
+}
+
+%body (p: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %p = (s32[], f32[4]{0}) parameter(0)
+  %gte = f32[4]{0} get-tuple-element(%p), index=1
+  ROOT %tuple.2 = (s32[], f32[4]{0}) tuple(%gte, %gte)
+}
+
+%cond (q: (s32[], f32[4])) -> pred[] {
+  %q = (s32[], f32[4]{0}) parameter(0)
+  ROOT %c = pred[] constant(false)
+}
+
+ENTRY %main.9 (w.1: f32[4], x.1: (s32[], f32[4])) -> f32[4] {
+  %w.1 = f32[4]{0} parameter(0), metadata={op_name="param_vals[0]"}
+  %x.1 = (s32[], f32[4]{0}) parameter(1)
+  %copy-start.1 = (f32[4]{0:S(1)}, f32[4]{0}, u32[]) copy-start(%w.1)
+  %copy-done.1 = f32[4]{0:S(1)} copy-done((f32[4]{0:S(1)}, f32[4]{0}, u32[]) %copy-start.1)
+  %fusion.3 = f32[4]{0} fusion(%copy-done.1), kind=kLoop, calls=%fused_computation
+  %gather.2 = f32[4]{0} copy(%fusion.3), metadata={op_name="gather"}
+  %while.4 = (s32[], f32[4]{0}) while(%x.1), condition=%cond, body=%body, metadata={op_name="jit(_step_fn)/optimizer/while"}
+  ROOT %add.5 = f32[4]{0} add(%gather.2, %gather.2), metadata={op_name="jit(_step_fn)/add"}
+}
+"""
+        table = scopes.table(text)
+        assert table["module"] == "jit__step_fn"
+        assert table["ops"] == {
+            "copy-start.1": "mlp", "copy-done.1": "mlp",   # by their user
+            "fusion.3": "mlp",                             # by its root
+            "gather.2": "mlp",                             # by its operand
+            "while.4": "optimizer", "add.5": scopes.UNSCOPED,
+            "gte": "optimizer", "tuple.2": "optimizer",    # the loop's
+            "c": "optimizer"}
+
+
 # ---------------------------------------------------------------------------
 class _HostEngine:
     """test_router's FakeEngine pattern: REAL scheduler + allocator behind
