@@ -50,34 +50,86 @@ def _kda_inputs(t, seed=0, b=2, h=2, kd=128):
     return [jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta)]
 
 
-@pytest.mark.parametrize("t", [128, 150, 40])
-def test_kda_forward_and_gradients_match_the_recurrence(t):
-    """150 and 40 are no multiple of the chunk of 64."""
-    from paddle_tpu.ops.pallas.kda import kda_chunked
+def _kda_case(t, inputs, seed=0):
+    """The inputs of a case: random, a decay that forgets within a few
+    tokens, or keys that point the same way (as out of a SiLU) with a slow
+    decay and beta near 1."""
+    q, k, v, g, beta = _kda_inputs(t, seed=seed)
+    if inputs == "strong_decay":
+        g = jnp.full_like(g, -2.0)
+    elif inputs == "same_way":
+        k = jnp.abs(k) + 0.05
+        k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+        beta = jnp.full_like(beta, 0.95)
+        g = g * 0.05
+    return q, k, v, g, beta
 
-    args = _kda_inputs(t)
 
-    def loss(fn):
-        def f(*a):
-            o = fn(*a)
-            return jnp.sum(jnp.sin(o)), o
-        return jax.value_and_grad(f, argnums=(0, 1, 2, 3, 4), has_aux=True)
+def _kda_path(backend):
+    """kda_chunked with the part before the scan on `backend`, interpreted."""
+    from paddle_tpu.ops.pallas import kda
 
-    (_, o1), g1 = loss(lambda *a: kda_chunked(*a, interpret=True))(*args)
-    (_, o0), g0 = loss(jax.vmap(KR.kda_recurrence))(*args)
-    _close(o1, o0, 2e-5, "output")
-    for name, a, b in zip(("dq", "dk", "dv", "dg", "dbeta"), g1, g0):
-        _close(a, b, 5e-5, name)
+    def run(*a):
+        rule = kda.chunk_backend
+        kda.chunk_backend = lambda *_: backend
+        try:
+            return kda.kda_chunked(*a, interpret=True)
+        finally:
+            kda.chunk_backend = rule
+    return run
+
+
+def _value_and_grads(fn, args):
+    def f(*a):
+        o = fn(*a)
+        return jnp.sum(jnp.sin(o.astype(jnp.float32))), o
+    (_, o), grads = jax.value_and_grad(f, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+    return o, grads
+
+
+@pytest.mark.parametrize("t,dtype,inputs,tol", [
+    pytest.param(128, "float32", "random", (2e-5, 5e-5), id="128"),
+    pytest.param(150, "float32", "random", (2e-5, 5e-5), id="150"),
+    pytest.param(40, "float32", "random", (2e-5, 5e-5), id="40"),
+    pytest.param(128, "bfloat16", "random", (2e-2, 3e-2), id="128-bfloat16"),
+    pytest.param(150, "bfloat16", "random", (2e-2, 3e-2), id="150-bfloat16"),
+    pytest.param(128, "float32", "strong_decay", (1e-4, 5e-4), id="strong_decay"),
+    pytest.param(192, "float32", "same_way", (1e-4, 5e-4), id="same_way"),
+])
+def test_kda_forward_and_gradients_match_the_recurrence(t, dtype, inputs, tol):
+    """The chunk kernels (`kda_chunk_fwd` / `kda_chunk_bwd`) against the
+    token-by-token recurrence and against the same part in XLA, output and
+    every input's gradient. 150 and 40 are no multiple of the chunk of 64.
+    In bfloat16 the products take bfloat16 operands on both paths (the
+    recurrence runs in float32 on the same rounded inputs)."""
+    q, k, v, g, beta = _kda_case(t, inputs)
+    lo = jnp.dtype(dtype)
+    args = (q.astype(lo), k.astype(lo), v.astype(lo), g, beta)
+    o1, g1 = _value_and_grads(_kda_path("pallas"), args)
+    o2, g2 = _value_and_grads(_kda_path("xla"), args)
+    ref_args = [x.astype(jnp.float32) for x in args]
+    o0, g0 = _value_and_grads(jax.vmap(KR.kda_recurrence), ref_args)
+    assert o1.dtype == lo and bool(jnp.isfinite(o1.astype(jnp.float32)).all())
+    _close(o1, o0, tol[0], "output")
+    _close(o1, o2, tol[0] / 10 if dtype == "float32" else tol[0], "output against XLA")
+    for name, a, b, c in zip(("dq", "dk", "dv", "dg", "dbeta"), g1, g0, g2):
+        assert a.dtype == c.dtype and bool(jnp.isfinite(a.astype(jnp.float32)).all())
+        _close(a, b, tol[1], name)
+        # under strong decay XLA's beta gradient overflows (inf x 0 above the
+        # diagonal of ku kn^T); the kernels select there instead
+        if bool(jnp.isfinite(c.astype(jnp.float32)).all()) or inputs != "strong_decay":
+            _close(a, c, tol[1] / 10 if dtype == "float32" else tol[1], name + " against XLA")
 
 
 def test_kda_strong_decay_stays_finite():
     """A channel that forgets within a few tokens: exp(G_mid - G_i) is large
     and must not overflow float32 inside a chunk of 64."""
     from paddle_tpu.ops.pallas.kda import kda_chunked
+    from paddle_tpu.tuning.blocks import last_resolution
 
-    q, k, v, g, beta = _kda_inputs(128, seed=1)
-    g = jnp.full_like(g, -2.0)
+    q, k, v, g, beta = _kda_case(128, "strong_decay", seed=1)
     o1 = kda_chunked(q, k, v, g, beta, interpret=True)
+    assert last_resolution("kda").derived["chunk_backend"] == "pallas"
     o0 = jax.vmap(KR.kda_recurrence)(q, k, v, g, beta)
     assert bool(jnp.isfinite(o1).all())
     _close(o1, o0, 1e-4, "output under strong decay")
@@ -86,15 +138,13 @@ def test_kda_strong_decay_stays_finite():
 def test_kda_keys_that_point_the_same_way():
     """Keys out of a SiLU are mostly positive, so k_t . k_i is 0.5 and not
     0.05: a Neumann-series inverse of (I + A) cancels to NaN there (it did on
-    the chip); the triangular solve must follow the recurrence."""
+    the chip); the chunk kernels' inverse must follow the recurrence."""
     from paddle_tpu.ops.pallas.kda import kda_chunked
+    from paddle_tpu.tuning.blocks import last_resolution
 
-    q, k, v, g, beta = _kda_inputs(192, seed=2)
-    k = jnp.abs(k) + 0.05
-    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    beta = jnp.full_like(beta, 0.95)
-    g = g * 0.05                                    # a slow decay: long memory
+    q, k, v, g, beta = _kda_case(192, "same_way", seed=2)
     o1, vjp1 = jax.vjp(lambda *a: kda_chunked(*a, interpret=True), q, k, v, g, beta)
+    assert last_resolution("kda").derived["chunk_backend"] == "pallas"
     o0, vjp0 = jax.vjp(jax.vmap(KR.kda_recurrence), q, k, v, g, beta)
     assert bool(jnp.isfinite(o1).all())
     _close(o1, o0, 1e-4, "output")
@@ -103,14 +153,104 @@ def test_kda_keys_that_point_the_same_way():
         _close(a, b, 5e-4, name)
 
 
+def test_kda_inverse_is_float32_accurate():
+    """The chunk kernels' (I + A)^-1 (a doubling at one bfloat16 pass, then
+    two Newton steps with float32 residuals) against float64, on keys that
+    point the same way with beta near 1: within a few float32 roundings,
+    as XLA's triangular solve in float32 is."""
+    from paddle_tpu.ops.pallas import kda
+
+    _, vpu = kda._masks(kda.LOCAL_CHUNKS * kda.CHUNK)
+    rs = np.random.RandomState(0)
+    k = np.abs(rs.randn(vpu.shape[1], 128)) + 0.05
+    k /= np.linalg.norm(k, axis=1, keepdims=True)
+    a = np.asarray(vpu[kda._STRICT]) * (0.999 * (k @ k.T))
+    exact = np.linalg.inv(np.eye(len(a)) + a)
+    t = np.asarray(kda._unit_lower_inverses(jnp.asarray(a, jnp.float32)[None], vpu)[0],
+                   np.float64)
+    xla = np.asarray(jax.scipy.linalg.solve_triangular(
+        jnp.eye(len(a)) + jnp.asarray(a, jnp.float32), jnp.eye(len(a)), lower=True,
+        unit_diagonal=True), np.float64)
+    err = np.abs(t - exact).max() / np.abs(exact).max()
+    assert err < 3e-7 and err < 4 * np.abs(xla - exact).max() / np.abs(exact).max(), err
+
+
+def _kernel_eqns(fn, args):
+    """Equations of each Pallas kernel body in fn's jaxpr, by kernel name,
+    nested jaxprs (a fori_loop's body) counted once."""
+    from jax._src import core
+
+    counts = {}
+
+    def size(jaxpr):
+        return sum(1 + sum(size(sub) for sub in core.jaxprs_in_params(e.params))
+                   for e in jaxpr.eqns)
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                counts[eqn.params["name"]] = size(eqn.params["jaxpr"])
+            for sub in core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return counts
+
+
+def _row_loop_kernel(x_ref, o_ref):
+    """A forward substitution written as a Python loop over a chunk's rows:
+    what the chunk kernels must not be."""
+    x = x_ref[...]
+    for r in range(1, x.shape[0]):
+        x = x.at[r].add(-x[r - 1] * 0.5)
+    o_ref[...] = x
+
+
+@pytest.mark.parametrize("kernel", ["kda_chunk_fwd", "kda_chunk_bwd", "rows_control"])
+def test_kda_chunk_kernels_do_not_unroll_the_chunk(kernel, monkeypatch):
+    """Setting-up cost: a Pallas kernel is traced and lowered at every call
+    site on every set-up, so its body must not grow with the chunk. The
+    chunk kernels' bodies hold the same equations at a chunk of 32 as of 64
+    (the inverse's steps are a `fori_loop`); a body that loops over the
+    rows in Python (the control) does not."""
+    from jax.experimental import pallas as pl
+
+    from paddle_tpu.ops.pallas import kda
+
+    counts = []
+    for chunk in (32, 64):
+        monkeypatch.setattr(kda, "CHUNK", chunk)
+        if kernel == "rows_control":
+            fn = lambda x: pl.pallas_call(                     # noqa: E731
+                _row_loop_kernel, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+                interpret=True, name="rows_control")(x)
+            got = _kernel_eqns(fn, (jnp.ones((chunk, 128), jnp.float32),))
+        else:
+            args = _kda_case(4 * chunk, "random")
+            got = _kernel_eqns(lambda *a: jax.grad(
+                lambda *x: jnp.sum(kda.kda_chunked(*x, interpret=True)),
+                argnums=(0, 1, 2, 3, 4))(*a), args)
+        counts.append(got[kernel])
+    if kernel == "rows_control":
+        assert counts[1] > 1.8 * counts[0], counts
+    else:
+        assert counts[0] == counts[1], counts
+        assert counts[1] < 2500, counts
+
+
 def test_kda_resolution_is_recorded():
-    from paddle_tpu.ops.pallas.kda import CHUNK, kda_chunked
+    from paddle_tpu.ops.pallas.kda import CHUNK, LOCAL_CHUNKS, chunk_backend, kda_chunked
     from paddle_tpu.tuning.blocks import last_resolution
 
     kda_chunked(*_kda_inputs(64), interpret=True)
     res = last_resolution("kda")
     assert res.values == {"chunk": CHUNK, "head_block": 4}
-    assert res.derived["grid"] == (1, 1) and res.derived["state_block"] == (4, 128, 128)
+    assert res.derived["grid"] == (1, 2) and res.derived["state_block"] == (4, 128, 128)
+    assert res.derived["chunk_backend"] == "pallas"
+    assert res.derived["local_block"] == (4, 1, LOCAL_CHUNKS * CHUNK, 128)
+    # compiled for a chip: the kernels at widths of whole lanes, XLA elsewhere
+    assert chunk_backend(128, 128, False) == "pallas"
+    assert chunk_backend(64, 128, False) == chunk_backend(128, 96, False) == "xla"
+    assert chunk_backend(64, 96, True) == "pallas"
 
 
 def _kda_layer_grad(recompute, dtype):
@@ -154,7 +294,8 @@ def test_kda_layer_runs_the_scan_twice_a_step_not_three_times():
         for recompute in (True, False):
             grad, args, _ = _kda_layer_grad(recompute, jnp.float32)
             counts = _pallas_calls(jax.make_jaxpr(grad)(*args).jaxpr, {})
-            assert counts == {"kda_fwd": 2, "kda_bwd": 1}, (recompute, counts)
+            assert counts == {"kda_fwd": 2, "kda_bwd": 1, "kda_chunk_fwd": 2,
+                              "kda_chunk_bwd": 1}, (recompute, counts)
 
 
 @pytest.mark.parametrize("dtype,tol", [("float32", 5e-5), ("bfloat16", 1e-2)])

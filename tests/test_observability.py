@@ -524,7 +524,8 @@ def _kernel_cases():
             lambda q, g, b: jax.grad(lambda q: kda.kda_chunked(
                 q, q, q, g, b, interpret=True).sum())(q),
             (jnp.ones((1, 64, 2, 128), f32), -jnp.ones((1, 64, 2, 128), f32),
-             jnp.ones((1, 64, 2), f32) * 0.5), ["kda_fwd", "kda_fwd", "kda_bwd"]),
+             jnp.ones((1, 64, 2), f32) * 0.5),
+            ["kda_chunk_fwd", "kda_fwd", "kda_chunk_fwd", "kda_fwd", "kda_bwd", "kda_chunk_bwd"]),
         "moe_rows": (
             movers, (jnp.ones((32, 128), f32), jnp.ones((32, 128), f32),
                      jnp.ones((32, 4), f32)),
@@ -559,7 +560,7 @@ class TestKernelNames:
                 src = open(os.path.join(root, f)).read()
                 calls += len(re.findall(r"pl\.pallas_call\(", src))
                 names += len(re.findall(r"\*\*_compat\.kernel_name\(", src))
-        assert calls == names == 17       # PR 34: the two row movers, their counter
+        assert calls == names == 19       # the KDA chunk kernels, forward and backward
 
 
 @contextlib.contextmanager

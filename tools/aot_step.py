@@ -4,6 +4,8 @@
     python tools/aot_step.py <cell>               # XLA's memory analysis of the step compiled for a described v5e
     python tools/aot_step.py <cell> --hash-only   # sha256 of the step lowered for the TPU, whole and with the
                                                   # Mosaic payloads cut out (is it the parent's program?)
+    python tools/aot_step.py <cell> --time-lowering  # seconds to trace the step and to lower it for the TPU,
+                                                  # and the Mosaic payload bytes by kernel name
 
 Builds the cell's `CompiledTrainStep` as the benchmark does (from the repository's
 root; the four-chip cell wants XLA_FLAGS=--xla_force_host_platform_device_count=4
@@ -46,14 +48,27 @@ except RuntimeError as e:
 print("built in", round(time.time() - t0, 1), "s", flush=True)
 topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
 one = SingleDeviceSharding(topo.devices[0])
-hash_only = "--hash-only" in sys.argv
-shapes = held["args"] if hash_only else jax.tree.map(
+compiling = not {"--hash-only", "--time-lowering"} & set(sys.argv)
+shapes = held["args"] if not compiling else jax.tree.map(
     lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one) if hasattr(a, "shape") else a, held["args"])
 t0 = time.time()
-lowered = held["jitted"].trace(*shapes).lower(lowering_platforms=("tpu",))
+traced = held["jitted"].trace(*shapes)
+t_trace = time.time() - t0
+lowered = traced.lower(lowering_platforms=("tpu",))
+t_lower = time.time() - t0 - t_trace
 text = lowered.as_text()
 print("stablehlo sha256", hashlib.sha256(text.encode()).hexdigest(), "payloads cut", hashlib.sha256(re.sub(r'\\22body\\22: \\22[^\\]*\\22', '', text).encode()).hexdigest(), flush=True)
-if "--hash-only" not in sys.argv:
+if "--time-lowering" in sys.argv:
+    # each Mosaic kernel call site carries its own serialized body; its name follows it
+    by_kernel = {}
+    for body, kernel in re.findall(r'\\22body\\22: \\22([^\\]*)\\22.*?kernel_name = "([^"]+)"', text):
+        n, size = by_kernel.get(kernel, (0, 0))
+        by_kernel[kernel] = (n + 1, size + len(body))
+    print(f"traced in {t_trace:.2f}s, lowered for the TPU in {t_lower:.2f}s; text {len(text) / 1e6:.2f} MB, "
+          f"Mosaic payloads {sum(b for _, b in by_kernel.values()) / 1e6:.2f} MB")
+    for kernel, (n, size) in sorted(by_kernel.items(), key=lambda kv: -kv[1][1]):
+        print(f"  {kernel}: {n} call sites, {size / 1e3:.1f} kB of payload")
+if compiling:
     c = held["jitted"].lower(*shapes).compile()
     from collections import Counter
     from paddle_tpu.observability import scopes
