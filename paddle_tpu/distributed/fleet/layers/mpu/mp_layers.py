@@ -5,11 +5,17 @@ Reference parity: fleet/layers/mpu/mp_layers.py — `VocabParallelEmbedding`
 `ParallelCrossEntropy` (:742).
 
 TPU-native: parameters carry logical FULL shapes annotated with an "mp"-axis
-sharding (NamedSharding); the compiled program partitions them via GSPMD, and
-the explicit `with_sharding_constraint` + custom-vjp comm ops reproduce the
-exact Megatron fwd/bwd collective placement (identity/psum pairs). Eagerly on
-one chip the layers behave as their dense equivalents — same numerics, so
-single-chip tests validate TP models.
+sharding (NamedSharding); the compiled program partitions them via GSPMD.
+Under GSPMD with 2+ devices on "mp" the activations between the layers are
+sequence-parallel (reference fleet/utils/sequence_parallel_utils.py): a
+column-parallel layer all-gathers its input's sequence, a row-parallel one and
+the embedding reduce-scatter their partial sums onto the sequence shards
+(`mp_ops.seq_gather` / `seq_reduce_scatter`, custom-vjp pairs) — the bytes of
+one all-reduce in two halves, each beside its product, and the norms and
+residual adds between them on this rank's part of the sequence. Inside
+shard_map (bound "mp") the layers keep Megatron's identity/psum pairs.
+Eagerly on one chip they behave as their dense equivalents — same numerics,
+so single-chip tests validate TP models.
 """
 from __future__ import annotations
 
@@ -18,9 +24,10 @@ import jax.numpy as jnp
 
 from paddle_tpu.core.tensor import Tensor, apply_op
 from paddle_tpu.distributed.fleet.layers.mpu.mp_ops import (
-    MP_AXIS, _c_identity, _c_split, _mp_allreduce, mp_axis_bound,
+    MP_AXIS, _c_identity, _c_split, _mp_allreduce, _seq_reduce_scatter,
+    mp_axis_bound, seq_gather, seq_reduce_scatter, sp_mesh,
 )
-from paddle_tpu.distributed.mesh import get_mesh, mesh_axis_size
+from paddle_tpu.distributed.mesh import mesh_axis_size
 from paddle_tpu.nn import functional as F
 from paddle_tpu.nn import initializer as I
 from paddle_tpu.nn.layer.layers import Layer
@@ -34,31 +41,6 @@ def _annotate(p: Tensor, *spec):
     compiler in paddle_tpu.parallel when building NamedShardings)."""
     p._mp_pspec = spec
     return p
-
-
-def _constraint(x: Tensor, *spec):
-    """with_sharding_constraint when compiled under a mesh; no-op eagerly and
-    inside shard_map (manual axes use the explicit collectives instead)."""
-    mesh = get_mesh()
-    if mesh is None or MP_AXIS not in mesh.shape:
-        return x
-    from paddle_tpu.distributed.collective import _bound_axes
-
-    if _bound_axes(tuple(mesh.axis_names)):
-        return x
-
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    def f(v):
-        try:
-            return jax.lax.with_sharding_constraint(v, NamedSharding(mesh, PartitionSpec(*spec)))
-        except (ValueError, RuntimeError):
-            return v
-
-    try:
-        return apply_op(f, x, name="sharding_constraint")
-    except Exception:
-        return x
 
 
 class VocabParallelEmbedding(Layer):
@@ -77,13 +59,18 @@ class VocabParallelEmbedding(Layer):
         )
 
     def forward(self, x):
-        if not mp_axis_bound():
-            # GSPMD/eager path: logical full weight, partitioning via _annotate
+        bound = mp_axis_bound()
+        mesh = None if bound else sp_mesh(x._value if isinstance(x, Tensor) else x)
+        mp = int(mesh.shape[MP_AXIS]) if mesh is not None else 1
+        if not bound and (mesh is None or x.ndim != 2
+                          or self.num_embeddings % mp or x.shape[1] % mp):
+            # eager / one device / shapes mp does not divide: logical full
+            # weight, partitioning (if any) via _annotate
             return F.embedding(x, self.weight)
 
-        # manual (shard_map) path: the local weight is this rank's vocab shard.
-        # Shift ids into the local range, zero out-of-shard rows, then allreduce
-        # (reference mp_layers.py:47 masks against [vocab_start, vocab_end)).
+        # this rank's vocab shard: shift ids into the local range, zero the
+        # out-of-shard rows (reference mp_layers.py:47 masks against
+        # [vocab_start, vocab_end)); the partial sums are then added over mp
         def f(ids, w):
             n_local = w.shape[0]
             start = jax.lax.axis_index(MP_AXIS) * n_local
@@ -93,8 +80,21 @@ class VocabParallelEmbedding(Layer):
             out = jnp.take(w, safe, axis=0)
             return jnp.where(in_range[..., None], out, jnp.zeros((), out.dtype))
 
-        out = apply_op(f, x, self.weight, name="vocab_parallel_embedding")
-        return _mp_allreduce(out)
+        if bound:       # manual (shard_map) path: the local weight is given
+            out = apply_op(f, x, self.weight, name="vocab_parallel_embedding")
+            return _mp_allreduce(out)
+        # GSPMD over mp: the same per shard, summed onto the sequence shards
+        from jax.sharding import PartitionSpec as P
+
+        from paddle_tpu.distributed.mesh import shard_map_compat
+        from paddle_tpu.ops.pallas._compat import DATA_AXES, mesh_axes_dividing
+
+        data = mesh_axes_dividing(mesh, DATA_AXES, x.shape[0])
+        per_shard = shard_map_compat(
+            lambda ids, w: _seq_reduce_scatter(f(ids, w), 1, 0), mesh,
+            (P(data, None), P(MP_AXIS, None)), P(data, MP_AXIS, None))
+        return apply_op(per_shard, x, self.weight,
+                        name="vocab_parallel_embedding")
 
 
 class ColumnParallelLinear(Layer):
@@ -118,10 +118,10 @@ class ColumnParallelLinear(Layer):
         )
 
     def forward(self, x):
-        # input replicated across mp; identity fwd / psum bwd on the input edge
-        x = _c_identity(x)
+        # input whole across mp: identity fwd / psum bwd on the input edge
+        # (shard_map), or the sequence gathered here (GSPMD)
+        x = _c_identity(x) if mp_axis_bound() else seq_gather(x, x.ndim - 2, 0)
         out = F.linear(x, self.weight, self.bias)
-        out = _constraint(out, None, None, MP_AXIS)
         if self.gather_output and mp_axis_bound():
             from paddle_tpu.distributed.fleet.layers.mpu.mp_ops import _c_concat
 
@@ -151,7 +151,10 @@ class RowParallelLinear(Layer):
         if not self.input_is_parallel:
             x = _c_split(x)
         out = F.linear(x, self.weight, None)
-        out = _mp_allreduce(out)
+        if mp_axis_bound():
+            out = _mp_allreduce(out)
+        else:
+            out = seq_reduce_scatter(out, out.ndim - 2, 0)
         if self.bias is not None:
             out = out + self.bias
         return out

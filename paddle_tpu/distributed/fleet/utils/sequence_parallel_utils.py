@@ -6,17 +6,21 @@ Reference parity: fleet/utils/sequence_parallel_utils.py — `ScatterOp` (:85),
 (:192), `mark_as_sequence_parallel_parameter`.
 
 TPU-native: the sequence dim is sharded over the "mp" axis between attention
-blocks; scatter/all-gather become lax collectives with custom-vjp pairing
-(all_gather fwd <-> reduce_scatter bwd) compiled onto ICI.
+blocks; scatter/all-gather are the ONE sequence split of `mpu/mp_ops.py`
+(custom-vjp pairs, all_gather fwd <-> reduce_scatter bwd): lax collectives
+inside shard_map, layout constraints in a GSPMD program, where the mpu layers
+use them whenever the mesh's "mp" axis has more than one device. These
+layers keep the reference's [seq, batch, hidden] layout: sequence dim 0.
 """
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
-from paddle_tpu.core.tensor import Tensor, apply_op
+from paddle_tpu.core.tensor import apply_op
 from paddle_tpu.distributed.collective import _bound_axes
-from paddle_tpu.distributed.fleet.layers.mpu.mp_ops import MP_AXIS
+from paddle_tpu.distributed.fleet.layers.mpu.mp_ops import (
+    MP_AXIS, seq_gather, seq_reduce_scatter, seq_scatter,
+)
 from paddle_tpu.nn import functional as F
 from paddle_tpu.nn import initializer as I
 from paddle_tpu.nn.layer.layers import Layer
@@ -28,86 +32,26 @@ __all__ = ["ScatterOp", "AllGatherOp", "ReduceScatterOp", "scatter", "all_gather
            "register_sequence_parallel_allreduce_hooks"]
 
 
-def _bound():
-    return bool(_bound_axes((MP_AXIS,)))
-
-
-# all_gather fwd (seq dim 0) <-> reduce_scatter bwd
-@jax.custom_vjp
-def _allgather_seq(x):
-    if _bound():
-        return jax.lax.all_gather(x, MP_AXIS, axis=0, tiled=True)
-    return x
-
-
-def _ag_fwd(x):
-    return _allgather_seq(x), None
-
-
-def _ag_bwd(_, g):
-    if _bound():
-        return (jax.lax.psum_scatter(g, MP_AXIS, scatter_dimension=0, tiled=True),)
-    return (g,)
-
-
-_allgather_seq.defvjp(_ag_fwd, _ag_bwd)
-
-
-# scatter fwd (slice local seq shard) <-> all_gather bwd
-@jax.custom_vjp
-def _scatter_seq(x):
-    if _bound():
-        n = jax.lax.axis_size(MP_AXIS)
-        i = jax.lax.axis_index(MP_AXIS)
-        sz = x.shape[0] // n
-        return jax.lax.dynamic_slice_in_dim(x, i * sz, sz, axis=0)
-    return x
-
-
-def _sc_fwd(x):
-    return _scatter_seq(x), None
-
-
-def _sc_bwd(_, g):
-    if _bound():
-        return (jax.lax.all_gather(g, MP_AXIS, axis=0, tiled=True),)
-    return (g,)
-
-
-_scatter_seq.defvjp(_sc_fwd, _sc_bwd)
-
-
-# reduce_scatter fwd <-> all_gather bwd
-@jax.custom_vjp
-def _reduce_scatter_seq(x):
-    if _bound():
-        return jax.lax.psum_scatter(x, MP_AXIS, scatter_dimension=0, tiled=True)
-    return x
-
-
-def _rs_fwd(x):
-    return _reduce_scatter_seq(x), None
-
-
-def _rs_bwd(_, g):
-    if _bound():
-        return (jax.lax.all_gather(g, MP_AXIS, axis=0, tiled=True),)
-    return (g,)
-
-
-_reduce_scatter_seq.defvjp(_rs_fwd, _rs_bwd)
+def _batch_dim(x):
+    """[seq, batch, ...]: the batch is dim 1; [seq * batch, hidden] (the
+    sequence major) has no dim of its own for it."""
+    return 1 if x.ndim >= 3 else None
 
 
 def scatter(x):
-    return apply_op(_scatter_seq, x, name="sp_scatter")
+    """This rank's part of the sequence (dim 0) of `x` (all-gather bwd)."""
+    return seq_scatter(x, 0, _batch_dim(x))
 
 
 def all_gather(x):
-    return apply_op(_allgather_seq, x, name="sp_allgather")
+    """The whole sequence (dim 0) from every rank's part (reduce-scatter bwd)."""
+    return seq_gather(x, 0, _batch_dim(x))
 
 
 def reduce_scatter(x):
-    return apply_op(_reduce_scatter_seq, x, name="sp_reduce_scatter")
+    """Partial sums over "mp" summed onto this rank's part of the sequence
+    (all-gather bwd)."""
+    return seq_reduce_scatter(x, 0, _batch_dim(x))
 
 
 # PyLayer-style aliases matching the reference class names

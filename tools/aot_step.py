@@ -8,10 +8,12 @@
                                                   # and the Mosaic payload bytes by kernel name
 
 Builds the cell's `CompiledTrainStep` as the benchmark does (from the repository's
-root; the four-chip cell wants XLA_FLAGS=--xla_force_host_platform_device_count=4
-and --hash-only), takes the jitted step and its arguments as the first call hands
-them over, and lowers it; compiled, it also counts the instructions of each
-named part of the model (`paddle_tpu.observability.scopes`). To compare two
+root; the four-chip cell wants XLA_FLAGS=--xla_force_host_platform_device_count=4),
+takes the jitted step and its arguments as the first call hands them over, and
+lowers it; compiled, it also counts the instructions of each named part of the
+model (`paddle_tpu.observability.scopes`). A cell on a mesh is compiled on the
+same mesh over a described v5e:2x2, and its collectives are listed by kind,
+part and shape (`--hlo <file>` writes the compiled text). To compare two
 trees put BOTH at ONE path in turn: a Mosaic payload carries its source's path
 and its call stack's line numbers. Runs with JAX_PLATFORMS=cpu; 1-3 minutes a cell on a CPU host."""
 import hashlib, os, re, sys, time
@@ -49,8 +51,21 @@ print("built in", round(time.time() - t0, 1), "s", flush=True)
 topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
 one = SingleDeviceSharding(topo.devices[0])
 compiling = not {"--hash-only", "--time-lowering"} & set(sys.argv)
+place = lambda a: one
+if compiling and step.mesh is not None:
+    # the same step on the same mesh shape over the described chips: the
+    # model reads the global mesh while it is traced
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from paddle_tpu.distributed.mesh import set_mesh
+    tmesh = Mesh(np.array(topo.devices[:step.mesh.size]).reshape(step.mesh.devices.shape), step.mesh.axis_names)
+    place = lambda a: NamedSharding(tmesh, a.sharding.spec if isinstance(a.sharding, NamedSharding) else P())
+    step.mesh = tmesh
+    step._state_shardings = jax.tree.map(lambda s: NamedSharding(tmesh, s.spec), step._state_shardings)
+    step._build()
+    held["jitted"] = step._jitted
+    set_mesh(tmesh)
 shapes = held["args"] if not compiling else jax.tree.map(
-    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one) if hasattr(a, "shape") else a, held["args"])
+    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=place(a)) if hasattr(a, "shape") else a, held["args"])
 t0 = time.time()
 traced = held["jitted"].trace(*shapes)
 t_trace = time.time() - t0
@@ -72,7 +87,34 @@ if compiling:
     c = held["jitted"].lower(*shapes).compile()
     from collections import Counter
     from paddle_tpu.observability import scopes
-    print("instructions by part:", dict(Counter(scopes.table(c.as_text())["ops"].values()).most_common()))
+    hlo = c.as_text()
+    parts = scopes.table(hlo)["ops"]
+    print("instructions by part:", dict(Counter(parts.values()).most_common()))
+    if "--hlo" in sys.argv:
+        open(sys.argv[sys.argv.index("--hlo") + 1], "w").write(hlo)
+    if step.mesh is not None:
+        # every collective of the program: its kind, the part it serves (or the
+        # fusion that holds it), its result
+        kinds, comp = Counter(), ""
+        for line in hlo.splitlines():
+            head = re.match(r"(?:ENTRY )?%(\S+) \(", line)
+            comp = head.group(1) if head else comp
+            if comp.startswith("all-reduce-scatter"):
+                continue        # the body of a reduce-scatter fusion, listed as that
+            m = re.match(r"\s*(?:ROOT )?%(\S+) = (.+?) (all-gather|all-reduce|reduce-scatter|collective-permute|all-to-all)"
+                         r"(-start|-done)?\(", line)
+            m = m or re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) (fusion)()\(.*calls=%(?:all-reduce-scatter|reduce-scatter)", line)
+            if m and m.group(4) != "-done":
+                kind = "reduce-scatter" if m.group(3) == "fusion" else m.group(3) + (m.group(4) or "")
+                shape = re.sub(r"\{[^}]*\}", "", m.group(2))
+                where = parts.get(m.group(1)) or "in " + re.sub(r"\.\d+$", "", comp)
+                kinds[(kind, where, shape[:90])] += 1
+        for (kind, part, shape), n in sorted(kinds.items()):
+            print(f"  {n:3d} x {kind:26s} {part:26s} {shape}")
+    cost = c.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    print(f"XLA's cost analysis a chip (Pallas calls left out): {cost.get('flops', 0) / 1e12:.3f} TFLOP, "
+          f"{cost.get('bytes accessed', 0) / 2**30:.2f} GiB accessed")
     m = c.memory_analysis()
     g = 2**30
     print(f"compiled in {time.time() - t0:.0f}s: arguments {m.argument_size_in_bytes / g:.3f} outputs {m.output_size_in_bytes / g:.3f} alias {m.alias_size_in_bytes / g:.3f} temporaries {m.temp_size_in_bytes / g:.3f} code {m.generated_code_size_in_bytes / g:.3f} GiB; total {(m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes + m.generated_code_size_in_bytes) / g:.3f} of 15.75")
