@@ -576,10 +576,10 @@ class LlamaPretrainingCriterion(nn.Layer):
         then mean over ALL tokens when use_parallel_cross_entropy, else
         F.cross_entropy's mean over non-ignored tokens."""
         if self.parallel_ce is not None:
-            per_tok = F.fused_linear_cross_entropy(
+            return F.fused_linear_cross_entropy(
                 hidden, lm_head.weight, labels, bias=lm_head.bias,
-                ignore_index=self.parallel_ce.ignore_index, reduction="none")
-            return per_tok.mean()
+                ignore_index=self.parallel_ce.ignore_index,
+                reduction="mean_all")
         return F.fused_linear_cross_entropy(
             hidden, lm_head.weight, labels, bias=lm_head.bias,
             reduction="mean")

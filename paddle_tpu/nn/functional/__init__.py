@@ -1017,7 +1017,11 @@ def fused_linear_cross_entropy(x, weight, label, bias=None, ignore_index=-100,
     x: [..., hidden]; weight: [hidden, vocab] (the local shard under bound
     mp — stats then reduce over the "mp" axis, Megatron-style); label:
     integer [...] matching x's leading dims. `z_loss` adds the
-    `z * logsumexp^2` stabilizer to both value and gradient."""
+    `z * logsumexp^2` stabilizer to both value and gradient. `reduction`:
+    "mean" (over non-ignored tokens), "sum", "mean_all" (over every token,
+    like `parallel_cross_entropy(...).mean()`) or "none" (per token, shaped
+    like `label`); a reduced loss computes its gradients in the same pass
+    as its value."""
     x = _t(x)
     lab = _t(label)
     if lab._value.ndim == x._value.ndim:
@@ -1031,13 +1035,12 @@ def fused_linear_cross_entropy(x, weight, label, bias=None, ignore_index=-100,
 
         flat = xv.reshape(-1, xv.shape[-1])
         labf = lv.reshape(-1)
-        nll = fused_linear_cross_entropy_loss(
+        out = fused_linear_cross_entropy_loss(
             flat, wv, labf, bv[0] if bv else None,
             ignore_index=ignore_index, label_smoothing=label_smoothing,
             z_loss=z_loss, chunk_tokens=chunk_tokens, chunk_vocab=chunk_vocab,
-            variant=variant, mp_axis="auto")
-        return _fused_ce_reduce(nll, labf != ignore_index, reduction,
-                                lv.shape, jnp.float32)
+            variant=variant, mp_axis="auto", reduction=reduction)
+        return out.reshape(lv.shape) if reduction == "none" else out
 
     args = [x, _t(weight), lab] + ([_t(bias)] if bias is not None else [])
     return apply_op(f, *args, name="fused_linear_cross_entropy")

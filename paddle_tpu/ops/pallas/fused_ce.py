@@ -8,19 +8,35 @@ tensor is the single largest HBM spike of a train step (LLaMA-2-7B at
 batch*seq=4096, vocab 32000: 512 MB in fp32), and it is pure overhead — the
 loss needs only three per-token scalars (max, log-sum-exp, target logit).
 
-TPU-native design: one `jax.custom_vjp` computes
+TPU-native design: a `jax.custom_vjp` computes
 ``loss = CE(x @ W + b, labels)`` in chunks so the full logits never exist in
-forward OR backward:
+forward OR backward.
+
+Passes over the head. A REDUCED loss (`reduction` "mean", "sum" or
+"mean_all": every train step's) takes ONE pass when differentiated
+(`_one_pass`): a scan over token chunks projects each chunk's `[C, V]` fp32
+logits tile once, reduces it to the softmax statistics, forms the loss and
+`d = dloss/dlogits` with the reduction's per-token weights (known before
+the call) where a per-token cotangent would go, and runs `dx = d W^T` and
+`dW += x^T d`: three head products a step, and the backward only scales the
+kept dx / dW / db by the scalar cotangent (at 1 or a power of two the
+gradients are bit-equal to the two-pass path's). Evaluated without a
+gradient, the same entry runs the statistics forward alone. Two passes
+remain — a statistics forward and a backward that projects every tile
+again, four products — for per-token losses (`reduction="none"`), the
+logits-level loss and the `vocab` variant. The statistics forward's
+variants:
 
 * **token-chunked** (`variant="tokens"`): `lax.scan` over token chunks; each
   chunk materializes only a `[C, V]` logits tile in fp32, reduces it to the
-  per-token stats, and is freed before the next chunk. Backward scans token
-  chunks too, recomputing the tile and accumulating `dW`/`db` in fp32, at a
-  depth of its own (below).
+  per-token stats, and is freed before the next chunk. Its backward scans
+  token chunks too, recomputing the tile and accumulating `dW`/`db` in fp32,
+  at a depth of its own (below).
 * **vocab-chunked** (`variant="vocab"`): `lax.scan` over vocab chunks with
   online (flash-style) max/sum-exp rescaling — the right shape when the
-  token count is small but the vocabulary is huge.
-* **pallas** (`variant="pallas"`): a Pallas kernel grids over
+  token count is small but the vocabulary is huge; its backward scans the
+  vocabulary, so a reduced loss keeps two passes here.
+* **pallas** (`variant="pallas"`, `ce_stats`): a Pallas kernel grids over
   (token-block, vocab-block) and keeps the running max/sum-exp/target/sum
   accumulators resident in VMEM, one MXU matmul per tile; on a CPU backend
   it runs in interpreter mode (fake-device pattern, SURVEY §4.4) so tier-1
@@ -30,11 +46,12 @@ forward OR backward:
 * **mp-parallel softmax**: when the "mp" mesh axis is bound (shard_map — the
   pipelined runtimes and manual-collective TP), each rank keeps only its
   vocab shard: labels shift into the local range, the per-token stats reduce
-  with `pmax`/`psum` over the axis (Megatron fwd), and backward `psum`s the
-  partial `dx` while `dW` stays shard-local (Megatron bwd) — no rank ever
-  holds a full vocab row.
+  with `pmax`/`psum` over the axis (Megatron fwd; inside the one pass, per
+  chunk), and the partial `dx` is `psum`med while `dW` stays shard-local
+  (Megatron bwd) — no rank ever holds a full vocab row. Under a GSPMD mesh
+  the entry runs Mosaic and the one pass per shard in its own shard_map.
 
-Backward blocking: with a head, every iteration of the backward's scan
+Blocking of the loop that makes the products: with a head, every iteration
 reads and writes the whole fp32 `dW` accumulator and streams the head twice,
 whatever the chunk, so it does not inherit the forward's 4M-element tile
 (128 tokens at vocab 32768: 96 such passes a 12K-token step). It takes
@@ -42,7 +59,8 @@ whatever the chunk, so it does not inherit the forward's 4M-element tile
 `chunk_tokens` the caller or `FLAGS_fused_ce_chunk_tokens` SET binds it as a
 memory bound; a tuned or heuristic forward tile does not. The logits-level
 loss (no head, no accumulator) keeps the forward's chunk. The depth is no
-knob: `last_resolution("fused_ce").derived` shows it.
+knob: `last_resolution("fused_ce").derived` shows it, and `passes` (1 or 2)
+says which path a call takes when differentiated.
 
 Numerics: per-chunk logits, all stats and all gradient accumulators are
 fp32 regardless of input dtype (bf16-safe); label smoothing, ignore_index
@@ -360,6 +378,27 @@ def _local_labels(cfg: _CECfg, labels, vloc):
     return labels.astype(jnp.int32) - off, axis, vloc * world
 
 
+def _merge_stats(axis, m, s, t, sl):
+    """Per-token lse, target logit and logit sum from one shard's stats:
+    under bound mp the Megatron reduction of the [tokens] vectors over the
+    axis (no rank holds a full vocabulary row)."""
+    lse = m + jnp.log(s)
+    if axis is not None:
+        g = jax.lax.pmax(lse, axis)
+        lse = g + jnp.log(jax.lax.psum(jnp.exp(lse - g), axis))
+        t = jax.lax.psum(t, axis)
+        sl = jax.lax.psum(sl, axis)
+    return lse, t, sl
+
+
+def _nll(cfg: _CECfg, lse, t, sl, v_total):
+    eps = cfg.label_smoothing
+    nll = lse - t if eps == 0.0 else lse - (1.0 - eps) * t - eps * sl / v_total
+    if cfg.z_loss:
+        nll = nll + cfg.z_loss * lse * lse
+    return nll
+
+
 def _fwd_impl(cfg: _CECfg, x, w, b, labels):
     vloc = w.shape[1] if cfg.has_w else x.shape[-1]
     lab_loc, axis, v_total = _local_labels(cfg, labels, vloc)
@@ -369,32 +408,25 @@ def _fwd_impl(cfg: _CECfg, x, w, b, labels):
         m, s, t, sl = _stats_pallas(cfg, x, w, lab_loc)
     else:
         m, s, t, sl = _stats_tokens(cfg, x, w, b, lab_loc)
-    lse = m + jnp.log(s)
-    if axis is not None:
-        g = jax.lax.pmax(lse, axis)
-        lse = g + jnp.log(jax.lax.psum(jnp.exp(lse - g), axis))
-        t = jax.lax.psum(t, axis)
-        sl = jax.lax.psum(sl, axis)
-    eps = cfg.label_smoothing
-    nll = lse - t if eps == 0.0 else lse - (1.0 - eps) * t - eps * sl / v_total
-    if cfg.z_loss:
-        nll = nll + cfg.z_loss * lse * lse
+    lse, t, sl = _merge_stats(axis, m, s, t, sl)
     valid = labels != cfg.ignore_index
-    return jnp.where(valid, nll, 0.0), lse
+    return jnp.where(valid, _nll(cfg, lse, t, sl, v_total), 0.0), lse
 
 
-def _bwd_coefs(cfg: _CECfg, labels, lse, ct):
-    ctv = jnp.where(labels != cfg.ignore_index, ct.astype(jnp.float32), 0.0)
-    coef_p = ctv * (1.0 + 2.0 * cfg.z_loss * lse) if cfg.z_loss else ctv
-    return ctv, coef_p
+def _ct_valid(cfg: _CECfg, labels, ct):
+    """The per-token cotangent in fp32, 0 at ignored tokens."""
+    return jnp.where(labels != cfg.ignore_index, ct.astype(jnp.float32), 0.0)
 
 
-def _chunk_dlogits(cfg: _CECfg, logits, lab_c, lse_c, ctv_c, coef_c, v_total):
+def _chunk_dlogits(cfg: _CECfg, logits, lab_c, lse_c, ctv_c, v_total):
     """d loss / d logits for one fp32 tile: p*coef - (1-eps)*ct*onehot -
-    (eps/V)*ct — the chunked form of softmax-minus-onehot."""
+    (eps/V)*ct with coef = ct (1 + 2 z lse) — the chunked form of
+    softmax-minus-onehot."""
     eps = cfg.label_smoothing
     p = jnp.exp(logits - lse_c[:, None])
     col = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+    coef_c = (ctv_c * (1.0 + 2.0 * cfg.z_loss * lse_c) if cfg.z_loss
+              else ctv_c)
     d = p * coef_c[:, None]
     d = d - jnp.where(col == lab_c[:, None],
                       (1.0 - eps) * ctv_c[:, None], 0.0)
@@ -413,60 +445,73 @@ def _mp_fix_grads(cfg: _CECfg, axis, dx, dw, db):
         cotangents pass through untouched — scale them back by world.
       * logits-level (no w): the logits input is itself vocab-sharded — its
         local d-logits tile is already complete, only the ÷world undone.
+    The one pass applies the same fix in its forward, to gradients taken at
+    the per-token weights: the reduced scalar is replicated over mp like the
+    per-token loss, so its cotangent arrives ÷world the same way (a psum
+    over the token axes inside the entry's own shard_map transposes to the
+    ÷ of those axes it undoes), and scaling by it commutes with the fix.
     Parity-gated by the mp cases of tests/test_fused_cross_entropy.py."""
     if axis is None:
         return dx, dw, db
     world = jax.lax.psum(1, axis)
     if not cfg.has_w:
         return dx * world, dw, db
-    dx = jax.lax.psum(dx, axis)
+    dx = jax.lax.psum(dx, axis) if dx is not None else None
     dw = dw * world
     if db is not None:
         db = db * world
     return dx, dw, db
 
 
+def _head_grads(cfg: _CECfg, carry, xi, d, wf):
+    """One token chunk's share of the head's gradients from its d-logits
+    tile: dx_chunk = d W^T, and x_chunk^T d (and d's column sums) added to
+    the fp32 accumulators."""
+    dw_acc, db_acc = carry
+    if cfg.fp8:
+        # gradient tile in e5m2, x/w in e4m3; the dw accumulator stays
+        # fp32 (only the matmuls change precision)
+        dxi = _fp8_mm(d, wf.T, a_e5m2=True)
+        dw_acc = dw_acc + _fp8_mm(d.T, xi.astype(jnp.float32),
+                                  a_e5m2=True).T
+    else:
+        dxi = jnp.dot(d, wf.T, preferred_element_type=jnp.float32)
+        dw_acc = dw_acc + jnp.dot(xi.astype(jnp.float32).T, d,
+                                  preferred_element_type=jnp.float32)
+    if db_acc is not None:
+        db_acc = db_acc + jnp.sum(d, axis=0)
+    return (dw_acc, db_acc), dxi
+
+
+def _grad_init(cfg: _CECfg, w):
+    return (jnp.zeros(w.shape, jnp.float32),
+            jnp.zeros((w.shape[1],), jnp.float32) if cfg.has_bias else None)
+
+
 def _bwd_tokens(cfg: _CECfg, x, w, b, labels, lse, ct):
     n = x.shape[0]
     vloc = w.shape[1] if cfg.has_w else x.shape[-1]
     lab_loc, axis, v_total = _local_labels(cfg, labels, vloc)
-    ctv, coef_p = _bwd_coefs(cfg, labels, lse, ct)
+    ctv = _ct_valid(cfg, labels, ct)
     c = cfg.bwd_chunk_tokens
     xp, lp, nc = _pad_tokens(x, lab_loc, c)
     aux = jnp.stack([jnp.pad(lse, (0, nc * c - n)),
-                     jnp.pad(ctv, (0, nc * c - n)),
-                     jnp.pad(coef_p, (0, nc * c - n))], axis=-1)
+                     jnp.pad(ctv, (0, nc * c - n))], axis=-1)
     xc = xp.reshape((nc, c) + xp.shape[1:])
     lc = lp.reshape(nc, c)
-    ac = aux.reshape(nc, c, 3)
+    ac = aux.reshape(nc, c, 2)
     wf = w.astype(jnp.float32) if cfg.has_w else None
 
     def step(carry, args):
         xi, li, ai = args
         logits = (_project(xi, w, b, cfg.fp8) if cfg.has_w
                   else xi.astype(jnp.float32))
-        d = _chunk_dlogits(cfg, logits, li, ai[:, 0], ai[:, 1], ai[:, 2],
-                           v_total)
+        d = _chunk_dlogits(cfg, logits, li, ai[:, 0], ai[:, 1], v_total)
         if not cfg.has_w:
             return carry, d
-        dw_acc, db_acc = carry
-        if cfg.fp8:
-            # gradient tile in e5m2, x/w in e4m3; the dw accumulator stays
-            # fp32 (only the matmuls change precision)
-            dxi = _fp8_mm(d, wf.T, a_e5m2=True)
-            dw_acc = dw_acc + _fp8_mm(d.T, xi.astype(jnp.float32),
-                                      a_e5m2=True).T
-        else:
-            dxi = jnp.dot(d, wf.T, preferred_element_type=jnp.float32)
-            dw_acc = dw_acc + jnp.dot(xi.astype(jnp.float32).T, d,
-                                      preferred_element_type=jnp.float32)
-        if db_acc is not None:
-            db_acc = db_acc + jnp.sum(d, axis=0)
-        return (dw_acc, db_acc), dxi
+        return _head_grads(cfg, carry, xi, d, wf)
 
-    init = ((jnp.zeros(w.shape, jnp.float32),
-             jnp.zeros((vloc,), jnp.float32) if cfg.has_bias else None)
-            if cfg.has_w else None)
+    init = _grad_init(cfg, w) if cfg.has_w else None
     carry, dxs = jax.lax.scan(step, init, (xc, lc, ac))
     dx = dxs.reshape((nc * c,) + dxs.shape[2:])[:n]
     dx, dw_acc, db_acc = _mp_fix_grads(
@@ -478,10 +523,53 @@ def _bwd_tokens(cfg: _CECfg, x, w, b, labels, lse, ct):
         db_acc.astype(b.dtype) if cfg.has_bias else None)
 
 
+def _one_pass(cfg: _CECfg, x, w, b, labels, tw):
+    """The reduced loss `sum_i tw_i * nll_i` AND its gradients at a
+    cotangent of 1, in ONE scan over token chunks at the backward's depth:
+    each chunk's fp32 logits tile is projected once, reduced to the
+    softmax statistics (over the mp axis when it is bound), and turned into
+    its d-logits tile with `tw` where the two-pass backward puts the
+    per-token cotangent; then the same two products as `_bwd_tokens`.
+    Three head products where the two passes make four."""
+    n, vloc = x.shape[0], w.shape[1]
+    lab_loc, axis, v_total = _local_labels(cfg, labels, vloc)
+    c = cfg.bwd_chunk_tokens
+    xp, lp, nc = _pad_tokens(x, lab_loc, c)
+    xc = xp.reshape((nc, c) + xp.shape[1:])
+    lc = lp.reshape(nc, c)
+    # tw is 0 at ignored tokens (`_token_weights`) and at the padding
+    cc = jnp.pad(tw, (0, nc * c - n)).reshape(nc, c)
+    wf = w.astype(jnp.float32)
+
+    def step(carry, args):
+        xi, li, ci = args
+        logits = _project(xi, w, b, cfg.fp8)
+        lse, t, sl = _merge_stats(axis, *_chunk_stats(logits, li))
+        # the statistics need whole rows, so the tile goes through HBM and
+        # d cannot be the projection's epilogue as in `_bwd_tokens`; the
+        # barrier forms d once, where XLA would form it inside each product
+        # again for every block of the product's output
+        d = jax.lax.optimization_barrier(
+            _chunk_dlogits(cfg, logits, li, lse, ci, v_total))
+        carry, dxi = _head_grads(cfg, carry, xi, d, wf)
+        if axis is not None:
+            # Megatron's sum of the partial dx over the vocabulary shards, a
+            # chunk at a time: beside the next chunk's products, and what
+            # the scan keeps is in the input's dtype
+            dxi = jax.lax.psum(dxi, axis)
+        return carry, (dxi.astype(x.dtype), _nll(cfg, lse, t, sl, v_total))
+
+    carry, (dxs, nll) = jax.lax.scan(step, _grad_init(cfg, w), (xc, lc, cc))
+    loss = jnp.sum(tw * nll.reshape(-1)[:n])
+    _, dw, db = _mp_fix_grads(cfg, axis, None, *carry)
+    return loss, (dxs.reshape((-1,) + dxs.shape[2:])[:n], dw.astype(w.dtype),
+                  db.astype(b.dtype) if cfg.has_bias else None)
+
+
 def _bwd_vocab(cfg: _CECfg, x, w, b, labels, lse, ct):
     n, vloc = x.shape[0], w.shape[1]
     lab_loc, axis, v_total = _local_labels(cfg, labels, vloc)
-    ctv, coef_p = _bwd_coefs(cfg, labels, lse, ct)
+    ctv = _ct_valid(cfg, labels, ct)
     cv = cfg.chunk_vocab
     wp, bp, nc = _pad_vocab(w, b, vloc, cv)
     wc = jnp.moveaxis(wp.reshape(wp.shape[0], nc, cv), 1, 0)
@@ -499,8 +587,7 @@ def _bwd_vocab(cfg: _CECfg, x, w, b, labels, lse, ct):
             logits = logits + args[2].astype(jnp.float32)
         # labels shifted into this chunk's [0, cv) frame, then padding
         # columns (>= vloc) zeroed
-        d = _chunk_dlogits(cfg, logits, lab_loc - j * cv, lse, ctv, coef_p,
-                           v_total)
+        d = _chunk_dlogits(cfg, logits, lab_loc - j * cv, lse, ctv, v_total)
         col = j * cv + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
         d = jnp.where(col < vloc, d, 0.0)
         if cfg.fp8:
@@ -567,6 +654,33 @@ def _build_linear_ce(cfg: _CECfg):
 
 
 @functools.lru_cache(maxsize=None)
+def _build_linear_ce_reduced(cfg: _CECfg):
+    """`sum_i tw_i * nll_i` as one custom_vjp. The primal is the statistics
+    forward alone (an evaluation pays no gradient products); under
+    differentiation the forward rule is `_one_pass`, which leaves dx, dW
+    (and db) at a cotangent of 1 in their own dtypes, and the backward rule
+    only scales them by the scalar cotangent. The per-token weights `tw`
+    are known before the call and take no gradient."""
+    @jax.custom_vjp
+    def f(x, w, b, labels, tw):
+        return jnp.sum(tw * _fwd_impl(cfg, x, w, b, labels)[0])
+
+    def fwd(x, w, b, labels, tw):
+        loss, grads = _one_pass(cfg, x, w, b, labels, tw)
+        return loss, (grads, labels, tw)
+
+    def bwd(res, ct):
+        grads, labels, tw = res
+        dx, dw, db = (None if g is None else
+                      (g.astype(jnp.float32) * ct).astype(g.dtype)
+                      for g in grads)
+        return dx, dw, db, _label_zero(labels), jnp.zeros_like(tw)
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
+@functools.lru_cache(maxsize=None)
 def _build_softmax_ce(cfg: _CECfg):
     @jax.custom_vjp
     def f(logits, labels):
@@ -586,7 +700,8 @@ def _build_softmax_ce(cfg: _CECfg):
 
 
 def _resolve_cfg(n, vloc, ignore_index, label_smoothing, z_loss, chunk_tokens,
-                 chunk_vocab, variant, mp_axis, has_w, has_bias):
+                 chunk_vocab, variant, mp_axis, has_w, has_bias,
+                 reduction="none"):
     from paddle_tpu.core.flags import flag
     from paddle_tpu.tuning.blocks import (Resolution, note_derived,
                                           resolve_blocks)
@@ -630,7 +745,12 @@ def _resolve_cfg(n, vloc, ignore_index, label_smoothing, z_loss, chunk_tokens,
         bwd, bwd_from = resolve_bwd_chunk(n, vloc), "shape"
     else:
         bwd, bwd_from = ct, "forward"
-    note_derived(res, bwd_chunk_tokens=bwd, bwd_chunk_from=bwd_from)
+    # a reduced loss through a head takes ONE pass (`_one_pass`); per-token
+    # losses, the logits-level loss and the vocab variant keep the
+    # statistics forward and a backward of their own
+    passes = 1 if (has_w and reduction != "none" and variant != "vocab") else 2
+    note_derived(res, bwd_chunk_tokens=bwd, bwd_chunk_from=bwd_from,
+                 passes=passes)
     if mp_axis == "auto":
         from paddle_tpu.distributed.collective import _bound_axes
         from paddle_tpu.distributed.fleet.layers.mpu.mp_ops import MP_AXIS
@@ -640,28 +760,53 @@ def _resolve_cfg(n, vloc, ignore_index, label_smoothing, z_loss, chunk_tokens,
                   ct, cv, variant, mp_axis, has_w, has_bias, bwd, fp8)
 
 
+_REDUCTIONS = ("none", "mean", "sum", "mean_all")
+
+
+def _token_weights(labels, ignore_index, reduction):
+    """Per-token fp32 weights w_i of a reduced loss `sum_i w_i * nll_i`:
+    the cotangent each token's loss gets from the reduction ("mean" over
+    non-ignored tokens, "sum", "mean_all" over every token)."""
+    valid = (labels != ignore_index).astype(jnp.float32)
+    if reduction == "sum":
+        return valid
+    if reduction == "mean":
+        return valid / jnp.maximum(jnp.sum(valid), 1.0)
+    return valid / labels.shape[0]
+
+
 def fused_linear_cross_entropy_loss(x, w, labels, bias=None, *,
                                     ignore_index=-100, label_smoothing=0.0,
                                     z_loss=0.0, chunk_tokens=0, chunk_vocab=0,
-                                    variant="auto", mp_axis="auto"):
-    """Per-token fp32 loss of ``CE(x @ w + bias, labels)`` without the
-    [tokens, vocab] logits. x: [N, H]; w: [H, V] (the local shard under bound
-    mp); labels: [N] int. Ignored tokens contribute 0."""
+                                    variant="auto", mp_axis="auto",
+                                    reduction="none"):
+    """fp32 ``CE(x @ w + bias, labels)`` without the [tokens, vocab] logits.
+    x: [N, H]; w: [H, V] (the local shard under bound mp); labels: [N] int.
+    `reduction` "none" gives the per-token loss (ignored tokens 0); "mean"
+    (over non-ignored tokens), "sum" or "mean_all" (over all N) give the
+    scalar, whose gradients come out of the one pass that computes it."""
     _check_labels(labels)
+    if reduction not in _REDUCTIONS:
+        raise ValueError(f"reduction must be one of {_REDUCTIONS}, "
+                         f"got {reduction!r}")
     cfg = _resolve_cfg(x.shape[0], w.shape[1], ignore_index, label_smoothing,
                        z_loss, chunk_tokens, chunk_vocab, variant, mp_axis,
-                       True, bias is not None)
+                       True, bias is not None, reduction)
     if cfg.variant == "pallas" and bias is not None:
         cfg = cfg._replace(variant="tokens")
-    if bias is not None:
-        return _build_linear_ce(cfg)(x, w, bias, labels)
-    mesh = _compat.gspmd_mesh(x, w) if cfg.variant == "pallas" else None
+    one_pass = reduction != "none" and cfg.variant != "vocab"
+    tw = (_token_weights(labels, ignore_index, reduction)
+          if reduction != "none" else None)
+    mesh = (_compat.gspmd_mesh(x, w)
+            if one_pass or cfg.variant == "pallas" else None)
     if mesh is None:
-        return _build_linear_ce(cfg)(x, w, labels)
-    # Mosaic under a GSPMD mesh: per shard, in the layout GSPMD gives the
-    # head — tokens over the data axes, the vocab dim of w over "mp", which
-    # is then BOUND inside the shard and selects the Megatron parallel
-    # softmax above (an undivisible vocab stays whole: no mp reduction)
+        return _linear_ce(cfg, x, w, bias, labels, tw)
+    # per shard, in the layout GSPMD gives the head — tokens over the data
+    # axes, the vocab dim of w over "mp", which is then BOUND inside the
+    # shard and selects the Megatron parallel softmax above (an undivisible
+    # vocab stays whole: no mp reduction). Mosaic cannot be partitioned by
+    # GSPMD, and the one pass must not be: its row statistics stay the
+    # explicit pmax / psum over the vocabulary shards
     from jax.sharding import PartitionSpec as P
 
     from paddle_tpu.distributed.fleet.layers.mpu.mp_ops import MP_AXIS
@@ -670,15 +815,36 @@ def fused_linear_cross_entropy_loss(x, w, labels, bias=None, *,
     tok = _compat.mesh_axes_dividing(mesh, _compat.DATA_AXES, x.shape[0])
     voc = _compat.mesh_axes_dividing(mesh, (MP_AXIS,), w.shape[1])
 
-    def per_shard(xs, ws, ls):
+    def per_shard(xs, ws, ls, *rest):
         c = _resolve_cfg(xs.shape[0], ws.shape[1], ignore_index,
                          label_smoothing, z_loss, chunk_tokens, chunk_vocab,
-                         "pallas", MP_AXIS if voc else None, True, False)
-        return _build_linear_ce(c)(xs, ws, ls)
+                         cfg.variant, MP_AXIS if voc else None, True,
+                         bias is not None, reduction)
+        # rest: (tw, [bias]) for the one pass; a bias never comes here
+        # otherwise (its variant is "tokens", which GSPMD partitions)
+        tws, bs = (rest[0], rest[1:]) if one_pass else (None, ())
+        out = _linear_ce(c, xs, ws, bs[0] if bs else None, ls, tws)
+        # the scalar summed over the token shards; it is equal on every mp
+        # rank already (the statistics were reduced over mp)
+        return jax.lax.psum(out, tok) if one_pass and tok else out
 
-    return shard_map_compat(per_shard, mesh,
-                            (P(tok, None), P(None, voc), P(tok)),
-                            P(tok))(x, w, labels)
+    args, specs = (x, w, labels), (P(tok, None), P(None, voc), P(tok))
+    if one_pass:
+        args, specs = args + (tw,), specs + (P(tok),)
+    if bias is not None:
+        args, specs = args + (bias,), specs + (P(voc),)
+    return shard_map_compat(per_shard, mesh, specs,
+                            P() if one_pass else P(tok))(*args)
+
+
+def _linear_ce(cfg: _CECfg, x, w, b, labels, tw):
+    """Per-token losses (tw None), or `sum_i tw_i * nll_i`: in one pass
+    unless the variant is "vocab"."""
+    if tw is not None and cfg.variant != "vocab":
+        return _build_linear_ce_reduced(cfg)(x, w, b, labels, tw)
+    nll = _build_linear_ce(cfg)(*((x, w, labels) if b is None
+                                  else (x, w, b, labels)))
+    return nll if tw is None else jnp.sum(tw * nll)
 
 
 def softmax_cross_entropy_loss(logits, labels, *, ignore_index=-100,

@@ -88,17 +88,6 @@ def fused_head_spec(head, loss_fn) -> dict | None:
     return spec
 
 
-def reduce_fused_nll(nll, labels_flat, spec):
-    """Reduce per-token fp32 fused-CE losses per the spec's reduction."""
-    red = spec.get("reduction", "mean")
-    if red == "mean_all":
-        return jnp.mean(nll)
-    from paddle_tpu.nn.functional import _fused_ce_reduce
-
-    valid = labels_flat != spec.get("ignore_index", -100)
-    return _fused_ce_reduce(nll, valid, red, nll.shape, nll.dtype)
-
-
 def fused_head_loss(head, head_vals, x, labels, spec):
     """Scalar fp32 `loss_fn(head(x), labels)` via the chunked fused kernel,
     with `head_vals` temporarily bound as the head's parameters. x/labels
@@ -126,9 +115,9 @@ def fused_head_loss(head, head_vals, x, labels, spec):
         lab = jnp.squeeze(lab, -1)
     flat = fv.reshape(-1, fv.shape[-1])
     labf = lab.reshape(-1)
-    nll = fused_linear_cross_entropy_loss(
+    return fused_linear_cross_entropy_loss(
         flat, w, labf, b,
         ignore_index=spec.get("ignore_index", -100),
         label_smoothing=spec.get("label_smoothing", 0.0),
-        z_loss=spec.get("z_loss", 0.0), mp_axis="auto")
-    return reduce_fused_nll(nll, labf, spec)
+        z_loss=spec.get("z_loss", 0.0), mp_axis="auto",
+        reduction=spec.get("reduction", "mean"))

@@ -202,13 +202,79 @@ class TestMpShardedParity:
         np.testing.assert_allclose(sharded(x, w, lab), ref(x, w),
                                    rtol=2e-5, atol=2e-5)
         assert last_resolution("fused_ce").derived == {
-            "bwd_chunk_tokens": 1280, "bwd_chunk_from": "shape"}
+            "bwd_chunk_tokens": 1280, "bwd_chunk_from": "shape", "passes": 2}
         g_f = jax.grad(lambda x_, w_: jnp.sum(sharded(x_, w_, lab)),
                        argnums=(0, 1))(x, w)
         g_r = jax.grad(lambda x_, w_: jnp.sum(ref(x_, w_)),
                        argnums=(0, 1))(x, w)
         for gf, gr in zip(g_f, g_r):
             np.testing.assert_allclose(gf, gr, rtol=2e-5, atol=1e-4)
+
+    @pytest.mark.parametrize("ct", [1.0, 2.0 ** -3])
+    @pytest.mark.parametrize("reduction", ["mean", "sum", "mean_all"])
+    def test_one_pass_mp_matches_two_pass(self, reduction, ct):
+        """The one pass under the bound axis: the statistics of each chunk
+        reduce over the vocabulary shards inside the loop, and the scalar's
+        gradients are the two-pass path's bit for bit."""
+        mesh = self._mesh()
+        v = 52
+        x, w, b, lab = _data(v=v)   # 4 chunks of 7 tokens, the last ragged
+        kw = dict(label_smoothing=0.1, z_loss=1e-3, chunk_tokens=7,
+                  variant="tokens", mp_axis="mp")
+        specs = (P(), P(None, "mp"), P())
+        one = shard_map_compat(lambda a, b_, l: fused_linear_cross_entropy_loss(
+            a, b_, l, reduction=reduction, **kw), mesh, specs, P())
+        two = shard_map_compat(lambda a, b_, l: fused_linear_cross_entropy_loss(
+            a, b_, l, **kw), mesh, specs, P())
+        l1, g1 = jax.jit(jax.value_and_grad(
+            lambda a, b_: ct * one(a, b_, lab), argnums=(0, 1)))(x, w)
+        assert last_resolution("fused_ce").derived["passes"] == 1
+        g2 = jax.jit(jax.grad(lambda a, b_: ct * _two_pass_reduced(
+            two(a, b_, lab), lab, reduction), argnums=(0, 1)))(x, w)
+        for a, b_ in zip(g1, g2):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+        np.testing.assert_allclose(
+            float(l1) / ct,
+            float(_two_pass_reduced(_ref_nll(x, w, None, lab, eps=0.1,
+                                             z_loss=1e-3), lab, reduction)),
+            rtol=2e-5)
+
+    @pytest.mark.parametrize("reduction", ["mean", "mean_all"])
+    def test_one_pass_under_a_gspmd_mesh(self, reduction):
+        """Under a dp x mp mesh the op runs the one pass per shard in its
+        own shard_map (tokens over dp, the head's vocabulary over mp) and
+        sums the scalar over the token shards there."""
+        if len(jax.devices()) < 4:
+            pytest.skip("needs the 8-virtual-device CPU platform")
+        from jax.sharding import NamedSharding
+
+        from paddle_tpu.distributed.mesh import set_mesh
+
+        mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "mp"))
+        x, w, b, lab = _data(n=2560, v=52)
+        xs = jax.device_put(x, NamedSharding(mesh, P("dp", None)))
+        ws = jax.device_put(w, NamedSharding(mesh, P(None, "mp")))
+        bs = jax.device_put(b, NamedSharding(mesh, P("mp")))
+
+        def loss(a, b_, c):
+            return fused_linear_cross_entropy_loss(
+                a, b_, lab, c, variant="tokens", reduction=reduction)
+
+        set_mesh(mesh)
+        try:
+            fn = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+            got, grads = fn(xs, ws, bs)
+            assert "sdy.manual_computation" in fn.lower(xs, ws, bs).as_text()
+            assert last_resolution("fused_ce").derived["passes"] == 1
+        finally:
+            set_mesh(None)
+
+        def ref(a, b_, c):
+            return _two_pass_reduced(_ref_nll(a, b_, c, lab), lab, reduction)
+
+        np.testing.assert_allclose(float(got), float(ref(x, w, b)), rtol=2e-5)
+        for g, r in zip(grads, jax.grad(ref, argnums=(0, 1, 2))(x, w, b)):
+            np.testing.assert_allclose(g, r, rtol=2e-5, atol=2e-5)
 
     def test_sharded_logits_softmax_matches_single_device(self):
         mesh = self._mesh()
@@ -293,7 +359,7 @@ class TestBackwardDepth:
                                    rtol=2e-5, atol=2e-5)
         res = last_resolution("fused_ce")
         assert res.derived == {"bwd_chunk_tokens": 1280,
-                               "bwd_chunk_from": "shape"}
+                               "bwd_chunk_from": "shape", "passes": 2}
         assert res.values["chunk_tokens"] == N_DEEP   # the forward's tile
         assert "bwd_chunk_tokens" not in res.values   # not a tunable
         for gf, gr in zip(_grads(fused, x, w, lab), _grads(ref, x, w, lab)):
@@ -335,7 +401,7 @@ class TestBackwardDepth:
         finally:
             set_flags({"fused_ce_chunk_tokens": prev})
         assert last_resolution("fused_ce").derived == {
-            "bwd_chunk_tokens": 16, "bwd_chunk_from": how}
+            "bwd_chunk_tokens": 16, "bwd_chunk_from": how, "passes": 2}
         rows = [int(r) for r in re.findall(rf"tensor<(\d+)x{v}x", txt)]
         assert rows and max(rows) == 16
 
@@ -349,7 +415,7 @@ class TestBackwardDepth:
         res = last_resolution("fused_ce")
         assert res.provenance == "trial" and res.values["chunk_tokens"] == 8
         assert res.derived == {"bwd_chunk_tokens": N,
-                               "bwd_chunk_from": "shape"}
+                               "bwd_chunk_from": "shape", "passes": 2}
         for gf, gr in zip(g, _grads(
                 lambda a, b, *r: _ref_nll(a, b, None, lab), x, w, lab)):
             np.testing.assert_allclose(gf, gr, rtol=2e-5, atol=2e-5)
@@ -372,7 +438,223 @@ class TestBackwardDepth:
         res = last_resolution("fused_ce")
         assert res.derived == {
             "bwd_chunk_tokens": res.values["chunk_tokens"],
-            "bwd_chunk_from": "forward"}
+            "bwd_chunk_from": "forward", "passes": 2}
+
+
+def _two_pass_reduced(nll, lab, reduction):
+    """The reduction the callers applied to per-token losses before the op
+    took it: F.cross_entropy's mean over non-ignored tokens, sum, and
+    `ParallelCrossEntropy(...).mean()` over every token."""
+    if reduction == "mean":
+        return jnp.sum(nll) / jnp.maximum(
+            jnp.sum((lab != IGN).astype(nll.dtype)), 1.0)
+    if reduction == "sum":
+        return jnp.sum(nll)
+    return jnp.mean(nll)
+
+
+def _count_eqns(jaxpr, name):
+    """Equations `name` in `jaxpr` and the jaxprs nested in it, a Pallas
+    kernel's body left out."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == name
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                n += _count_eqns(sub, name)
+    return n
+
+
+def _bits(a):
+    return np.asarray(jnp.asarray(a).astype(jnp.float32))
+
+
+class TestOnePass:
+    """A reduced loss through the head: one token-chunked pass computes the
+    loss and the gradients together. At a cotangent of 1 or a power of two
+    its gradients are the two-pass path's bit for bit; the two-pass path is
+    the per-token op reduced outside it, at the same chunk."""
+
+    def _pair(self, reduction, extras, dtype, n=N_DEEP):
+        x, w, b, lab = _data(dtype, n=n)   # every fifth label ignored
+        kw = dict(variant="tokens", mp_axis=None)
+        if extras:
+            kw.update(label_smoothing=0.1, z_loss=1e-3)
+        bias = b if extras else None
+
+        def one(x_, w_, b_):
+            return fused_linear_cross_entropy_loss(
+                x_, w_, lab, b_, reduction=reduction, **kw)
+
+        def two(x_, w_, b_):
+            return _two_pass_reduced(fused_linear_cross_entropy_loss(
+                x_, w_, lab, b_, **kw), lab, reduction)
+
+        return one, two, (x, w, bias)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("ct", [1.0, 2.0 ** -3])
+    @pytest.mark.parametrize("extras", [False, True],
+                             ids=["plain", "bias_smoothing_zloss"])
+    @pytest.mark.parametrize("reduction", ["mean", "sum", "mean_all"])
+    def test_gradients_bit_equal_to_two_pass(self, reduction, extras, ct,
+                                             dtype):
+        # 2500 tokens: two iterations of 1280 with a ragged tail
+        one, two, args = self._pair(reduction, extras, dtype)
+        argnums = (0, 1, 2) if extras else (0, 1)
+        g1 = jax.grad(lambda *a: ct * one(*a), argnums=argnums)(*args)
+        assert last_resolution("fused_ce").derived == {
+            "bwd_chunk_tokens": 1280, "bwd_chunk_from": "shape", "passes": 1}
+        g2 = jax.grad(lambda *a: ct * two(*a), argnums=argnums)(*args)
+        for a, b in zip(g1, g2):
+            assert a.dtype == b.dtype == dtype
+            np.testing.assert_array_equal(_bits(a), _bits(b))
+        np.testing.assert_allclose(float(one(*args)), float(two(*args)),
+                                   rtol=2e-6)
+
+    @pytest.mark.parametrize("reduction", ["mean", "sum", "mean_all"])
+    def test_other_cotangent_within_one_ulp(self, reduction):
+        """0.3: the one pass scales the bf16 gradients after they are
+        rounded, the two-pass path the fp32 cotangent before: one bf16
+        rounding apart, beside the fp32 sums' own rounding (a few fp32 ulps
+        of the largest element, which only an element that cancels to near
+        zero can show)."""
+        one, two, args = self._pair(reduction, True, jnp.bfloat16)
+        g1 = jax.grad(lambda *a: 0.3 * one(*a), argnums=(0, 1, 2))(*args)
+        g2 = jax.grad(lambda *a: 0.3 * two(*a), argnums=(0, 1, 2))(*args)
+        for a, b in zip(g1, g2):
+            a, b = _bits(a), _bits(b)
+            ulp = np.spacing(np.maximum(np.abs(a), np.abs(b))) * 2.0 ** 16
+            sums = np.max(np.abs(b)) * 2.0 ** -21
+            assert np.all(np.abs(a - b) <= ulp + sums)
+            assert np.mean(a != b) < 0.5
+
+    def test_primal_makes_one_head_product(self):
+        """An evaluation pays no gradient products: the un-differentiated
+        reduced loss is the statistics forward alone (the Pallas `ce_stats`
+        call where the variant is pallas), and the differentiated one is ONE
+        scan of three products with no `ce_stats` call."""
+        x, w, _, lab = _data(n=N_DEEP)
+
+        def loss(a, b):
+            return fused_linear_cross_entropy_loss(
+                a, b, lab, variant="pallas", mp_axis=None, reduction="mean")
+
+        primal = jax.make_jaxpr(loss)(x, w).jaxpr
+        assert _count_eqns(primal, "pallas_call") == 1
+        assert _count_eqns(primal, "dot_general") == 0
+        diff = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1)))(
+            x, w).jaxpr
+        assert _count_eqns(diff, "pallas_call") == 0
+        assert _count_eqns(diff, "scan") == 1
+        assert _count_eqns(diff, "dot_general") == 3
+        tokens = jax.make_jaxpr(lambda a, b: fused_linear_cross_entropy_loss(
+            a, b, lab, variant="tokens", mp_axis=None, reduction="mean"))(
+                x, w).jaxpr
+        assert _count_eqns(tokens, "dot_general") == 1
+
+    def test_ignored_everywhere_gives_zero(self):
+        x, w, _, lab = _data()
+        ign = jnp.full_like(lab, IGN)
+        loss, (dx, dw) = jax.value_and_grad(
+            lambda a, b: fused_linear_cross_entropy_loss(
+                a, b, ign, chunk_tokens=7, mp_axis=None, reduction="mean"),
+            argnums=(0, 1))(x, w)
+        assert float(loss) == 0.0
+        assert not np.any(np.asarray(dx)) and not np.any(np.asarray(dw))
+
+    def test_unknown_reduction_is_refused(self):
+        x, w, _, lab = _data()
+        with pytest.raises(ValueError, match="reduction"):
+            fused_linear_cross_entropy_loss(x, w, lab, reduction="avg")
+
+
+def _model_loss(name):
+    """One tiny model of each head that reaches the op, traced with labels
+    (its loss is what the train step differentiates): the loss's shape."""
+    from paddle_tpu import models
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.ops.pallas.flash_attention import force_interpret
+
+    build = {
+        "llama": (models.LlamaForCausalLM, models.llama_tiny_config),
+        "kimi_linear": (models.KimiLinearForCausalLM,
+                        models.kimi_linear_tiny_config),
+        "lfm2_moe": (models.Lfm2MoeForCausalLM, models.lfm2_moe_tiny_config),
+        "deepseek_v3": (models.DeepseekV3ForCausalLM,
+                        models.deepseek_v3_tiny_config),
+    }[name]
+    paddle.seed(0)
+    cfg = build[1]()
+    model = build[0](cfg)
+    ids = np.random.RandomState(0).randint(
+        0, cfg.vocab_size, size=(2, 16)).astype(np.int32)
+    with force_interpret():
+        return jax.eval_shape(
+            lambda i: model(Tensor(i), labels=Tensor(i))._value, ids)
+
+
+def _fused_head_loss(reduction):
+    """`fused_head_loss`, as the pipelined runtimes call it, on a head that
+    opts in (forward_features + lm_head) with a CrossEntropyLoss."""
+    from paddle_tpu.parallel.fused_head import (fused_head_loss,
+                                                fused_head_spec)
+
+    class Head(nn.Layer):
+        def __init__(self):
+            super().__init__()
+            self.norm = nn.LayerNorm(H)
+            self.lm_head = nn.Linear(H, V, bias_attr=False)
+
+        def forward_features(self, x):
+            return self.norm(x)
+
+        def forward(self, x):
+            return self.lm_head(self.forward_features(x))
+
+    head = Head()
+    spec = fused_head_spec(head, nn.CrossEntropyLoss(reduction=reduction))
+    x, _, _, lab = _data()
+    return fused_head_loss(head, [p._value for p in head.parameters()],
+                           x, lab, spec)
+
+
+class TestPassesByCaller:
+    """`last_resolution("fused_ce").derived["passes"]` says which path ran:
+    every head of the benchmark's cells and the pipelined runtimes' head
+    give a reduction and take one pass; per-token losses and the vocab
+    variant keep two."""
+
+    @pytest.mark.parametrize("model", ["llama", "kimi_linear", "lfm2_moe",
+                                       "deepseek_v3"])
+    def test_model_heads_take_one_pass(self, model):
+        loss = _model_loss(model)
+        assert loss.shape == () and loss.dtype == jnp.float32
+        assert last_resolution("fused_ce").derived["passes"] == 1
+
+    @pytest.mark.parametrize("reduction", ["mean", "sum"])
+    def test_fused_head_loss_takes_one_pass(self, reduction):
+        assert np.isfinite(float(_fused_head_loss(reduction)))
+        assert last_resolution("fused_ce").derived["passes"] == 1
+
+    def test_per_token_losses_take_two(self):
+        x, w, _, lab = _data()
+        out = F.fused_linear_cross_entropy(
+            paddle.to_tensor(np.asarray(x)), paddle.to_tensor(np.asarray(w)),
+            paddle.to_tensor(np.asarray(lab)), reduction="none")
+        assert tuple(out.shape) == (N,)
+        assert last_resolution("fused_ce").derived["passes"] == 2
+
+    def test_vocab_variant_takes_two(self):
+        x, w, _, lab = _data()
+        loss, _ = jax.value_and_grad(
+            lambda a: fused_linear_cross_entropy_loss(
+                a, w, lab, variant="vocab", chunk_vocab=13, mp_axis=None,
+                reduction="mean"))(x)
+        assert last_resolution("fused_ce").derived["passes"] == 2
+        np.testing.assert_allclose(
+            float(loss), float(_two_pass_reduced(_ref_nll(x, w, None, lab),
+                                                 lab, "mean")), rtol=2e-5)
 
 
 class TestNoFullLogitsMaterialized:
